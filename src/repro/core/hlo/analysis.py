@@ -416,9 +416,6 @@ def analyze_compiled(compiled) -> Dict[str, float]:
     ca = {}
     try:
         ca = compiled.cost_analysis() or {}
-        # older jax returns a one-element list of per-program dicts
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
     except Exception:
         pass
     mem = {}

@@ -1,15 +1,13 @@
 """Jit'd public wrapper for flash-decode."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import check_backend
 from repro.kernels.decode_attention.kernel import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 
 
-def decode_attention_op(q, k, v, kv_len, *, interpret: bool | None = None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def decode_attention_op(q, k, v, kv_len, *, interpret: bool = False):
+    check_backend(interpret)
     return decode_attention(q, k, v, kv_len, interpret=interpret)
 
 

@@ -1,18 +1,14 @@
-"""Jit'd public wrapper for flash attention with backend dispatch."""
+"""Jit'd public wrapper for flash attention."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import check_backend
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 
 
 def flash_attention_op(q, k, v, *, causal: bool = True, q_offset: int = 0,
-                       interpret: bool | None = None):
-    """Flash attention; interpret defaults to True off-TPU so the Pallas
-    kernel body itself is what runs (and is tested) everywhere."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+                       interpret: bool = False):
+    check_backend(interpret)
     return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
                            interpret=interpret)
 

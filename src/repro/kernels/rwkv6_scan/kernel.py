@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
@@ -35,15 +33,18 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     lw = lw_ref[0].astype(jnp.float32)        # (c, hd) log-decays (<0)
     u = u_ref[0].astype(jnp.float32)          # (1, hd) bonus
 
-    cum = jnp.cumsum(lw, axis=0)              # inclusive logW
+    # inclusive logW as a lower-triangular matmul (Mosaic has no cumsum)
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    i_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = (t_idx >= i_idx).astype(jnp.float32)
+    cum = jax.lax.dot(tril, lw, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
     cum_ex = cum - lw                         # exclusive logW (W_{t-1})
 
     # intra-chunk pairwise decays: exp(cum_ex[t] - cum[i]) for i < t
     diff = cum_ex[:, None, :] - cum[None, :, :]          # (t, i, hd)
     decay = jnp.exp(jnp.minimum(diff, 0.0))
-    A = jnp.einsum("tik,tk,ik->ti", decay, r, k)
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    i_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    A = jnp.sum(decay * r[:, None, :] * k[None, :, :], axis=-1)   # (t, i)
     A = jnp.where(t_idx > i_idx, A, 0.0)
     out = jax.lax.dot(A, v, preferred_element_type=jnp.float32)
 
@@ -95,7 +96,7 @@ def rwkv6_scan(r, k, v, logw, u, state0, *, chunk: int = 32,
             pl.BlockSpec((1, chunk, hd), lambda n, c: (n, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda n, c: (n, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda n, c: (n, c, 0)),
-            pl.BlockSpec((1, hd), lambda n, c: (n, 0)),
+            pl.BlockSpec((1, 1, hd), lambda n, c: (n, 0, 0)),
             pl.BlockSpec((1, hd, hd), lambda n, c: (n, 0, 0)),
         ],
         out_specs=[
@@ -107,8 +108,8 @@ def rwkv6_scan(r, k, v, logw, u, state0, *, chunk: int = 32,
             jax.ShapeDtypeStruct((N, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, logw, u, state0)
+    )(r, k, v, logw, u.reshape(N, 1, hd), state0)
     return out[:, :S], state

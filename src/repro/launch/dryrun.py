@@ -27,7 +27,6 @@ from repro.core.hlo.analysis import analyze_compiled
 from repro.launch import mesh as mesh_lib
 from repro.launch import steps as steps_lib
 from repro.models import api
-from repro.optim import adamw
 from repro.sharding import activation_rules
 
 
@@ -55,21 +54,10 @@ def dryrun_cell(arch_id: str, shape_name: str, multi_pod: bool = False,
     with activation_rules(mesh, seq_parallel=seq_parallel):
         if shape.mode == "train":
             opt_cfg = OptimizerConfig()
-            opt_shapes = jax.eval_shape(
-                lambda: adamw.init_opt_state(
-                    jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape,
-                                                                s.dtype),
-                                 params_shapes), opt_cfg))
-            sh = mesh_lib.shardings_for(cfg, shape, mesh, params_shapes,
-                                        opt_shapes, inputs,
-                                        seq_parallel=seq_parallel)
-            step_fn, _ = steps_lib.step_for_shape(cfg, shape, opt_cfg,
-                                                  remat=remat)
-            jitted = jax.jit(
-                step_fn,
-                in_shardings=(sh["params"], sh["opt_state"], sh["batch"]),
-                out_shardings=(sh["params"], sh["opt_state"], None),
-                donate_argnums=(0, 1))
+            _, opt_shapes = steps_lib.train_state_shapes(cfg, opt_cfg)
+            jitted, _ = steps_lib.jit_train_step(
+                cfg, opt_cfg, mesh, inputs, remat=remat,
+                seq_parallel=seq_parallel)
             lowered = jitted.lower(params_shapes, opt_shapes, inputs)
         elif shape.mode == "prefill":
             sh = mesh_lib.shardings_for(cfg, shape, mesh, params_shapes,

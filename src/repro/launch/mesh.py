@@ -22,7 +22,9 @@ from repro.core.config import (MeshConfig, ModelConfig, OptimizerConfig,
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model annotates activations with sharding constraints
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_elastic_mesh(num_devices: int, model_parallel: int = 16) -> Mesh:

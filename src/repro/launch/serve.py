@@ -3,10 +3,12 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --smoke \
         --requests 8 --max-new 32
 
-A minimal production-shaped server core: a request queue, bucketed prefill,
-a decode batch with in-flight slot reuse (a finished request's slot is
-refilled from the queue), greedy sampling.  On TPU the same loop runs the
-full config on the production mesh with the Pallas decode kernel.
+A minimal production-shaped server core: a request queue, token-by-token
+prefill, a decode batch with in-flight slot reuse (a finished request's
+slot is refilled from the queue), greedy sampling.  ``--smoke`` serves the
+CPU-sized config in f32; without it the registered full config runs in its
+own dtype.  Attention runs through the jnp path of ``repro.models``; no
+Pallas kernel is on this path yet.
 
 Decode steps run with **per-slot cache positions**: each active slot
 writes/attends at its own sequence position, so slots at different depths
@@ -23,7 +25,6 @@ loop's ordering on a scripted arrival trace).
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import time
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Tuple
@@ -33,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.config import get_arch
+from repro.launch import common
 from repro.launch import mesh as mesh_lib
 from repro.launch import steps as steps_lib
 from repro.models import api
@@ -46,6 +48,8 @@ class Request:
     max_new: int
     out: List[int] = field(default_factory=list)
     done: bool = False
+    # False once any logits row this request sampled from was not finite
+    finite: bool = True
     # per-request serving metrics (perf_counter timestamps; the measured
     # side of the virtual ServingReport)
     t_arrive: float = 0.0
@@ -145,6 +149,7 @@ class BatchedServer:
         for i in active:
             r = self.slot_req[i]
             nxt = int(np.argmax(logits[i]))
+            r.finite = r.finite and bool(np.isfinite(logits[i]).all())
             if not r.out:
                 r.t_first = now
             r.out.append(nxt)
@@ -185,10 +190,8 @@ def main(argv=None):
     p.add_argument("--max-len", type=int, default=128)
     args = p.parse_args(argv)
 
-    spec = get_arch(args.arch)
-    cfg = spec.smoke if args.smoke else spec.model
-    cfg = dataclasses.replace(cfg, param_dtype="float32",
-                              compute_dtype="float32")
+    common.enable_compile_cache()
+    cfg = common.run_config(get_arch(args.arch), args.smoke)
     if cfg.family in ("audio", "encdec", "convnet"):
         raise SystemExit("serve.py targets decoder-only archs")
 

@@ -7,13 +7,13 @@ out_shardings computed by ``repro.launch.mesh.shardings_for``.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.config import ModelConfig, OptimizerConfig, ShapeConfig
+from repro.launch import mesh as mesh_lib
 from repro.models import api
 from repro.optim import adamw
 
@@ -34,6 +34,44 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
         return params, opt_state, metrics
 
     return train_step
+
+
+def train_state_shapes(cfg: ModelConfig, opt_cfg: OptimizerConfig):
+    """(params, optimizer state) as ShapeDtypeStruct pytrees."""
+    params_shapes = api.param_shapes(cfg)
+    opt_shapes = jax.eval_shape(
+        lambda: adamw.init_opt_state(params_shapes, opt_cfg))
+    return params_shapes, opt_shapes
+
+
+def jit_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, mesh,
+                   batch_specs: Dict[str, Any], *, remat: str = "dots",
+                   seq_parallel: bool = False) -> Tuple[Callable, Dict]:
+    """The train step jitted with the in/out shardings that
+    ``mesh.shardings_for`` gives on ``mesh``: params and optimizer state
+    keep their shardings from step to step and are donated.  Returns
+    ``(jitted, shardings)``; place the state with :func:`init_train_state`.
+    """
+    params_shapes, opt_shapes = train_state_shapes(cfg, opt_cfg)
+    shape = ShapeConfig("train", 0, 0, "train")
+    sh = mesh_lib.shardings_for(cfg, shape, mesh, params_shapes, opt_shapes,
+                                batch_specs, seq_parallel=seq_parallel)
+    jitted = jax.jit(make_train_step(cfg, opt_cfg, remat=remat),
+                     in_shardings=(sh["params"], sh["opt_state"], sh["batch"]),
+                     out_shardings=(sh["params"], sh["opt_state"], None),
+                     donate_argnums=(0, 1))
+    return jitted, sh
+
+
+def init_train_state(key, cfg: ModelConfig, opt_cfg: OptimizerConfig,
+                     shardings: Dict[str, Any]):
+    """Params and optimizer state created directly in their shardings
+    (never gathered on one device first)."""
+    params = jax.jit(lambda k: api.init_params(k, cfg),
+                     out_shardings=shardings["params"])(key)
+    opt_state = jax.jit(lambda p: adamw.init_opt_state(p, opt_cfg),
+                        out_shardings=shardings["opt_state"])(params)
+    return params, opt_state
 
 
 def make_prefill_step(cfg: ModelConfig) -> Callable:

@@ -6,29 +6,42 @@
 Wires together: config registry -> mesh + sharding -> synthetic data
 pipeline (prefetching) -> jitted train step (donated state) -> checkpoint
 manager (async, atomic, auto-resume) -> supervisor heartbeats.  ``--smoke``
-selects the reduced config (CPU-runnable, f32); omit it on a real TPU fleet
-to train the full config on the production mesh.
+selects the reduced config in f32 (CPU-runnable); without it the full
+config trains in its registered dtype.  On several devices the mesh is
+(data, model) and params and optimizer state are created in the shardings
+of ``repro.launch.mesh.shardings_for``.
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.core.config import OptimizerConfig, get_arch
 from repro.data.pipeline import DataConfig, PrefetchIterator, \
     SyntheticTokenPipeline
+from repro.launch import common
 from repro.launch import mesh as mesh_lib
 from repro.launch import steps as steps_lib
 from repro.models import api
-from repro.optim import adamw
 from repro.runtime.supervisor import Supervisor
 from repro.sharding import activation_rules
+
+
+def model_batch(cfg, host_batch, batch: int, seq: int):
+    """The step's input dict (host arrays) for one pipeline batch."""
+    out = dict(host_batch)
+    if cfg.family == "vlm":
+        npre = min(cfg.frontend.num_prefix, seq // 2)
+        out["prefix_embeds"] = np.zeros((batch, npre, cfg.d_model),
+                                        np.float32)
+    elif cfg.family in ("audio", "encdec"):
+        out = {"frames": np.zeros((batch, seq // 2, cfg.d_model), np.float32),
+               "tokens": out["tokens"][:, :seq // 2]}
+    return out
 
 
 def main(argv=None):
@@ -47,14 +60,13 @@ def main(argv=None):
     p.add_argument("--ckpt-every", type=int, default=50)
     p.add_argument("--resume", action="store_true")
     p.add_argument("--log-every", type=int, default=10)
-    p.add_argument("--dtype", default="float32",
-                   help="param/compute dtype (CPU executes f32 only)")
+    p.add_argument("--dtype", default=None,
+                   help="param/compute dtype (default: the config's own; "
+                        "float32 with --smoke)")
     args = p.parse_args(argv)
 
-    spec = get_arch(args.arch)
-    cfg = spec.smoke if args.smoke else spec.model
-    cfg = dataclasses.replace(cfg, param_dtype=args.dtype,
-                              compute_dtype=args.dtype)
+    common.enable_compile_cache()
+    cfg = common.run_config(get_arch(args.arch), args.smoke, args.dtype)
     opt_cfg = OptimizerConfig(lr=args.lr, warmup_steps=args.warmup,
                               total_steps=args.steps,
                               grad_compression=args.grad_compression)
@@ -64,22 +76,28 @@ def main(argv=None):
     print(f"devices={n_dev} mesh={mesh_lib.mesh_name(mesh)} "
           f"arch={cfg.name} params≈{api.param_count(cfg):,}")
 
-    rng = jax.random.key(0)
-    with activation_rules(mesh):
-        params = api.init_params(rng, cfg)
-        opt_state = adamw.init_opt_state(params, opt_cfg)
-        step_fn = steps_lib.make_train_step(cfg, opt_cfg, remat=args.remat)
-        jitted = jax.jit(step_fn, donate_argnums=(0, 1))
+    data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
+                          global_batch=args.batch)
+    pipeline = SyntheticTokenPipeline(data_cfg)
+    batch_specs = {
+        k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+        for k, v in model_batch(
+            cfg, {"tokens": np.zeros((args.batch, args.seq), np.int32)},
+            args.batch, args.seq).items()}
 
-        data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
-                              global_batch=args.batch)
-        pipeline = SyntheticTokenPipeline(data_cfg)
+    with activation_rules(mesh):
+        jitted, sh = steps_lib.jit_train_step(cfg, opt_cfg, mesh, batch_specs,
+                                              remat=args.remat)
+        state_sh = {"params": sh["params"], "opt_state": sh["opt_state"]}
         ckpt = CheckpointManager(args.ckpt_dir)
         start_step = 0
         if args.resume and ckpt.latest_step() is not None:
-            start_step, state = ckpt.restore()
+            start_step, state = ckpt.restore(shardings=state_sh)
             params, opt_state = state["params"], state["opt_state"]
             print(f"resumed from step {start_step}")
+        else:
+            params, opt_state = steps_lib.init_train_state(
+                jax.random.key(0), cfg, opt_cfg, sh)
 
         sup = Supervisor(num_workers=1)
         prefetch = PrefetchIterator(pipeline, start_step=start_step)
@@ -88,16 +106,7 @@ def main(argv=None):
         try:
             for _ in range(start_step, args.steps):
                 step_i, host_batch = next(prefetch)
-                batch = {k: jnp.asarray(v) for k, v in host_batch.items()}
-                if cfg.family == "vlm":
-                    npre = min(cfg.frontend.num_prefix, args.seq // 2)
-                    batch["prefix_embeds"] = jnp.zeros(
-                        (args.batch, npre, cfg.d_model), jnp.float32)
-                elif cfg.family in ("audio", "encdec"):
-                    batch = {"frames": jnp.zeros(
-                        (args.batch, args.seq // 2, cfg.d_model),
-                        jnp.float32),
-                        "tokens": batch["tokens"][:, :args.seq // 2]}
+                batch = model_batch(cfg, host_batch, args.batch, args.seq)
                 t0 = time.perf_counter()
                 params, opt_state, metrics = jitted(params, opt_state, batch)
                 loss = float(metrics["loss"])

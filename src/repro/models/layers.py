@@ -113,9 +113,8 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
 #
 # This is the XLA-native twin of the Pallas flash-attention kernel
 # (repro/kernels/flash_attention): O(S * chunk) live memory instead of
-# O(S^2), numerically identical to full softmax attention.  The dry-run and
-# CPU tests use this path; on real TPU the Pallas kernel replaces it
-# (cfg-level switch in repro.models.api).
+# O(S^2), numerically identical to full softmax attention.  It is the only
+# attention path of the models, on every backend.
 # ---------------------------------------------------------------------------
 
 

@@ -88,13 +88,7 @@ def test_collectives_under_shard_map_are_counted():
     def f(a):
         return jax.lax.psum(a, "x")
 
-    if hasattr(jax, "shard_map"):
-        shard_map = jax.shard_map
-    else:  # jax < 0.5 keeps it in experimental
-        from jax.experimental.shard_map import shard_map as _sm
-
-        shard_map = _sm
-    sm = shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
+    sm = jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
     comp = jax.jit(sm).lower(jnp.ones((8, 16))).compile()
     rep = analyze_compiled(comp)
     # 1-way all-reduce may be optimised away; just assert the walker parses

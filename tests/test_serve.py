@@ -91,6 +91,24 @@ def test_slot_reuse_after_finish():
     assert r1.done
 
 
+def test_non_finite_logits_are_flagged():
+    slots, vocab = 2, 8
+
+    def stub(params, state, tokens, pos):
+        logits = np.zeros((slots, vocab), np.float32)
+        logits[1, 3] = np.nan
+        return logits, state
+
+    server = BatchedServer(cfg=None, batch_slots=slots, max_len=16,
+                           decode_fn=stub)
+    ok, bad = (Request(i, np.array([1], np.int32), max_new=2)
+               for i in range(2))
+    server.admit(ok)
+    server.admit(bad)
+    server.step()
+    assert ok.finite and not bad.finite
+
+
 @pytest.mark.slow
 def test_ragged_batched_decode_matches_solo():
     """Numeric regression: slots at different depths decode exactly as if
