@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 from pathlib import Path
 from typing import Optional
 
@@ -20,10 +21,20 @@ def enable_compile_cache() -> str:
     """Keep JAX's persistent compilation cache in
     ``$JAX_COMPILATION_CACHE_DIR`` when that is set, else in the fixed
     ``<repo>/.jax_cache`` (the path is part of the cache key, so it never
-    depends on a temporary name, a pid or the time).  Returns the path."""
+    depends on a temporary name, a pid or the time).  Returns the path.
+
+    The key also covers each op's name stack: the layer scopes of
+    ``repro.models.scopes`` that a device trace reports, which JAX's
+    default key leaves out.  So an executable compiled from code that
+    names its layers otherwise is never loaded in its place.  Source
+    paths in that metadata are made relative to the checkout, so the key
+    does not depend on where the checkout lives."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
         str(REPO_ROOT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(str(REPO_ROOT) + os.sep))
     return path
 
 

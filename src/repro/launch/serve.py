@@ -10,6 +10,19 @@ CPU-sized config in f32; without it the registered full config runs in its
 own dtype.  Attention runs through the jnp path of ``repro.models``; no
 Pallas kernel is on this path yet.
 
+Each request's model input is its prompt and then its served tokens, each
+fed once at its own position: ``admit`` prefills all but the prompt's last
+token, and the first decode step feeds that token and samples the first
+served one.
+
+Tracing: ``admit`` and ``step`` open ``jax.profiler.TraceAnnotation``
+spans (``serve.admit`` > ``serve.prefill``; ``serve.step`` >
+``serve.decode``, ``serve.sample``), which a profiler trace records on the
+clock of the device's ops and cost nothing measurable without one.  With a
+``repro.obs.Probe`` the server also counts prefill tokens, decode steps,
+active slots summed over steps, served tokens and completed requests,
+under the names ``repro.serve_sim``'s simulator uses.
+
 Decode steps run with **per-slot cache positions**: each active slot
 writes/attends at its own sequence position, so slots at different depths
 coexist in one batch (the scalar-``pos`` variant corrupted any slot that
@@ -38,7 +51,16 @@ from repro.launch import common
 from repro.launch import mesh as mesh_lib
 from repro.launch import steps as steps_lib
 from repro.models import api
+from repro.obs import Probe
 from repro.sharding import activation_rules
+
+# host spans, on the profiler's clock
+SPAN_ADMIT = "serve.admit"
+SPAN_PREFILL = "serve.prefill"      # inside serve.admit
+SPAN_STEP = "serve.step"
+SPAN_DECODE = "serve.decode"        # inside serve.step: dispatch to logits
+SPAN_SAMPLE = "serve.sample"        # inside serve.step: argmax, bookkeeping
+SPANS = (SPAN_ADMIT, SPAN_PREFILL, SPAN_STEP, SPAN_DECODE, SPAN_SAMPLE)
 
 
 @dataclass
@@ -78,7 +100,7 @@ class BatchedServer:
 
     def __init__(self, cfg, batch_slots: int, max_len: int,
                  decode_fn: Optional[Callable] = None, state=None,
-                 record_events: bool = False):
+                 record_events: bool = False, probe: Optional[Probe] = None):
         self.cfg = cfg
         self.slots = batch_slots
         self.max_len = max_len
@@ -97,6 +119,20 @@ class BatchedServer:
         # with record_events (parity vs the virtual scheduler) — unbounded
         # otherwise
         self.events: List[Tuple] = []
+        # counter handles, bound once; each site pays one ``is not None``
+        self.probe = probe
+        if probe is not None:
+            self._p_prefill = probe.counter("serve/prefill_tokens",
+                                            unit="tokens")
+            self._p_steps = probe.counter("serve/decode_steps", unit="steps")
+            self._p_slot_steps = probe.counter("serve/slot_steps",
+                                               unit="slots")
+            self._p_tokens = probe.counter("serve/tokens_out", unit="tokens")
+            self._p_completed = probe.counter("serve/completed",
+                                              unit="requests")
+        else:
+            self._p_prefill = self._p_steps = self._p_slot_steps = None
+            self._p_tokens = self._p_completed = None
 
     def load(self, params):
         self.params = params
@@ -110,40 +146,64 @@ class BatchedServer:
 
     def admit(self, req: Request) -> bool:
         """Prefill a request into a free slot (token-by-token prefill keeps
-        one compiled decode step; bucket prefill is the production path)."""
-        try:
-            slot = self.slot_req.index(None)
-        except ValueError:
-            return False
-        self.slot_req[slot] = req
-        req.t_admit = time.perf_counter()
-        if self.record_events:
-            self.events.append(("admit", req.rid))
-        for pos, tok in enumerate(req.prompt):
-            tokens = np.zeros((self.slots,), np.int32)
-            tokens[slot] = tok
-            _, self.state = self.decode(
-                self.params, self.state, jnp.asarray(tokens),
-                jnp.asarray(self._pos_vector(slot, pos), jnp.int32))
-        self.slot_pos[slot] = len(req.prompt)
-        return True
+        one compiled decode step; bucket prefill is the production path).
+        The prompt's last token is left for the first decode step, which
+        feeds it at position ``len(prompt) - 1``."""
+        n = len(req.prompt)
+        with jax.profiler.TraceAnnotation(SPAN_ADMIT, rid=req.rid,
+                                          prompt_len=n):
+            try:
+                slot = self.slot_req.index(None)
+            except ValueError:
+                return False
+            self.slot_req[slot] = req
+            req.t_admit = time.perf_counter()
+            if self.record_events:
+                self.events.append(("admit", req.rid))
+            with jax.profiler.TraceAnnotation(SPAN_PREFILL):
+                for pos, tok in enumerate(req.prompt[:-1]):
+                    tokens = np.zeros((self.slots,), np.int32)
+                    tokens[slot] = tok
+                    _, self.state = self.decode(
+                        self.params, self.state, jnp.asarray(tokens),
+                        jnp.asarray(self._pos_vector(slot, pos), jnp.int32))
+            self.slot_pos[slot] = n - 1
+            if self._p_prefill is not None:
+                self._p_prefill.add(self.probe.elapsed(), n - 1)
+            return True
 
     def step(self) -> int:
         """One decode step for every active slot; returns #finished."""
-        active = [i for i, r in enumerate(self.slot_req) if r is not None]
-        if not active:
-            return 0
-        tokens = np.zeros((self.slots,), np.int32)
-        for i in active:
-            r = self.slot_req[i]
-            tokens[i] = r.out[-1] if r.out else r.prompt[-1]
-        if self.record_events:
-            self.events.append(
-                ("step", tuple(sorted(self.slot_req[i].rid for i in active))))
-        logits, self.state = self.decode(
-            self.params, self.state, jnp.asarray(tokens),
-            jnp.asarray(self.slot_pos, jnp.int32))
-        logits = np.asarray(logits)
+        with jax.profiler.TraceAnnotation(SPAN_STEP):
+            active = [i for i, r in enumerate(self.slot_req) if r is not None]
+            if not active:
+                return 0
+            tokens = np.zeros((self.slots,), np.int32)
+            for i in active:
+                r = self.slot_req[i]
+                tokens[i] = r.out[-1] if r.out else r.prompt[-1]
+            if self.record_events:
+                self.events.append(
+                    ("step", tuple(sorted(self.slot_req[i].rid
+                                          for i in active))))
+            with jax.profiler.TraceAnnotation(SPAN_DECODE):
+                logits, self.state = self.decode(
+                    self.params, self.state, jnp.asarray(tokens),
+                    jnp.asarray(self.slot_pos, jnp.int32))
+                logits = np.asarray(logits)
+            with jax.profiler.TraceAnnotation(SPAN_SAMPLE):
+                finished = self._sample(active, logits)
+            if self._p_steps is not None:
+                t = self.probe.elapsed()
+                self._p_steps.add(t)
+                self._p_slot_steps.add(t, len(active))
+                self._p_tokens.add(t, len(active))
+                self._p_completed.add(t, finished)
+            return finished
+
+    def _sample(self, active: List[int], logits: np.ndarray) -> int:
+        """Greedy tokens for the active slots; frees finished slots and
+        returns how many finished."""
         now = time.perf_counter()
         finished = 0
         for i in active:
@@ -198,7 +258,8 @@ def main(argv=None):
     mesh = mesh_lib.make_elastic_mesh(jax.device_count(), 1)
     with activation_rules(mesh):
         params = api.init_params(jax.random.key(0), cfg)
-        server = BatchedServer(cfg, args.slots, args.max_len)
+        probe = Probe("serve")
+        server = BatchedServer(cfg, args.slots, args.max_len, probe=probe)
         server.load(params)
 
         rng = np.random.default_rng(0)
@@ -209,18 +270,22 @@ def main(argv=None):
                  for i in range(args.requests)]
         done: List[Request] = []
         pending = list(queue)
-        steps = 0
         while len(done) < len(queue):
             while pending and server.admit(pending[0]):
                 pending.pop(0)
             server.step()
-            steps += 1
             done = [r for r in queue if r.done]
         wall = time.perf_counter() - t0
         toks = sum(len(r.out) for r in queue)
+        c = probe.to_metrics()["counters"]
+        steps = int(c["serve/decode_steps"])
         print(f"served {len(queue)} requests, {toks} tokens in {wall:.2f}s "
               f"({toks / wall:.1f} tok/s, {steps} decode steps)")
         print(serve_summary(queue))
+        print("  counters: " + ", ".join(f"{k.split('/', 1)[1]} {int(v)}"
+                                         for k, v in c.items()))
+        occ = c["serve/slot_steps"] / max(steps * args.slots, 1)
+        print(f"  slot occupancy = {occ:.3f}")
         return queue
 
 

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.core.config import AttentionConfig, ModelConfig
 from repro.models import layers as L
+from repro.models import scopes
 from repro.sharding import constrain
 
 Params = Dict[str, Any]
@@ -47,6 +48,7 @@ def gqa_cache_spec(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]
     return {"k": jax.ShapeDtypeStruct(shp, dt), "v": jax.ShapeDtypeStruct(shp, dt)}
 
 
+@jax.named_scope(scopes.ATTN_PROJ)
 def apply_gqa(p: Params, x: jnp.ndarray, cfg: ModelConfig, *, mode: str,
               cache: Optional[Params] = None, pos=None,
               causal: bool = True) -> Tuple[jnp.ndarray, Optional[Params]]:
@@ -75,28 +77,32 @@ def apply_gqa(p: Params, x: jnp.ndarray, cfg: ModelConfig, *, mode: str,
     new_cache = None
     if mode == "decode":
         assert cache is not None
-        k_c = k.astype(cache["k"].dtype)
-        v_c = v.astype(cache["v"].dtype)
-        if jnp.ndim(pos) == 0:
-            pk = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_c, pos,
-                                                     axis=2)
-            pv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_c, pos,
-                                                     axis=2)
-        else:
-            # per-slot positions (continuous batching: each slot writes its
-            # own cache index) — one update per batch row
-            upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(
-                c, u, p, axis=1))
-            pk = upd(cache["k"], k_c, pos)
-            pv = upd(cache["v"], v_c, pos)
+        with jax.named_scope(scopes.KV_WRITE):
+            k_c = k.astype(cache["k"].dtype)
+            v_c = v.astype(cache["v"].dtype)
+            if jnp.ndim(pos) == 0:
+                pk = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_c, pos,
+                                                         axis=2)
+                pv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_c, pos,
+                                                         axis=2)
+            else:
+                # per-slot positions (continuous batching: each slot writes
+                # its own cache index) — one update per batch row
+                upd = jax.vmap(
+                    lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(
+                        c, u, p, axis=1))
+                pk = upd(cache["k"], k_c, pos)
+                pv = upd(cache["v"], v_c, pos)
         new_cache = {"k": pk, "v": pv}
         pk = constrain(pk, ("batch", "kv_heads", "kv_seq", None))
         pv = constrain(pv, ("batch", "kv_heads", "kv_seq", None))
         kv_len = jnp.broadcast_to(jnp.asarray(pos) + 1, (B,)).astype(jnp.int32)
-        out = L.attention(q, pk.astype(cd), pv.astype(cd), causal=False,
-                          kv_len=kv_len)
+        with jax.named_scope(scopes.ATTN_CORE):
+            out = L.attention(q, pk.astype(cd), pv.astype(cd), causal=False,
+                              kv_len=kv_len)
     else:
-        out = L.attention(q, k, v, causal=causal)
+        with jax.named_scope(scopes.ATTN_CORE):
+            out = L.attention(q, k, v, causal=causal)
         if mode == "prefill":
             new_cache = {"k": k, "v": v}
 
@@ -153,6 +159,7 @@ def _mla_q(p: Params, x, a: AttentionConfig, cd) -> Tuple[jnp.ndarray, jnp.ndarr
     return q[..., :a.qk_nope_head_dim], q[..., a.qk_nope_head_dim:]
 
 
+@jax.named_scope(scopes.ATTN_PROJ)
 def apply_mla(p: Params, x: jnp.ndarray, cfg: ModelConfig, *, mode: str,
               cache: Optional[Params] = None, pos=None,
               causal: bool = True) -> Tuple[jnp.ndarray, Optional[Params]]:
@@ -184,17 +191,19 @@ def apply_mla(p: Params, x: jnp.ndarray, cfg: ModelConfig, *, mode: str,
         assert cache is not None and S == 1
         ckv_t = ckv.astype(cache["ckv"].dtype)
         krope_t = krope.astype(cache["krope"].dtype)
-        if jnp.ndim(pos) == 0:
-            ckv_c = jax.lax.dynamic_update_slice_in_dim(
-                cache["ckv"], ckv_t, pos, axis=1)
-            krope_c = jax.lax.dynamic_update_slice_in_dim(
-                cache["krope"], krope_t, pos, axis=1)
-        else:
-            # per-slot positions: one latent-cache update per batch row
-            upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(
-                c, u, p, axis=0))
-            ckv_c = upd(cache["ckv"], ckv_t, pos)
-            krope_c = upd(cache["krope"], krope_t, pos)
+        with jax.named_scope(scopes.KV_WRITE):
+            if jnp.ndim(pos) == 0:
+                ckv_c = jax.lax.dynamic_update_slice_in_dim(
+                    cache["ckv"], ckv_t, pos, axis=1)
+                krope_c = jax.lax.dynamic_update_slice_in_dim(
+                    cache["krope"], krope_t, pos, axis=1)
+            else:
+                # per-slot positions: one latent-cache update per batch row
+                upd = jax.vmap(
+                    lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(
+                        c, u, p, axis=0))
+                ckv_c = upd(cache["ckv"], ckv_t, pos)
+                krope_c = upd(cache["krope"], krope_t, pos)
         new_cache = {"ckv": ckv_c, "krope": krope_c}
         ckv_c = constrain(ckv_c, ("batch", "kv_seq", None))
         # --- absorbed decode over the latent cache ---
@@ -202,18 +211,19 @@ def apply_mla(p: Params, x: jnp.ndarray, cfg: ModelConfig, *, mode: str,
         # f32 copy of the compressed cache)
         q_abs = jnp.einsum("bshn,lhn->bhl", q_nope, w_uk,
                            preferred_element_type=jnp.float32).astype(cd)
-        s = jnp.einsum("bhl,btl->bht", q_abs, ckv_c,
-                       preferred_element_type=jnp.float32)
-        s += jnp.einsum("bshr,btr->bht", q_rope, krope_c,
-                        preferred_element_type=jnp.float32)
-        s *= scale
-        t_pos = jnp.arange(ckv_c.shape[1])
-        pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
-        mask = t_pos[None, None, :] <= pos_b[:, None, None]
-        s = jnp.where(mask, s, -jnp.inf)
-        probs = jax.nn.softmax(s, axis=-1)
-        ctx = jnp.einsum("bht,btl->bhl", probs.astype(cd), ckv_c,
-                         preferred_element_type=jnp.float32).astype(cd)
+        with jax.named_scope(scopes.ATTN_CORE):
+            s = jnp.einsum("bhl,btl->bht", q_abs, ckv_c,
+                           preferred_element_type=jnp.float32)
+            s += jnp.einsum("bshr,btr->bht", q_rope, krope_c,
+                            preferred_element_type=jnp.float32)
+            s *= scale
+            t_pos = jnp.arange(ckv_c.shape[1])
+            pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
+            mask = t_pos[None, None, :] <= pos_b[:, None, None]
+            s = jnp.where(mask, s, -jnp.inf)
+            probs = jax.nn.softmax(s, axis=-1)
+            ctx = jnp.einsum("bht,btl->bhl", probs.astype(cd), ckv_c,
+                             preferred_element_type=jnp.float32).astype(cd)
         out = jnp.einsum("bhl,lhv->bhv", ctx, w_uv,
                          preferred_element_type=jnp.float32)
         out = out.reshape(B, 1, H * vdim).astype(cd)
@@ -231,7 +241,8 @@ def apply_mla(p: Params, x: jnp.ndarray, cfg: ModelConfig, *, mode: str,
         k = k.transpose(0, 2, 1, 3)
         v = v.transpose(0, 2, 1, 3)
         q = constrain(q, ("batch", "heads", "seq", None))
-        out = L.attention(q, k, v, causal=causal)
+        with jax.named_scope(scopes.ATTN_CORE):
+            out = L.attention(q, k, v, causal=causal)
         out = out.transpose(0, 2, 1, 3).reshape(B, S, H * vdim)
         new_cache = {"ckv": ckv, "krope": krope} if mode == "prefill" else None
 

@@ -25,6 +25,7 @@ from repro.core.config import ModelConfig
 from repro.models import attention as ATT
 from repro.models import layers as L
 from repro.models import rwkv6 as R6
+from repro.models import scopes
 from repro.models import ssm as SSM
 
 Params = Dict[str, Any]
@@ -120,28 +121,33 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
         if c is not None:
             new_cache["attn"] = c
     elif mixer == "ssm":
-        y, c = SSM.apply_ssm(p["ssm"], h, cfg, mode=mode,
-                             cache=None if cache is None else cache["ssm"],
-                             pos=pos)
+        with jax.named_scope(scopes.SSM):
+            y, c = SSM.apply_ssm(p["ssm"], h, cfg, mode=mode,
+                                 cache=None if cache is None else cache["ssm"],
+                                 pos=pos)
         if c is not None:
             new_cache["ssm"] = c
     else:  # rwkv time mix
-        y, c = R6.apply_time_mix(p["rwkv_tm"], h, cfg, mode=mode,
-                                 cache=None if cache is None else cache["rwkv_tm"])
+        with jax.named_scope(scopes.RWKV):
+            y, c = R6.apply_time_mix(
+                p["rwkv_tm"], h, cfg, mode=mode,
+                cache=None if cache is None else cache["rwkv_tm"])
         if c is not None:
             new_cache["rwkv_tm"] = c
     x = x + y.astype(x.dtype)
 
     h = L.apply_norm(p["norm2"], x, cfg.norm_eps)
-    if ffn == "dense":
-        y = L.apply_ffn(p["ffn"], h, cfg.act, cd)
-    elif ffn == "moe":
-        y, aux = L.apply_moe(p["ffn_moe"], h, cfg, compute_dtype=cd)
-    else:  # rwkv channel mix
-        y, c = R6.apply_channel_mix(p["rwkv_cm"], h, cfg, mode=mode,
-                                    cache=None if cache is None else cache["rwkv_tm"])
-        if c is not None:
-            new_cache.setdefault("rwkv_tm", {}).update(c)
+    with jax.named_scope(scopes.FFN):
+        if ffn == "dense":
+            y = L.apply_ffn(p["ffn"], h, cfg.act, cd)
+        elif ffn == "moe":
+            y, aux = L.apply_moe(p["ffn_moe"], h, cfg, compute_dtype=cd)
+        else:  # rwkv channel mix
+            y, c = R6.apply_channel_mix(
+                p["rwkv_cm"], h, cfg, mode=mode,
+                cache=None if cache is None else cache["rwkv_tm"])
+            if c is not None:
+                new_cache.setdefault("rwkv_tm", {}).update(c)
     x = x + y.astype(x.dtype)
     return x, (new_cache if new_cache else None), aux
 

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from repro.core.config import ModelConfig
 from repro.models import blocks as B
 from repro.models import layers as L
+from repro.models import scopes
 from repro.sharding import constrain
 
 Params = Dict[str, Any]
@@ -31,6 +32,7 @@ def init_params(key, cfg: ModelConfig) -> Params:
     return p
 
 
+@jax.named_scope(scopes.HEAD)
 def _head(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     cd = L.dtype_of(cfg.compute_dtype)
     if cfg.tie_embeddings:
@@ -92,6 +94,7 @@ def chunked_xent(p: Params, cfg: ModelConfig, h: jnp.ndarray,
     mf = mf.reshape(Bz, nc, chunk).transpose(1, 0, 2)
 
     @jax.checkpoint
+    @jax.named_scope(scopes.HEAD)
     def step(carry, inp):
         hc, tc, mc = inp
         logits = _head(p, hc, cfg).astype(jnp.float32)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.config import OptimizerConfig
+from repro.models import scopes
 
 Params = Any
 
@@ -112,6 +113,7 @@ def _paths(tree, prefix="") -> Any:
     return prefix
 
 
+@jax.named_scope(scopes.OPTIMIZER)
 def adamw_update(params: Params, grads: Params, state: Dict,
                  cfg: OptimizerConfig) -> Tuple[Params, Dict, Dict]:
     """One AdamW step.  Returns (params, state, metrics)."""

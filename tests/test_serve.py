@@ -1,10 +1,13 @@
-"""BatchedServer regression tests: per-slot decode positions.
+"""BatchedServer regression tests: per-slot decode positions, and each
+token fed once.
 
 The scalar-``pos`` server passed ``max(slot_pos)`` to every slot, writing
 all KV caches at the same index — wrong (and cache-corrupting) as soon as
-slots sit at different sequence depths.  The stub-decode tests pin the
-positions the scheduling loop passes; the slow JAX test checks batched
-decode with ragged slots matches each request decoded alone.
+slots sit at different sequence depths.  A later fault fed the prompt's
+last token twice (prefill, then again in the first step).  The stub-decode
+tests pin the tokens and positions the scheduling loop passes; the slow
+JAX test checks batched decode with ragged slots matches each request
+decoded alone.
 """
 import dataclasses
 
@@ -33,34 +36,62 @@ def test_step_passes_per_slot_positions():
     server.admit(Request(1, np.array([7], np.int32), max_new=4))
     calls.clear()
     server.step()
-    _, pos = calls[-1]
-    # regression: slot 0 decodes at its own position 3, slot 1 at 1 —
-    # the old scalar code passed max(slot_pos) = 3 for both
+    tokens, pos = calls[-1]
+    # regression: slot 0 feeds its last prompt token at its own position 2,
+    # slot 1 its only one at 0 — the old scalar code passed max(slot_pos)
+    # = 2 for both
     assert pos.shape == (3,)
-    assert list(pos) == [3, 1, 0]
+    assert list(pos) == [2, 0, 0]
+    assert list(tokens[:2]) == [3, 7]
     server.step()
     _, pos = calls[-1]
-    assert list(pos) == [4, 2, 0]
+    assert list(pos) == [3, 1, 0]
 
 
 def test_admit_prefill_preserves_other_slot_positions():
     server, calls = _stub_server(slots=2)
     server.admit(Request(0, np.array([1, 2, 3], np.int32), max_new=8))
-    server.step()                      # slot0 advances to 4
+    server.step()                      # slot0 advances to 3
     calls.clear()
-    server.admit(Request(1, np.array([5, 6], np.int32), max_new=8))
-    # during slot1's prefill, slot0 must keep its own position (4), not be
+    server.admit(Request(1, np.array([5, 6, 7], np.int32), max_new=8))
+    # during slot1's prefill, slot0 must keep its own position (3), not be
     # dragged to the prefill token index (the cache-corruption regression)
-    assert [list(pos) for _, pos in calls] == [[4, 0], [4, 1]]
-    assert list(server.slot_pos) == [4, 2]
+    assert [list(pos) for _, pos in calls] == [[3, 0], [3, 1]]
+    assert list(server.slot_pos) == [3, 2]
 
 
 def test_prefill_targets_only_the_admitted_slot():
     server, calls = _stub_server(slots=2)
-    server.admit(Request(0, np.array([9, 8], np.int32), max_new=2))
+    server.admit(Request(0, np.array([9, 8, 7], np.int32), max_new=2))
     for tokens, _ in calls:
         assert tokens[1] == 0          # other slot sees padding tokens only
+    # the last prompt token is left for the first decode step
     assert [t[0] for t, _ in calls] == [9, 8]
+
+
+def test_each_token_fed_once_at_its_position():
+    """A request's model input is its prompt and then its served tokens,
+    each once, at positions 0, 1, 2, ... (the old loop fed the prompt's
+    last token a second time, at position len(prompt))."""
+    slots, vocab = 2, 16
+    fed = []
+
+    def stub(params, state, tokens, pos):
+        fed.append((int(tokens[0]), int(pos[0])))
+        logits = np.zeros((slots, vocab), np.float32)
+        logits[:, (int(tokens[0]) * 3 + 1) % vocab] = 1.0
+        return logits, state
+
+    server = BatchedServer(cfg=None, batch_slots=slots, max_len=64,
+                           decode_fn=stub)
+    prompt = [5, 9, 2, 11]
+    r = Request(0, np.array(prompt, np.int32), max_new=5)
+    server.admit(r)
+    while not r.done:
+        server.step()
+    seq = prompt + r.out[:-1]
+    assert fed == [(t, i) for i, t in enumerate(seq)]
+    assert len(r.out) == 5
 
 
 def test_events_and_metrics_recorded():
