@@ -1,9 +1,10 @@
-"""Jit'd public wrapper for flash attention."""
+"""Jit'd public wrappers for flash attention."""
 from __future__ import annotations
 
 from repro.kernels import check_backend
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.flash_attention.train import causal_flash_attention
 
 
 def flash_attention_op(q, k, v, *, causal: bool = True, q_offset: int = 0,
@@ -13,4 +14,10 @@ def flash_attention_op(q, k, v, *, causal: bool = True, q_offset: int = 0,
                            interpret=interpret)
 
 
-__all__ = ["flash_attention_op", "flash_attention", "attention_ref"]
+def causal_flash_attention_op(q, k, v, *, interpret: bool = False):
+    check_backend(interpret)
+    return causal_flash_attention(q, k, v, interpret=interpret)
+
+
+__all__ = ["flash_attention_op", "flash_attention", "attention_ref",
+           "causal_flash_attention_op", "causal_flash_attention"]

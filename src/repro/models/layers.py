@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.config import AttentionConfig, MoEConfig, ModelConfig
+from repro.kernels.flash_attention import train as flash_train
+from repro.sharding import active_mesh
 
 Params = Dict[str, Any]
 
@@ -111,10 +113,13 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
 # ---------------------------------------------------------------------------
 # Attention — online-softmax chunked dot-product attention.
 #
-# This is the XLA-native twin of the Pallas flash-attention kernel
-# (repro/kernels/flash_attention): O(S * chunk) live memory instead of
-# O(S^2), numerically identical to full softmax attention.  It is the only
-# attention path of the models, on every backend.
+# O(S * chunk) live memory instead of O(S^2), numerically identical to full
+# softmax attention.  ``attention`` picks the path by backend and shape:
+# short sequences materialise the scores (``full_attention``); long causal
+# self-attention from position 0 runs the causal Pallas flash kernel with
+# its own backward on a TPU (repro/kernels/flash_attention/train.py);
+# everything else (decode against a cache, ``kv_len``, ``q_offset``,
+# non-causal, unequal q/v head dims, other backends) runs the scan below.
 # ---------------------------------------------------------------------------
 
 
@@ -232,14 +237,37 @@ def full_attention(q, k, v, *, causal: bool, q_offset: int = 0,
     return out.reshape(B, Hq, Sq, vd).astype(q.dtype)
 
 
+def uses_flash_kernel(q, k, v, *, causal: bool, q_offset: int = 0,
+                      kv_len=None) -> bool:
+    """Whether ``attention`` runs the causal flash kernel on a TPU: causal
+    bf16 self-attention from position 0 over the whole sequence, equal head
+    dims the kernel supports, on at most one device (a Mosaic kernel is not
+    partitioned across a mesh)."""
+    (_, hq, sq, hd), (_, hkv, sk, _) = q.shape, k.shape
+    mesh = active_mesh()
+    return (causal and isinstance(q_offset, int) and q_offset == 0
+            and kv_len is None and sq == sk and v.shape[-1] == hd
+            and hq % hkv == 0 and flash_train.supported(sq, hd)
+            and all(x.dtype == jnp.bfloat16 for x in (q, k, v))
+            and (mesh is None or mesh.size == 1))
+
+
 def attention(q, k, v, *, causal: bool, q_offset: int = 0, kv_len=None,
               chunked_threshold: int = 1024) -> jnp.ndarray:
-    """Dispatch: full softmax for short sequences, online-softmax otherwise."""
+    """Dispatch: full softmax for short sequences; for long ones the causal
+    flash kernel where ``uses_flash_kernel`` and the program is lowered for
+    a TPU, else online-softmax."""
     if q.shape[2] * k.shape[2] <= chunked_threshold ** 2:
         return full_attention(q, k, v, causal=causal, q_offset=q_offset,
                               kv_len=kv_len)
-    return chunked_attention(q, k, v, causal=causal, q_offset=q_offset,
-                             kv_len=kv_len)
+    scan = partial(chunked_attention, causal=causal, q_offset=q_offset,
+                   kv_len=kv_len)
+    if uses_flash_kernel(q, k, v, causal=causal, q_offset=q_offset,
+                         kv_len=kv_len):
+        # the branch is chosen when the program is lowered for its platform
+        return jax.lax.platform_dependent(
+            q, k, v, tpu=flash_train.causal_flash_attention, default=scan)
+    return scan(q, k, v)
 
 
 # ---------------------------------------------------------------------------

@@ -58,6 +58,11 @@ def activation_rules(mesh: Optional[Mesh], rules: Optional[Dict] = None,
         _ACTIVE.update(prev)
 
 
+def active_mesh() -> Optional[Mesh]:
+    """The mesh installed by :func:`activation_rules`, if any."""
+    return _ACTIVE["mesh"]
+
+
 def _mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
