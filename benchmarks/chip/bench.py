@@ -4,9 +4,10 @@
     python3 benchmarks/chip/bench.py --workload qwen1.5-0.5b.train_4k \
         --seed 7 --seconds 20 --trace 0
 
-The cell's configuration, traffic mix, limits and per-layer readers are
-found by name (see ``harness.py``).  Everything runs in this one process,
-which holds the chip; nothing is forked.  Set-up (JAX start, weights made
+The cell's configuration, traffic mix, limits, per-layer readers,
+reference and counts are found by name (see ``harness.py``).  Everything
+runs in this one process, which holds the chip; nothing is forked.
+Set-up (JAX start, weights made
 on the device from the seed, compilation or a load from the persistent
 cache, warm-up) counts as ``setup_s``; then the window runs for
 ``--seconds``.  ``--trace 0`` reports the cell's end-to-end metrics,
@@ -62,11 +63,14 @@ def setup_jax():
 
 
 class Context:
-    """What a per-layer reader sees."""
+    """What a per-layer reader sees: the cell, the run's record, the reduced
+    trace, the chip's peaks, and ``counts``, the configuration's operation
+    and byte counts (``harness.counts_for``)."""
 
     def __init__(self, cell, rec, red, peak):
         self.cell, self.rec, self.trace, self.peak = cell, rec, red, peak
         self.config, self.traffic = cell.config, cell.traffic
+        self.counts = harness.counts_for(cell.config, cell.here)
 
 
 def run_cell(args, cell=None, devices=None, break_step=None):
@@ -115,7 +119,7 @@ def run_cell(args, cell=None, devices=None, break_step=None):
             trace_reduce.find_xplane(trace_dir)))
         ctx = Context(cell, rec, red, peaks.get(kind, {}))
         for m in cell.per_layer:
-            v = harness.metric_reader(m["name"])(ctx)
+            v = harness.metric_reader(m["name"], cell.here)(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     else:

@@ -7,8 +7,9 @@
 
 For each seed the program's readings (``correctness.py``) against the
 float32 reference, at the cell's own sizes.  For ``--control`` seeds the
-control's: the reference with every matrix product rounded to float8
-(``reference.fp8_mm``) in the program's place.  For ``--faults`` seeds the
+control's: the configuration's reference (``harness.reference_for``) with
+every matrix product rounded to float8 (its ``fp8_mm``) in the program's
+place.  For ``--faults`` seeds the
 program with a planted fault in place of the timed path: for training,
 half of the batch left out of the loss (the mean taken over the rest); a
 step that returns its state unchanged reads 1 by construction and needs no
@@ -34,7 +35,6 @@ sys.path[:0] = [str(HERE), str(HERE.parents[1] / "src")]
 import bench  # noqa: E402
 import correctness  # noqa: E402
 import harness  # noqa: E402
-import reference  # noqa: E402
 
 
 def half_batch(step, cfg, opt_cfg, sh):
@@ -57,6 +57,7 @@ def calibrate_train(cell, args):
     import train_loop as td
 
     out = {"program": {}, "control": {}, "half_batch": {}, "raw": {}}
+    reference = harness.reference_for(cell.config, cell.here)
     for s in args.seeds:
         t = time.perf_counter()
         o = td.setup(cell, s)
@@ -90,6 +91,7 @@ def calibrate_serve(cell, args):
     import traffic_gen
 
     out = {"program": {}, "control": {}}
+    reference = harness.reference_for(cell.config, cell.here)
     for s in args.seeds:
         t = time.perf_counter()
         cfg, server, shapes = sd.build(cell, s)

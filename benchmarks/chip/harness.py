@@ -3,21 +3,32 @@
 ``BENCHMARK.json`` (at the checkout's root) names each cell's configuration
 and traffic mix.  The files are found by those names alone:
 
-* ``configs/<config>.json``: the configuration as it is run;
+* ``configs/<config>.json``: the configuration as it is run, with the cut
+  it makes from its source (``program_config``);
 * ``traffic/<mix>.json``: the mix's kind (``serve`` or ``train``) and its
   parameters;
 * ``limits/<cell>.json``: the limits of the cell's correctness check;
 * ``metrics/<metric>.py``: one reader per per-layer metric, with
-  ``read(ctx) -> float | None``.
+  ``read(ctx) -> float | None``;
+* ``references/<config>.py``: the configuration's plain float32 reference,
+  with ``reference.py``'s interface (``RefConfig.from_file``,
+  ``highest_mm``, ``fp8_mm``, ``train``, ``logits_at``, ``gaps_at``,
+  ``argmax_at``); ``reference.py`` where there is none;
+* ``counts/<config>.py``: the operations and bytes the configuration's
+  steps need, with ``flops.py``'s public functions; ``flops.py`` where
+  there is none.
 
-A later cell, mix or metric is a new file and a new ``BENCHMARK.json``
-entry; nothing here changes.
+A later cell, mix, metric or configuration is new files and new
+``BENCHMARK.json`` entries; nothing here changes.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib
 import importlib.util
 import json
+import re
 from pathlib import Path
 from typing import Dict, List, Optional
 
@@ -45,6 +56,7 @@ class Cell:
     limits: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    here: Path = HERE           # the directory its files were found in
 
 
 def _reports(metric: Dict, cell: str) -> bool:
@@ -66,7 +78,7 @@ def find_cell(name: str, bench: Optional[Dict] = None,
                 config=load_json(here / "configs" / f"{w['config']}.json"),
                 traffic=load_json(here / "traffic" / f"{w['traffic']}.json"),
                 limits=load_json(here / "limits" / f"{name}.json"),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer, here=here)
 
 
 def cell_from_files(name: str, here: Path = HERE) -> Cell:
@@ -78,48 +90,162 @@ def cell_from_files(name: str, here: Path = HERE) -> Cell:
                 config=load_json(here / "configs" / f"{conf}.json"),
                 traffic=load_json(here / "traffic" / f"{mix}.json"),
                 limits=load_json(lim) if lim.exists() else {"limits": {}},
-                end_to_end=[], per_layer=[])
+                end_to_end=[], per_layer=[], here=here)
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: Path, kind: str):
+    """The module in ``path``, loaded once a process, so that the jitted
+    functions of a reference compile once however often it is asked for."""
+    stem = re.sub(r"\W", "_", path.stem)
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{stem}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(name: str, here: Path = HERE):
     path = here / "metrics" / f"{name}.py"
     if not path.exists():
         raise BenchError(f"no reader {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, "metric").read
+
+
+def _own_or(conf: Dict, kind: str, default: str, here: Path):
+    path = here / kind / f"{conf['name']}.py"
+    return _load(path, kind) if path.exists() else \
+        importlib.import_module(default)
+
+
+def reference_for(conf: Dict, here: Path = HERE):
+    """The configuration's plain reference: ``references/<name>.py``, or
+    ``reference.py`` where there is none."""
+    return _own_or(conf, "references", "reference", here)
+
+
+def counts_for(conf: Dict, here: Path = HERE):
+    """The configuration's operation and byte counts: ``counts/<name>.py``,
+    or ``flops.py`` where there is none."""
+    return _own_or(conf, "counts", "flops", here)
+
+
+def _source(conf: Dict):
+    """The source's value of a key: under ``published`` for a key the file
+    lists in ``reduced``, else the file's own."""
+    red, pub = conf.get("reduced", []), conf.get("published", {})
+    missing = [k for k in red if k not in pub]
+    if missing:
+        raise BenchError(f"{conf['name']}.json reduces {missing} but gives "
+                         f"no published value for them")
+    return lambda k: pub[k] if k in red else conf.get(k)
+
+
+def _widths(cfg, conf: Dict) -> Dict:
+    """The registered arch's sizes under the keys of the configuration
+    file, whose names follow its source's config.json."""
+    a, moe = cfg.attention, cfg.moe
+    w = {"hidden_size": cfg.d_model, "num_hidden_layers": cfg.num_layers,
+         "num_attention_heads": a.num_heads,
+         "num_key_value_heads": a.num_kv_heads,
+         "vocab_size": cfg.vocab_size, "attention_bias": a.qkv_bias,
+         "tie_word_embeddings": cfg.tie_embeddings}
+    if a.kind == "mla":
+        w.update(q_lora_rank=a.q_lora_rank or None,
+                 kv_lora_rank=a.kv_lora_rank,
+                 qk_nope_head_dim=a.qk_nope_head_dim,
+                 qk_rope_head_dim=a.qk_rope_head_dim,
+                 v_head_dim=a.v_head_dim)
+    else:
+        w["head_dim"] = a.head_dim
+    if moe is None:
+        w["intermediate_size"] = cfg.d_ff
+    elif "n_routed_experts" in conf:            # DeepSeek's names
+        w.update(n_routed_experts=moe.num_experts,
+                 num_experts_per_tok=moe.num_experts_per_tok,
+                 n_shared_experts=moe.num_shared_experts,
+                 moe_intermediate_size=moe.d_ff_expert,
+                 first_k_dense_replace=moe.first_k_dense,
+                 intermediate_size=moe.d_ff_dense or cfg.d_ff)
+    else:                                       # Mixtral's and Granite's
+        w.update(num_local_experts=moe.num_experts,
+                 num_experts_per_tok=moe.num_experts_per_tok,
+                 intermediate_size=moe.d_ff_expert)
+    return w
+
+
+def _check_floors(conf: Dict):
+    """The model-configs guide's floors on a cut (its section 4): at least
+    four layers after the leading dense ones, 8 routed experts, an eighth
+    of the vocabulary."""
+    red, name = set(conf.get("reduced", [])), conf["name"]
+    if "num_hidden_layers" in red and conf["num_hidden_layers"] \
+            - conf.get("first_k_dense_replace", 0) < 4:
+        raise BenchError(f"{name}.json keeps fewer than 4 layers after the "
+                         f"leading dense ones")
+    for k in ("num_local_experts", "n_routed_experts"):
+        if k in red and conf[k] < 8:
+            raise BenchError(f"{name}.json keeps {conf[k]} routed experts, "
+                             f"fewer than 8")
+    if "vocab_size" in red and \
+            8 * conf["vocab_size"] < conf["published"]["vocab_size"]:
+        raise BenchError(f"{name}.json keeps less than an eighth of the "
+                         f"vocabulary")
+
+
+def _set_field(obj, path: List[str], value, dotted: str):
+    name = path[0]
+    if not dataclasses.is_dataclass(obj) or \
+            name not in {f.name for f in dataclasses.fields(obj)}:
+        raise BenchError(f"the program's ModelConfig has no field {dotted!r}")
+    if len(path) > 1:
+        value = _set_field(getattr(obj, name), path[1:], value, dotted)
+    return dataclasses.replace(obj, **{name: value})
 
 
 def program_config(conf: Dict, traffic: Dict):
-    """The program's ModelConfig for a configuration file: the registered
-    arch, with the published rope theta and norm epsilon, and the dtypes
-    the mix states.  Every width is checked against the file."""
+    """The program's ModelConfig for a configuration file.
+
+    Every width the registered arch has is checked against the file's
+    source value (``published`` for a key in ``reduced``).  Then the
+    file's ``program`` object makes the cut: each entry names a ModelConfig
+    field (dotted for a nested one, ``moe.num_experts``) and the file key,
+    listed in ``reduced`` or ``assumed``, whose value it takes.  Besides
+    that, only the published rope theta and norm epsilon and the dtypes
+    the mix states differ from the registered arch."""
     from repro.core.config import get_arch
 
     spec = get_arch(conf["arch"])
     cfg = spec.smoke if conf.get("size") == "smoke" else spec.model
-    a = cfg.attention
-    want = {"hidden_size": cfg.d_model, "num_hidden_layers": cfg.num_layers,
-            "num_attention_heads": a.num_heads,
-            "num_key_value_heads": a.num_kv_heads, "head_dim": a.head_dim,
-            "vocab_size": cfg.vocab_size,
-            "attention_bias": a.qkv_bias,
-            "tie_word_embeddings": cfg.tie_embeddings}
-    if cfg.moe:
-        want.update(num_local_experts=cfg.moe.num_experts,
-                    num_experts_per_tok=cfg.moe.num_experts_per_tok,
-                    intermediate_size=cfg.moe.d_ff_expert)
-    else:
-        want["intermediate_size"] = cfg.d_ff
-    bad = {k: (v, conf.get(k)) for k, v in want.items() if conf.get(k) != v}
+    source = _source(conf)
+    want = _widths(cfg, conf)
+    bad = {k: (v, source(k)) for k, v in want.items() if source(k) != v}
+    moe = cfg.moe
+    if not bad and moe is not None and moe.num_shared_experts and \
+            moe.d_ff_shared != moe.num_shared_experts * moe.d_ff_expert:
+        bad["shared experts' width"] = (moe.d_ff_shared, moe.num_shared_experts
+                                        * moe.d_ff_expert)
     if bad:
         raise BenchError(f"registered {conf['arch']} differs from "
                          f"{conf['name']}.json: {bad}")
+    _check_floors(conf)
+    program = conf.get("program", {})
+    answers = set(conf.get("reduced", [])) | set(conf.get("assumed", {}))
+    for field, key in program.items():
+        if key not in answers or key not in conf:
+            raise BenchError(f"program field {field!r} takes {key!r}, which "
+                             f"{conf['name']}.json does not list in reduced "
+                             f"or assumed with a value")
+        cfg = _set_field(cfg, field.split("."), conf[key], field)
+    untaken = [k for k in conf.get("reduced", [])
+               if k in want and k not in program.values()]
+    if untaken:
+        raise BenchError(f"{conf['name']}.json reduces {untaken} but no "
+                         f"program field takes them")
     cfg = dataclasses.replace(
         cfg, norm_eps=float(conf["rms_norm_eps"]),
-        attention=dataclasses.replace(a, rope_theta=float(conf["rope_theta"])),
+        attention=dataclasses.replace(cfg.attention,
+                                      rope_theta=float(conf["rope_theta"])),
         param_dtype=traffic.get("param_dtype", "bfloat16"),
         compute_dtype=traffic.get("compute_dtype", "bfloat16"))
     return cfg

@@ -1,7 +1,7 @@
 """The serve step's model FLOPs (active parameters x 2 per slot in use,
-and attention over the live lengths) over its device time and the bf16
-peak."""
-import flops
+and attention over the live lengths, from the configuration's
+``decode_step_flops``, ``harness.counts_for``) over its device time and
+the bf16 peak."""
 import trace_reduce
 
 from importlib import util as _u
@@ -17,7 +17,8 @@ def read(ctx):
     sec, n = trace_reduce.module_time(ctx.trace, r"jit_serve_step")
     if not n or not sec:
         return None
-    work = [flops.decode_step_flops(ctx.config, x) for x in _roof.calls(ctx)]
+    work = [ctx.counts.decode_step_flops(ctx.config, x)
+            for x in _roof.calls(ctx)]
     if not work:
         return None
     per_call = sum(work) / len(work)

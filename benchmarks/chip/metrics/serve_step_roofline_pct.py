@@ -1,9 +1,9 @@
 """The serve step's share of its roofline: for every call in the traced
 window, the least time the chip needs (the larger of the FLOPs over the
-bf16 peak and the bytes over the HBM bandwidth, from ``flops.py``: weights
-read once, the cache at each slot's live length), summed, over the device
-time of the jitted serve step."""
-import flops
+bf16 peak and the bytes over the HBM bandwidth, from the configuration's
+counts, ``harness.counts_for``: weights read once, the cache at each
+slot's live length), summed, over the device time of the jitted serve
+step."""
 import trace_reduce
 
 
@@ -22,9 +22,9 @@ def read(ctx):
     sec, n = trace_reduce.module_time(ctx.trace, r"jit_serve_step")
     if not n or not sec:
         return None
-    c, pk = ctx.config, ctx.peak
-    need = sum(max(flops.decode_step_flops(c, x) / pk["bf16_flops"],
-                   flops.decode_step_bytes(c, x) / pk["hbm_bytes_per_s"])
+    c, pk, counts = ctx.config, ctx.peak, ctx.counts
+    need = sum(max(counts.decode_step_flops(c, x) / pk["bf16_flops"],
+                   counts.decode_step_bytes(c, x) / pk["hbm_bytes_per_s"])
                for x in calls(ctx))
     per_call = need / max(sum(1 for _ in calls(ctx)), 1)
     return 100.0 * per_call * n / sec

@@ -1,8 +1,7 @@
 """Whole train step's share of the chip's bf16 peak: the FLOPs the step
-needs (``flops.train_step_flops``: forward and backward, causal attention,
-no rematerialisation) over the device time of the jitted train step in the
-trace, per call."""
-import flops
+needs (the configuration's ``train_step_flops``, ``harness.counts_for``:
+forward and backward, causal attention, no rematerialisation) over the
+device time of the jitted train step in the trace, per call."""
 import trace_reduce
 
 
@@ -11,5 +10,5 @@ def read(ctx):
     if not calls or not sec:
         return None
     t = ctx.traffic
-    need = flops.train_step_flops(ctx.config, t["batch"], t["seq_len"])
+    need = ctx.counts.train_step_flops(ctx.config, t["batch"], t["seq_len"])
     return 100.0 * need * calls / sec / ctx.peak["bf16_flops"]

@@ -11,9 +11,10 @@ enters the TTFT sample at that limit.
 
 The check runs after the window and after the program's state is freed:
 for a sample of finished requests drawn from the seed (the longest among
-them), the reference reads the prompt and the served tokens, and ``gap``
-is the widest amount by which a served token's logit lies below the
-reference's best at its position.
+them), the configuration's reference (``harness.reference_for``) reads
+the prompt and the served tokens, and ``gap`` is the widest amount by
+which a served token's logit lies below the reference's best at its
+position.
 """
 from __future__ import annotations
 
@@ -28,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import harness
-import reference
 import traffic_gen
 import weights
 
@@ -193,13 +193,15 @@ def _padded(tokens, rows, chosen, length: int):
 
 
 def reference_gaps(cell, shapes, seed: int, served, max_len: int,
-                   mm=reference.highest_mm) -> Dict:
+                   mm=None) -> Dict:
     """For each (prompt, served tokens): the gap of every served token
     under the reference over prompt + served tokens (``gap``), and, as a
     diagnostic, over the sequence the server's loop fed its model, which
-    repeats the prompt's last token (``gap_fed``).  With ``mm`` =
-    ``reference.fp8_mm``, ``control`` is the gap, under the float32
+    repeats the prompt's last token (``gap_fed``).  With ``mm`` = the
+    reference's ``fp8_mm``, ``control`` is the gap, under the float32
     reference, of the token the lower precision puts first."""
+    reference = harness.reference_for(cell.config, cell.here)
+    mm = mm or reference.highest_mm
     rc = reference.RefConfig.from_file(cell.config)
     out = {"gap": 0.0, "gap_fed": 0.0, "control": 0.0}
     if not served:

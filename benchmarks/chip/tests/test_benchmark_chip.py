@@ -149,8 +149,10 @@ def test_train_batches_seeded_and_distinct():
 
 def test_new_config_mix_and_metric_found_by_name(tmp_path):
     """A new cell is new files plus BENCHMARK.json entries: the harness
-    finds the configuration, mix, limits and reader by name alone."""
-    for d in ("configs", "traffic", "limits", "metrics"):
+    finds the configuration, mix, limits, reader, reference and counts by
+    name alone."""
+    for d in ("configs", "traffic", "limits", "metrics", "references",
+              "counts"):
         (tmp_path / d).mkdir()
     (tmp_path / "configs" / "tiny-model.json").write_text(
         json.dumps({"name": "tiny-model", "hidden_size": 8}))
@@ -160,6 +162,10 @@ def test_new_config_mix_and_metric_found_by_name(tmp_path):
         json.dumps({"limits": {"gap": 0.5}}))
     (tmp_path / "metrics" / "new_metric.py").write_text(
         "def read(ctx):\n    return 2.0 * ctx\n")
+    (tmp_path / "references" / "tiny-model.py").write_text(
+        "from reference import *  # noqa: F401,F403\nOWN = 'ref'\n")
+    (tmp_path / "counts" / "tiny-model.py").write_text(
+        "def train_step_flops(c, batch, seq):\n    return 7.0\n")
     bench = {"workloads": [{"name": "tiny-model.bursty_mix",
                             "config": "tiny-model", "traffic": "bursty_mix",
                             "chips": 1, "why": "x"}],
@@ -175,6 +181,10 @@ def test_new_config_mix_and_metric_found_by_name(tmp_path):
     assert [m["name"] for m in cell.end_to_end] == ["e2e", "setup_s"]
     assert [m["name"] for m in cell.per_layer] == ["new_metric"]
     assert harness.metric_reader("new_metric", here=tmp_path)(4) == 8.0
+    ref = harness.reference_for(cell.config, cell.here)
+    assert ref.OWN == "ref" and callable(ref.RefConfig.from_file)
+    assert harness.counts_for(cell.config, cell.here).train_step_flops(
+        cell.config, 1, 8) == 7.0
 
 
 def test_every_listed_file_exists():
@@ -291,10 +301,10 @@ def test_reference_matches_program_forward(arch):
     import jax.numpy as jnp
     from repro.models import api
 
-    import reference
     import weights
 
     conf = _smoke(arch)
+    reference = harness.reference_for(conf)
     cfg = harness.program_config(conf, {"param_dtype": "float32",
                                         "compute_dtype": "float32"})
     if cfg.moe:     # the program's dropless option, as the reference is
@@ -392,7 +402,6 @@ def test_control_is_not_correct(cache_dir):
     from repro.models import api
 
     import correctness
-    import reference
     import train_loop
 
     cell = _train_cell()
@@ -401,8 +410,9 @@ def test_control_is_not_correct(cache_dir):
     batches = [traffic_gen.train_batch(cell.traffic, 5, i, 2,
                                        cfg.vocab_size) for i in range(3)]
     ref = train_loop.reference_readings(cell, shapes, 5, batches)
-    ctrl = train_loop.reference_readings(cell, shapes, 5, batches,
-                                           mm=reference.fp8_mm)
+    ctrl = train_loop.reference_readings(
+        cell, shapes, 5, batches,
+        mm=harness.reference_for(cell.config).fp8_mm)
     ok, rows = correctness.judge(correctness.train_readings(ctrl, ref),
                                  cell.limits["limits"])
     assert not ok, rows

@@ -9,8 +9,9 @@ the Adam state after step 1, and the parameters' change after step 3.  The
 window then continues the same object from step 4.
 
 After the window and after the peak memory is read, the program's state is
-freed and the reference repeats the three steps from the same weights, in
-float32 at the highest precision.
+freed and the configuration's reference (``harness.reference_for``)
+repeats the three steps from the same weights, in float32 at the highest
+precision.
 """
 from __future__ import annotations
 
@@ -25,7 +26,6 @@ import numpy as np
 
 import correctness
 import harness
-import reference
 import traffic_gen
 import weights
 
@@ -145,10 +145,12 @@ def readings(rec) -> Dict:
 
 
 def reference_readings(cell, shapes, seed: int, host_batches,
-                       mm=reference.highest_mm) -> Dict:
+                       mm=None) -> Dict:
     """The reference's three steps from the weights ``seed`` makes, in
-    float32 (``mm`` = ``reference.fp8_mm`` gives the control)."""
+    float32 (``mm`` = the reference's ``fp8_mm`` gives the control)."""
     t = cell.traffic
+    reference = harness.reference_for(cell.config, cell.here)
+    mm = mm or reference.highest_mm
     rc = reference.RefConfig.from_file(cell.config)
     f32_shapes = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32), shapes)
