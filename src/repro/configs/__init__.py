@@ -2,6 +2,7 @@
 architecture with repro.core.config's registry (``--arch <id>``)."""
 from repro.configs import (  # noqa: F401
     deepseek_v2_236b,
+    deepseek_v2_lite,
     dilated_vgg,
     granite_moe_1b_a400m,
     internvl2_2b,

@@ -18,6 +18,22 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 
 @dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension (``rope_scaling`` of type ``yarn``, as
+    DeepSeek-V2 states it): rotary frequencies interpolated by a linear
+    ramp between the correction dims of ``beta_fast`` and ``beta_slow`` at
+    ``original_max_position_embeddings``, and the softmax scale multiplied
+    by ``mscale(factor, mscale_all_dim)**2``."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclass(frozen=True)
 class AttentionConfig:
     """Multi-head attention description (GQA or MLA)."""
 
@@ -27,6 +43,7 @@ class AttentionConfig:
     head_dim: int = 64
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[RopeScaling] = None
     # MLA (DeepSeek-V2) parameters; only read when kind == "mla".
     q_lora_rank: int = 0          # 0 => no query compression
     kv_lora_rank: int = 512
@@ -65,7 +82,16 @@ class MoEConfig:
     router_dtype: str = "float32"
     router_noise: float = 0.0
     aux_loss_coef: float = 0.001
-    capacity_factor: float = 1.25   # <=0 means dropless (C = S*K)
+    capacity_factor: float = 1.25   # <=0: dropless, grouped by expert
+    # renormalise the top-k gates to sum 1; False needs the dropless MoE
+    norm_topk_prob: bool = True
+    # experts [0, experts_held) live here (expert parallelism); 0 = all.
+    # The router keeps all num_experts outputs.  Dropless only.
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)

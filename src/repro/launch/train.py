@@ -114,8 +114,12 @@ def main(argv=None):
                 sup.heartbeat(0, step_i, dt)
                 losses.append(loss)
                 if (step_i + 1) % args.log_every == 0:
+                    moe = (f"held {float(metrics['moe_held_assignments']):.0f}"
+                           f"  max load "
+                           f"{float(metrics['moe_max_load']):5.2f}  "
+                           if "moe_held_assignments" in metrics else "")
                     print(f"step {step_i + 1:5d}  loss {loss:8.4f}  "
-                          f"gnorm {float(metrics['grad_norm']):7.3f}  "
+                          f"gnorm {float(metrics['grad_norm']):7.3f}  {moe}"
                           f"lr {float(metrics['lr']):.2e}  {dt * 1e3:7.1f} ms")
                 if (step_i + 1) % args.ckpt_every == 0:
                     ckpt.save(step_i + 1,

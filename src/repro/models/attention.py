@@ -147,16 +147,27 @@ def mla_cache_spec(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]
     }
 
 
-def _mla_q(p: Params, x, a: AttentionConfig, cd) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _mla_q(p: Params, x, a: AttentionConfig, cd,
+           eps: float) -> Tuple[jnp.ndarray, jnp.ndarray]:
     B, S, _ = x.shape
     qk_dim = a.qk_nope_head_dim + a.qk_rope_head_dim
     if "wq_a" in p:
-        ql = L.apply_norm(p["q_norm"], L.linear(p["wq_a"], x, cd))
+        ql = L.apply_norm(p["q_norm"], L.linear(p["wq_a"], x, cd), eps)
         q = L.linear(p["wq_b"], ql, cd)
     else:
         q = L.linear(p["wq"], x, cd)
     q = q.reshape(B, S, a.num_heads, qk_dim)
     return q[..., :a.qk_nope_head_dim], q[..., a.qk_nope_head_dim:]
+
+
+def mla_softmax_scale(a: AttentionConfig) -> float:
+    """(qk_nope + qk_rope)^-1/2, times mscale(factor, mscale_all_dim)^2
+    under YaRN (DeepSeek-V2's ``softmax_scale``)."""
+    scale = 1.0 / math.sqrt(a.qk_nope_head_dim + a.qk_rope_head_dim)
+    s = a.rope_scaling
+    if s is not None and s.mscale_all_dim:
+        scale *= L.yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
 
 
 @jax.named_scope(scopes.ATTN_PROJ)
@@ -174,18 +185,19 @@ def apply_mla(p: Params, x: jnp.ndarray, cfg: ModelConfig, *, mode: str,
     else:
         positions = jnp.arange(S)[None, :]
 
-    q_nope, q_rope = _mla_q(p, x, a, cd)
-    q_rope = L.apply_rope(q_rope, positions, a.rope_theta)
+    q_nope, q_rope = _mla_q(p, x, a, cd, cfg.norm_eps)
+    q_rope = L.apply_rope(q_rope, positions, a.rope_theta, a.rope_scaling)
 
     kv_a = L.linear(p["wkv_a"], x, cd)
-    ckv = L.apply_norm(p["kv_norm"], kv_a[..., :a.kv_lora_rank])
+    ckv = L.apply_norm(p["kv_norm"], kv_a[..., :a.kv_lora_rank], cfg.norm_eps)
     krope = kv_a[..., a.kv_lora_rank:][:, :, None, :]       # (B,S,1,rope)
-    krope = L.apply_rope(krope, positions, a.rope_theta)[:, :, 0, :]
+    krope = L.apply_rope(krope, positions, a.rope_theta,
+                         a.rope_scaling)[:, :, 0, :]
 
     wkv_b = p["wkv_b"]["w"].astype(cd).reshape(a.kv_lora_rank, H, nope + vdim)
     w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
 
-    scale = 1.0 / math.sqrt(nope + rope)
+    scale = mla_softmax_scale(a)
 
     if mode == "decode":
         assert cache is not None and S == 1
@@ -242,7 +254,7 @@ def apply_mla(p: Params, x: jnp.ndarray, cfg: ModelConfig, *, mode: str,
         v = v.transpose(0, 2, 1, 3)
         q = constrain(q, ("batch", "heads", "seq", None))
         with jax.named_scope(scopes.ATTN_CORE):
-            out = L.attention(q, k, v, causal=causal)
+            out = L.attention(q, k, v, causal=causal, scale=scale)
         out = out.transpose(0, 2, 1, 3).reshape(B, S, H * vdim)
         new_cache = {"ckv": ckv, "krope": krope} if mode == "prefill" else None
 

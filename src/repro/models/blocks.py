@@ -107,10 +107,10 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                 mixer: str, ffn: str, *, mode: str,
                 cache: Optional[Params] = None, pos=None,
                 causal: bool = True,
-                ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
-    """Returns (x, new_cache, aux_loss)."""
+                ) -> Tuple[jnp.ndarray, Optional[Params], Dict]:
+    """Returns (x, new_cache, ``L.moe_stats``)."""
     cd = L.dtype_of(cfg.compute_dtype)
-    aux = jnp.zeros((), jnp.float32)
+    stats = L.moe_stats()
     new_cache: Params = {}
 
     h = L.apply_norm(p["norm1"], x, cfg.norm_eps)
@@ -141,7 +141,7 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
         if ffn == "dense":
             y = L.apply_ffn(p["ffn"], h, cfg.act, cd)
         elif ffn == "moe":
-            y, aux = L.apply_moe(p["ffn_moe"], h, cfg, compute_dtype=cd)
+            y, stats = L.apply_moe(p["ffn_moe"], h, cfg, compute_dtype=cd)
         else:  # rwkv channel mix
             y, c = R6.apply_channel_mix(
                 p["rwkv_cm"], h, cfg, mode=mode,
@@ -149,7 +149,7 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
             if c is not None:
                 new_cache.setdefault("rwkv_tm", {}).update(c)
     x = x + y.astype(x.dtype)
-    return x, (new_cache if new_cache else None), aux
+    return x, (new_cache if new_cache else None), stats
 
 
 # ---------------------------------------------------------------------------
@@ -204,13 +204,14 @@ def _remat_wrap(fn, remat: str):
 def apply_stack(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
                 mode: str, cache: Optional[Params] = None, pos=None,
                 causal: bool = True, remat: str = "dots",
-                ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
+                ) -> Tuple[jnp.ndarray, Optional[Params], Dict]:
     """Run prefix blocks then the scanned periods.
 
-    Returns (x, new_cache (same structure as cache, or None), total_aux).
+    Returns (x, new_cache (same structure as cache, or None), the layers'
+    ``L.moe_stats`` merged).
     """
     prefix, period, n_periods = block_pattern(cfg)
-    total_aux = jnp.zeros((), jnp.float32)
+    total = L.moe_stats()
     new_cache: Params = {}
 
     if prefix:
@@ -218,9 +219,14 @@ def apply_stack(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         for i, (m, f) in enumerate(prefix):
             blk = params["prefix"][f"blk{i}"]
             c_in = None if cache is None else cache["prefix"][f"blk{i}"]
-            x, c, aux = apply_block(blk, x, cfg, m, f, mode=mode,
-                                    cache=c_in, pos=pos, causal=causal)
-            total_aux += aux
+            # in training a prefix block is rematerialised as a period is
+            run = _remat_wrap(
+                lambda blk, x, c_in, m=m, f=f: apply_block(
+                    blk, x, cfg, m, f, mode=mode, cache=c_in, pos=pos,
+                    causal=causal),
+                remat if mode == "train" else "none")
+            x, c, stats = run(blk, x, c_in)
+            total = L.merge_moe_stats(total, stats)
             if c is not None:
                 pc[f"blk{i}"] = c
         if pc:
@@ -229,21 +235,22 @@ def apply_stack(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     def period_fn(x, scanned):
         p_params, p_cache = scanned
         caches_out = {}
-        aux_sum = jnp.zeros((), jnp.float32)
+        period_stats = L.moe_stats()
         for j, (m, f) in enumerate(period):
             c_in = None if p_cache is None else p_cache[f"sub{j}"]
-            x, c, aux = apply_block(p_params[f"sub{j}"], x, cfg, m, f,
-                                    mode=mode, cache=c_in, pos=pos,
-                                    causal=causal)
-            aux_sum += aux
+            x, c, stats = apply_block(p_params[f"sub{j}"], x, cfg, m, f,
+                                      mode=mode, cache=c_in, pos=pos,
+                                      causal=causal)
+            period_stats = L.merge_moe_stats(period_stats, stats)
             if c is not None:
                 caches_out[f"sub{j}"] = c
-        return x, (caches_out if caches_out else None, aux_sum)
+        return x, (caches_out if caches_out else None, period_stats)
 
     body = _remat_wrap(period_fn, remat if mode == "train" else "none")
     xs = (params["periods"], cache["periods"] if cache is not None else None)
-    x, (period_caches, auxes) = jax.lax.scan(body, x, xs)
-    total_aux += jnp.sum(auxes)
+    x, (period_caches, per) = jax.lax.scan(body, x, xs)
+    total = L.merge_moe_stats(total, L.moe_stats(
+        jnp.sum(per["aux"]), jnp.sum(per["held"]), jnp.max(per["max_load"])))
     if period_caches is not None:
         new_cache["periods"] = period_caches
-    return x, (new_cache if new_cache else None), total_aux
+    return x, (new_cache if new_cache else None), total

@@ -14,8 +14,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.config import AttentionConfig, MoEConfig, ModelConfig
+from repro.core.config import (AttentionConfig, MoEConfig, ModelConfig,
+                               RopeScaling)
+from repro.kernels import grouped_matmul
 from repro.kernels.flash_attention import train as flash_train
+from repro.models import scopes
 from repro.sharding import active_mesh
 
 Params = Dict[str, Any]
@@ -98,13 +101,53 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_correction_dim(rotations: float, head_dim: int, theta: float,
+                         max_pos: int) -> float:
+    return head_dim * math.log(max_pos / (rotations * 2 * math.pi)) \
+        / (2 * math.log(theta))
+
+
+def yarn_freqs(head_dim: int, theta: float,
+               s: RopeScaling) -> Tuple[jnp.ndarray, float]:
+    """YaRN's inverse frequencies (hd/2,) and the factor on cos and sin, as
+    DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding`` computes them: the
+    interpolated frequencies (divided by ``factor``) below the correction
+    dim of ``beta_slow``, the original ones above that of ``beta_fast``,
+    a linear ramp between."""
+    expo = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    extra = 1.0 / (theta ** expo)
+    inter = 1.0 / (s.factor * theta ** expo)
+    dim = partial(_yarn_correction_dim, head_dim=head_dim, theta=theta,
+                  max_pos=s.original_max_position_embeddings)
+    low = max(math.floor(dim(s.beta_fast)), 0)
+    high = min(math.ceil(dim(s.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp               # share of the original frequency
+    mult = yarn_mscale(s.factor, s.mscale) \
+        / yarn_mscale(s.factor, s.mscale_all_dim)
+    return inter * (1.0 - keep) + extra * keep, mult
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling: Optional[RopeScaling] = None) -> jnp.ndarray:
     """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if scaling is None:
+        freqs, mult = rope_freqs(hd, theta), 1.0        # (hd/2,)
+    else:
+        freqs, mult = yarn_freqs(hd, theta, scaling)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     angles = angles[..., None, :]                       # (..., S, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if mult != 1.0:
+        cos, sin = cos * mult, sin * mult
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -134,19 +177,21 @@ def _attn_block(q, k, v, mask, scale):
 def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       *, causal: bool, q_offset: int = 0,
                       chunk_q: int = 512, chunk_k: int = 1024,
-                      kv_len: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                      kv_len: Optional[jnp.ndarray] = None,
+                      scale: Optional[float] = None) -> jnp.ndarray:
     """Online-softmax attention.
 
     q: (B, Hq, Sq, hd);  k, v: (B, Hkv, Sk, hd) with Hq % Hkv == 0 (GQA).
     ``q_offset``: absolute position of q[0] (for decode / chunked prefill).
     ``kv_len``: optional (B,) actual kv lengths (decode with ragged cache).
+    ``scale``: the scores' factor, 1/sqrt(hd) where None.
     Returns (B, Hq, Sq, hd) in q.dtype.
     """
     B, Hq, Sq, hd = q.shape
     _, Hkv, Sk, _ = k.shape
     vd = v.shape[-1]
     group = Hq // Hkv
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     # broadcast kv heads to q heads (XLA fuses this; no materialised copy
     # thanks to the einsum below operating per kv-head group)
     qg = q.reshape(B, Hkv, group, Sq, hd)
@@ -211,7 +256,7 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def full_attention(q, k, v, *, causal: bool, q_offset: int = 0,
-                   kv_len=None) -> jnp.ndarray:
+                   kv_len=None, scale: Optional[float] = None) -> jnp.ndarray:
     """Reference full-materialisation attention (small shapes only)."""
     B, Hq, Sq, hd = q.shape
     _, Hkv, Sk, _ = k.shape
@@ -219,7 +264,8 @@ def full_attention(q, k, v, *, causal: bool, q_offset: int = 0,
     group = Hq // Hkv
     qg = q.reshape(B, Hkv, group, Sq, hd)
     s = jnp.einsum("bngqd,bnkd->bngqk", qg, k,
-                   preferred_element_type=jnp.float32) / math.sqrt(hd)
+                   preferred_element_type=jnp.float32)
+    s = s / math.sqrt(hd) if scale is None else s * scale
     q_pos = q_offset + jnp.arange(Sq)
     k_pos = jnp.arange(Sk)
     mask = jnp.ones((Sq, Sk), bool)
@@ -238,32 +284,35 @@ def full_attention(q, k, v, *, causal: bool, q_offset: int = 0,
 
 
 def uses_flash_kernel(q, k, v, *, causal: bool, q_offset: int = 0,
-                      kv_len=None) -> bool:
+                      kv_len=None, scale: Optional[float] = None) -> bool:
     """Whether ``attention`` runs the causal flash kernel on a TPU: causal
-    bf16 self-attention from position 0 over the whole sequence, equal head
-    dims the kernel supports, on at most one device (a Mosaic kernel is not
-    partitioned across a mesh)."""
+    bf16 self-attention from position 0 over the whole sequence, scaled by
+    1/sqrt(hd), equal head dims the kernel supports, on at most one device
+    (a Mosaic kernel is not partitioned across a mesh)."""
     (_, hq, sq, hd), (_, hkv, sk, _) = q.shape, k.shape
     mesh = active_mesh()
     return (causal and isinstance(q_offset, int) and q_offset == 0
-            and kv_len is None and sq == sk and v.shape[-1] == hd
+            and kv_len is None and scale is None and sq == sk
+            and v.shape[-1] == hd
             and hq % hkv == 0 and flash_train.supported(sq, hd)
             and all(x.dtype == jnp.bfloat16 for x in (q, k, v))
             and (mesh is None or mesh.size == 1))
 
 
 def attention(q, k, v, *, causal: bool, q_offset: int = 0, kv_len=None,
+              scale: Optional[float] = None,
               chunked_threshold: int = 1024) -> jnp.ndarray:
     """Dispatch: full softmax for short sequences; for long ones the causal
     flash kernel where ``uses_flash_kernel`` and the program is lowered for
-    a TPU, else online-softmax."""
+    a TPU, else online-softmax.  ``scale``: the scores' factor, 1/sqrt(hd)
+    where None."""
     if q.shape[2] * k.shape[2] <= chunked_threshold ** 2:
         return full_attention(q, k, v, causal=causal, q_offset=q_offset,
-                              kv_len=kv_len)
+                              kv_len=kv_len, scale=scale)
     scan = partial(chunked_attention, causal=causal, q_offset=q_offset,
-                   kv_len=kv_len)
+                   kv_len=kv_len, scale=scale)
     if uses_flash_kernel(q, k, v, causal=causal, q_offset=q_offset,
-                         kv_len=kv_len):
+                         kv_len=kv_len, scale=scale):
         # the branch is chosen when the program is lowered for its platform
         return jax.lax.platform_dependent(
             q, k, v, tpu=flash_train.causal_flash_attention, default=scan)
@@ -300,22 +349,27 @@ def apply_ffn(p: Params, x: jnp.ndarray, act: str,
 
 
 # ---------------------------------------------------------------------------
-# Mixture of Experts — grouped, capacity-based, einsum dispatch/combine.
+# Mixture of Experts.
 #
-# The (group, seq, expert, capacity) dispatch tensors reshard under GSPMD
-# into all-to-alls when experts live on the "model" mesh axis (expert
-# parallelism); see repro.sharding.  Dropped tokens (over capacity) simply
-# contribute zero, standard Switch/T5X semantics.
+# Two paths.  With a capacity factor (> 0): grouped, capacity-based,
+# einsum dispatch/combine; the (group, seq, expert, capacity) dispatch
+# tensors reshard under GSPMD into all-to-alls when experts live on the
+# "model" mesh axis (expert parallelism, see repro.sharding), and tokens
+# over capacity contribute zero (Switch/T5X).  Dropless (<= 0): the
+# assignments are sorted by expert and the experts held here
+# (``MoEConfig.experts_held``) run grouped matmuls over their own rows
+# only, so the work scales with the rows routed to them.
 # ---------------------------------------------------------------------------
 
 
 def init_moe(key, cfg: ModelConfig, dtype) -> Params:
     m = cfg.moe
     keys = jax.random.split(key, 5)
-    d, f, e = cfg.d_model, m.d_ff_expert, m.num_experts
+    d, f, e = cfg.d_model, m.d_ff_expert, m.held
     scale_in = 1.0 / math.sqrt(d)
     p = {
-        "router": init_linear(keys[0], d, e, jnp.float32, scale=scale_in),
+        "router": init_linear(keys[0], d, m.num_experts, jnp.float32,
+                              scale=scale_in),
         "w_up": (jax.random.normal(keys[1], (e, d, f)) * scale_in).astype(dtype),
         "w_gate": (jax.random.normal(keys[2], (e, d, f)) * scale_in).astype(dtype),
         "w_down": (jax.random.normal(keys[3], (e, f, d)) / math.sqrt(f)).astype(dtype),
@@ -338,22 +392,55 @@ MOE_GROUP_SIZE = 4096   # routing-group tokens; capacity scales with the
 #                         made dispatch 10-50x the expert FLOPs)
 
 
+def moe_stats(aux=0.0, held=0.0, max_load=0.0) -> Dict[str, jnp.ndarray]:
+    """What an FFN reports besides its output: the balance loss, the
+    assignments routed to the experts held, and the largest held expert's
+    assignments over the mean held expert's (0 for a dense FFN)."""
+    f32 = jnp.float32
+    return {"aux": jnp.asarray(aux, f32), "held": jnp.asarray(held, f32),
+            "max_load": jnp.asarray(max_load, f32)}
+
+
+def merge_moe_stats(a, b):
+    """Over layers: losses and assignments add, the worst load is kept."""
+    return {"aux": a["aux"] + b["aux"], "held": a["held"] + b["held"],
+            "max_load": jnp.maximum(a["max_load"], b["max_load"])}
+
+
+def _load_stats(aux, counts):
+    """``moe_stats`` from the assignments of each expert held."""
+    counts = counts.astype(jnp.float32)
+    return moe_stats(aux, jnp.sum(counts),
+                     jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9))
+
+
 def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig,
               capacity_factor: Optional[float] = None,
-              compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (G, S, D) groups of tokens. Returns (out, aux_loss)."""
+              compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Dict]:
+    """x: (G, S, D) groups of tokens. Returns (out, ``moe_stats``)."""
     m = cfg.moe
+    cf = m.capacity_factor if capacity_factor is None else capacity_factor
+    if cf <= 0:
+        y, stats = dropless_moe(p, x, m, compute_dtype)
+    else:
+        y, stats = _capacity_moe(p, x, m, cf, compute_dtype)
+    if "shared" in p:
+        y = y + apply_ffn(p["shared"], x, "swiglu", compute_dtype)
+    return y, stats
+
+
+def _capacity_moe(p: Params, x: jnp.ndarray, m: MoEConfig, cf: float,
+                  compute_dtype):
+    if m.held != m.num_experts or not m.norm_topk_prob:
+        raise ValueError("experts_held and norm_topk_prob=False need the "
+                         "dropless MoE (capacity_factor <= 0)")
     G0, S0, D = x.shape
     # re-group long sequences into fixed-size routing groups
     if S0 > MOE_GROUP_SIZE and S0 % MOE_GROUP_SIZE == 0:
         x = x.reshape(G0 * (S0 // MOE_GROUP_SIZE), MOE_GROUP_SIZE, D)
     G, S, D = x.shape
     E, K = m.num_experts, m.num_experts_per_tok
-    cf = m.capacity_factor if capacity_factor is None else capacity_factor
-    if cf <= 0:
-        C = S * K                      # dropless
-    else:
-        C = moe_capacity(S, E, K, cf)
+    C = moe_capacity(S, E, K, cf)
 
     logits = jnp.einsum("gsd,de->gse", x.astype(jnp.float32),
                         p["router"]["w"].astype(jnp.float32))
@@ -399,9 +486,6 @@ def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     ye = constrain(ye, ("expert", "batch", None, None))
     y = jnp.einsum("gsec,egcd->gsd", combine.astype(compute_dtype), ye,
                    preferred_element_type=jnp.float32).astype(compute_dtype)
-
-    if "shared" in p:
-        y = y + apply_ffn(p["shared"], x, "swiglu", compute_dtype)
     if (G, S) != (G0, S0):
         y = y.reshape(G0, S0, D)
 
@@ -409,7 +493,94 @@ def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     density = jnp.mean(jnp.sum(onehot, axis=2), axis=(0, 1))      # (E,)
     router_prob = jnp.mean(probs, axis=(0, 1))                    # (E,)
     aux = E * jnp.sum(density / K * router_prob)
-    return y, aux
+    return y, _load_stats(aux, jnp.sum(onehot, axis=(0, 1, 2)))
+
+
+# The assignments of T tokens to K experts each, as n = T * K rows sorted by
+# expert: row r holds token order[r] // K, and assignment a sits at row
+# inverse[a].  Dispatch and combine are each other's transpose; both are
+# gathers, so neither pass scatters.
+
+
+def _dispatch_rows(x, order, inverse, k: int):
+    """(T, D) tokens -> (n, D) rows in expert order."""
+    return jnp.take(x, order // k, axis=0)
+
+
+def _combine_rows(y, order, inverse, k: int):
+    """(n, D) rows -> (T, D): each token's K rows summed in float32."""
+    t = y.shape[0] // k
+    return jnp.sum(jnp.take(y, inverse, axis=0).reshape(t, k, -1)
+                   .astype(jnp.float32), axis=1)
+
+
+def _rows_vjp(fwd, bwd):
+    f = jax.custom_vjp(fwd, nondiff_argnums=(3,))
+    # the residual's empty array carries x's dtype for the cotangent
+    f.defvjp(lambda x, o, i, k: (fwd(x, o, i, k), (o, i, x[:0])),
+             lambda k, res, g: (bwd(g, res[0], res[1], k).astype(
+                 res[2].dtype), None, None))
+    return f
+
+
+dispatch_rows = _rows_vjp(_dispatch_rows, _combine_rows)
+combine_rows = _rows_vjp(_combine_rows, _dispatch_rows)
+
+
+def _grouped_swiglu(p: Params, rows, group_sizes, cd):
+    """SwiGLU of each held expert over its rows, which ``group_sizes``
+    count off in order; rows past the last group are not computed (and
+    may hold anything)."""
+    mm = partial(grouped_matmul.grouped_matmul, group_sizes=group_sizes)
+    gate = mm(rows, p["w_gate"].astype(cd), tiling=grouped_matmul.TILING_IN)
+    up = mm(rows, p["w_up"].astype(cd), tiling=grouped_matmul.TILING_IN)
+    h = (jax.nn.silu(gate) * up).astype(cd)
+    return mm(h, p["w_down"].astype(cd), tiling=grouped_matmul.TILING_OUT)
+
+
+def dropless_moe(p: Params, x: jnp.ndarray, m: MoEConfig, cd):
+    """Top-k MoE that drops nothing, over the experts held (no shared
+    expert).
+
+    The router keeps all ``num_experts`` outputs: softmax, greedy top-k,
+    gates renormalised where ``norm_topk_prob``.  The (token, choice)
+    assignments are sorted by expert; those of the experts held,
+    ``[0, experts_held)``, come first, and grouped matmuls run over them
+    alone.  The weighted results
+    go back to their tokens (the other experts' part is left out, as on
+    the chip that holds only these).  The balance loss is DeepSeek's
+    sequence-wise one, E/(S K) * sum_e count_e * mean_s p_e, averaged over
+    the sequences of x (B, S, D)."""
+    B, S, D = x.shape
+    E, K, H = m.num_experts, m.num_experts_per_tok, m.held
+    n = B * S * K
+    with jax.named_scope(scopes.MOE_ROUTE):
+        rd = dtype_of(m.router_dtype)
+        logits = jnp.einsum("bsd,de->bse", x.astype(rd),
+                            p["router"]["w"].astype(rd),
+                            preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                   # (B,S,E)
+        gate, idx = jax.lax.top_k(probs, K)                       # (B,S,K)
+        if m.norm_topk_prob:
+            gate = gate / jnp.maximum(jnp.sum(gate, -1, keepdims=True), 1e-9)
+        seq_counts = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32),
+                             axis=(1, 2))                          # (B,E)
+        aux = jnp.mean(jnp.sum(seq_counts * jnp.mean(probs, axis=1), -1)) \
+            * E / (S * K)
+        counts = jnp.sum(seq_counts, axis=0)[:H]
+        order = jnp.argsort(idx.reshape(n), stable=True)
+        inverse = jnp.argsort(order)
+        rows = dispatch_rows(x.astype(cd).reshape(B * S, D), order, inverse,
+                             K)
+        held = (jnp.arange(n) < jnp.sum(counts))[:, None]
+        rows = jnp.where(held, rows, 0)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        out = _grouped_swiglu(p, rows, counts.astype(jnp.int32), cd)
+    with jax.named_scope(scopes.MOE_ROUTE):
+        w = jnp.take(gate.reshape(n), order)[:, None]
+        out = (jnp.where(held, out, 0) * w).astype(cd)
+        y = combine_rows(out, order, inverse, K).reshape(B, S, D).astype(cd)
+    return y, _load_stats(aux, counts)
 
 
 # ---------------------------------------------------------------------------

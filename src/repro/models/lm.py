@@ -62,17 +62,18 @@ def forward(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray], *,
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full-sequence forward. Returns (logits, aux_loss)."""
     x = _embed_inputs(p, cfg, batch)
-    x, _, aux = B.apply_stack(p["stack"], x, cfg, mode="train", remat=remat)
+    x, _, stats = B.apply_stack(p["stack"], x, cfg, mode="train", remat=remat)
     x = L.apply_norm(p["final_norm"], x, cfg.norm_eps)
-    return _head(p, x, cfg), aux
+    return _head(p, x, cfg), stats["aux"]
 
 
 def hidden_states(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
-                  *, remat: str = "dots") -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Final-norm hidden states (pre-head). Returns (h, aux)."""
+                  *, remat: str = "dots") -> Tuple[jnp.ndarray, Dict]:
+    """Final-norm hidden states (pre-head). Returns (h, ``L.moe_stats``
+    merged over the layers)."""
     x = _embed_inputs(p, cfg, batch)
-    x, _, aux = B.apply_stack(p["stack"], x, cfg, mode="train", remat=remat)
-    return L.apply_norm(p["final_norm"], x, cfg.norm_eps), aux
+    x, _, stats = B.apply_stack(p["stack"], x, cfg, mode="train", remat=remat)
+    return L.apply_norm(p["final_norm"], x, cfg.norm_eps), stats
 
 
 def chunked_xent(p: Params, cfg: ModelConfig, h: jnp.ndarray,
@@ -111,8 +112,12 @@ def chunked_xent(p: Params, cfg: ModelConfig, h: jnp.ndarray,
 def loss_fn(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray], *,
             remat: str = "dots") -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Next-token cross-entropy (+ MoE aux); chunked head+xent keeps the
-    (B,S,V) logits tensor out of memory."""
-    h, aux = hidden_states(p, cfg, batch, remat=remat)
+    (B,S,V) logits tensor out of memory.  A MoE model's metrics also count
+    the assignments routed to the experts held (``moe_held_assignments``,
+    summed over the expert layers) and the worst layer's largest held
+    expert's load over its mean (``moe_max_load``)."""
+    h, stats = hidden_states(p, cfg, batch, remat=remat)
+    aux = stats["aux"]
     n_prefix = h.shape[1] - batch["tokens"].shape[1]
     if n_prefix > 0:
         h = h[:, n_prefix:]
@@ -122,7 +127,11 @@ def loss_fn(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray], *,
                         None if mask is None else mask[:, 1:])
     aux_coef = cfg.moe.aux_loss_coef if cfg.moe else 0.0
     total = loss + aux_coef * aux
-    return total, {"loss": loss, "aux": aux, "total": total}
+    metrics = {"loss": loss, "aux": aux, "total": total}
+    if cfg.moe:
+        metrics.update(moe_held_assignments=stats["held"],
+                       moe_max_load=stats["max_load"])
+    return total, metrics
 
 
 # ---------------------------------------------------------------------------

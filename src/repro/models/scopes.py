@@ -21,3 +21,10 @@ SSM = "ssm"                 # Mamba mixer
 RWKV = "rwkv"               # RWKV-6 time mix
 
 ALL = (ATTN_PROJ, ATTN_CORE, KV_WRITE, FFN, HEAD, OPTIMIZER, SSM, RWKV)
+
+# Inside FFN, the dropless MoE's two parts; an op under one of these is
+# under FFN too, so FFN's time keeps its meaning.
+MOE_ROUTE = "moe_route"     # router, top-k, balance loss, sort, permute, combine
+MOE_EXPERTS = "moe_experts"  # grouped matmuls and SwiGLU of the experts held
+
+NESTED = (MOE_ROUTE, MOE_EXPERTS)

@@ -1,13 +1,15 @@
 """The chip's side of the kernels and the steps, without a chip.
 
 Each kernel of ``repro.kernels``, the qwen1.5-0.5b decode step and its
-train step are compiled at real widths for a described TPU v5e: nothing
+train step, and the deepseek-v2-lite cell's train step are compiled at
+real widths for a described TPU v5e: nothing
 runs, but the
 chip's compiler refuses what would not lower (tiling, unsupported ops) or
 not fit in its memory.  The kernel wrappers must also refuse to run off a
 TPU unless asked for the Pallas interpreter.
 """
 import dataclasses
+import math
 import re
 
 import jax
@@ -27,6 +29,8 @@ from repro.kernels.ssm_scan.ops import ssm_scan, ssm_scan_op
 from repro.launch import common
 from repro.launch import steps as steps_lib
 from repro.models import api
+from repro.models import layers as L
+from repro.sharding import activation_rules
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -106,9 +110,26 @@ def _ssm_scan(s):
                       _on(s, (bz, di, ds)))
 
 
+def _grouped_swiglu(s):
+    # granite-moe-1b-a400m's experts (32 of 512 over d 1024), 4096 tokens x
+    # 8 choices, forward and backward: the tiles swept at deepseek-v2-lite's
+    # widths clamp to these
+    e, d, f, rows, bf = 32, 1024, 512, 4096 * 8, jnp.bfloat16
+
+    def fwd_bwd(x, w_gate, w_up, w_down, sizes):
+        def total(*a):
+            p = dict(zip(("w_gate", "w_up", "w_down"), a[1:]))
+            return jnp.sum(L._grouped_swiglu(p, a[0], sizes, bf))
+        return jax.grad(total, argnums=(0, 1, 2, 3))(x, w_gate, w_up, w_down)
+    return fwd_bwd, (_on(s, (rows, d), bf), _on(s, (e, d, f), bf),
+                     _on(s, (e, d, f), bf), _on(s, (e, f, d), bf),
+                     _on(s, (e,), jnp.int32))
+
+
 KERNELS = {"causal_flash_attention": _causal_flash_attention,
            "decode_attention": _decode_attention,
            "flash_attention": _flash_attention,
+           "grouped_swiglu": _grouped_swiglu,
            "rwkv6_scan": _rwkv6_scan,
            "ssm_scan": _ssm_scan}
 
@@ -168,6 +189,88 @@ def test_qwen_train_step_runs_the_flash_kernel_on_one_v5e(one_chip):
     loops = re.findall(r' while\(.*?op_name="([^"]*)"', text)
     assert loops and not any("attn_core" in op for op in loops)
     assert 0 < _total_bytes(compiled) < 0.9 * V5E_HBM_BYTES
+
+
+def _deepseek_cell():
+    """The ModelConfig and mix of ``deepseek-v2-lite.train_8k``, through the
+    benchmark's own cut (``benchmarks/chip/harness.py``)."""
+    import json
+    import sys
+    from pathlib import Path
+
+    chip = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    sys.path.insert(0, str(chip))
+    import harness
+
+    conf = json.loads((chip / "configs" / "deepseek-v2-lite.json").read_text())
+    mix = json.loads((chip / "traffic" / "train_8k.json").read_text())
+    return harness.program_config(conf, mix), mix
+
+
+def test_deepseek_v2_lite_train_step_fits_one_v5e_and_groups_by_expert(
+        one_chip):
+    """The cell's train step (1 x 8192 tokens, f32 master weights, bf16
+    compute, remat dots) fits 90% of HBM by the compiler's own peak, runs
+    the held experts' products in the megablox kernels (``gmm``, and
+    ``tgmm`` for the weights' gradient) under ``moe_experts``, and holds no
+    (group, seq, expert, capacity) one-hot dispatch: no array in the
+    program is near the S x E x S*K elements such a tensor has."""
+    cfg, mix = _deepseek_cell()
+    assert cfg.moe.capacity_factor <= 0 and cfg.moe.held == 8
+    opt_cfg = OptimizerConfig(**mix["optimizer"])
+    params, opt_state = (_place(one_chip, t) for t in
+                         steps_lib.train_state_shapes(cfg, opt_cfg))
+    S = mix["seq_len"]
+    batch = {"tokens": _on(one_chip, (mix["batch"], S), jnp.int32)}
+    compiled = jax.jit(steps_lib.make_train_step(cfg, opt_cfg,
+                                                 remat=mix["remat"]),
+                       donate_argnums=(0, 1)).lower(
+        params, opt_state, batch).compile()
+    assert 0 < compiled.memory_analysis().peak_memory_in_bytes \
+        < 0.9 * V5E_HBM_BYTES
+    text = compiled.as_text()
+    calls = re.findall(r"%(t?gmm)(?:\.\d+)? = \S+ custom-call\(.*?"
+                       r'metadata=\{op_name="([^"]*)"', text, re.S)
+    assert {name for name, _ in calls} == {"gmm", "tgmm"}
+    assert all("/moe_experts/" in op for _, op in calls)
+    sizes = [math.prod(int(d) for d in dims.split(","))
+             for dims in re.findall(r"[a-z0-9]+\[([0-9,]+)\]", text)]
+    one_hot = S * cfg.moe.num_experts * S * cfg.moe.num_experts_per_tok
+    assert max(sizes) < one_hot / 100
+
+
+def test_dropless_moe_on_a_four_chip_mesh_keeps_xlas_ragged_dot(topo):
+    """GSPMD does not partition a Mosaic call, so on a mesh of four
+    described v5e chips (experts sharded on ``model``) the dropless train
+    step keeps ``lax.ragged_dot``, which XLA partitions: it compiles, runs
+    no megablox kernel, and gathers no expert's weights onto one chip."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "model"))
+    cfg = dataclasses.replace(get_arch("deepseek-v2-lite").smoke,
+                              param_dtype="float32", compute_dtype="bfloat16")
+    assert cfg.moe.capacity_factor <= 0
+    opt_cfg = OptimizerConfig()
+    tokens = jax.ShapeDtypeStruct((4, 256), jnp.int32)
+    with activation_rules(mesh):
+        jitted, sh = steps_lib.jit_train_step(cfg, opt_cfg, mesh,
+                                              {"tokens": tokens})
+        params, opt_state = (
+            jax.tree.map(lambda a, s: _on(s, a.shape, a.dtype), t, s)
+            for t, s in zip(steps_lib.train_state_shapes(cfg, opt_cfg),
+                            (sh["params"], sh["opt_state"])))
+        text = jitted.lower(params, opt_state, {"tokens": _on(
+            sh["batch"]["tokens"], tokens.shape, tokens.dtype)}
+        ).compile().as_text()
+    assert not re.findall(r"%t?gmm(?:\.\d+)? = \S+ custom-call\(", text)
+    assert "ragged-dot" in text
+    m = cfg.moe
+    whole = {f"{m.num_experts},{cfg.d_model},{m.d_ff_expert}",
+             f"{m.num_experts},{m.d_ff_expert},{cfg.d_model}"}
+    gathered = re.findall(r"= \S+\[([0-9,]+)\]\S* all-gather", text)
+    assert gathered and not any(dims.endswith(w) for dims in gathered
+                                for w in whole)
 
 
 OPS = {"causal_flash_attention": (causal_flash_attention_op, 3),

@@ -171,6 +171,47 @@ def test_scope_times_of_recorded_train_step(scoped):
         assert all(bench_scopes.scope_of(tf_op) == k for _, tf_op, _ in rows)
 
 
+@pytest.mark.parametrize("tf_op,nested", [
+    ("jit(train_step)/jvp()/while/body/closed_call/ffn/moe_route/jit(_take)/"
+     "gather", "moe_route"),
+    ("jit(train_step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+     "ffn/moe_experts/cond/branch_0_fun/jit(tgmm)/pallas_call",
+     "moe_experts"),
+    ("jit(train_step)/jvp()/while/body/closed_call/ffn/dot_general", None),
+    ("jit(train_step)/ffn/moe_route/attn_core/dot_general", None),
+])
+def test_innermost_nested_moe_scope(tf_op, nested):
+    import moe_scopes
+
+    assert moe_scopes.nested_scope_of(tf_op) == nested
+
+
+def test_moe_scopes_lie_inside_ffn():
+    """The dropless MoE's ops carry ``moe_route`` or ``moe_experts`` inside
+    ``ffn`` (so ``ffn``'s time still holds them); a trace of a program
+    without them reads nothing."""
+    import jax
+    import jax.numpy as jnp
+    import moe_scopes
+
+    from repro.core.config import OptimizerConfig, get_arch
+    from repro.launch import steps as steps_lib
+    from repro.models import scopes
+
+    cfg = dataclasses.replace(get_arch("deepseek-v2-lite").smoke,
+                              param_dtype="float32", compute_dtype="float32")
+    opt = OptimizerConfig()
+    ps, os_ = steps_lib.train_state_shapes(cfg, opt)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 64), jnp.int32)}
+    step = jax.jit(steps_lib.make_train_step(cfg, opt))
+    names = _op_names(step.lower(ps, os_, batch).compile())
+    inner = [n for n in names if moe_scopes.nested_scope_of(n)]
+    assert {moe_scopes.nested_scope_of(n) for n in inner} == \
+        set(scopes.NESTED) == set(moe_scopes.NESTED)
+    assert all(bench_scopes.scope_of(n) == "ffn" for n in inner)
+    assert moe_scopes.nested_times(str(SCOPED)) is None
+
+
 def test_program_without_scopes_reads_nothing():
     """The older trace, of a jitted function with no scope."""
     assert bench_scopes.scope_times(str(CHIP / "testdata" /
