@@ -14,9 +14,10 @@ def flash_attention_op(q, k, v, *, causal: bool = True, q_offset: int = 0,
                            interpret=interpret)
 
 
-def causal_flash_attention_op(q, k, v, *, interpret: bool = False):
+def causal_flash_attention_op(q, k, v, *, scale=None,
+                              interpret: bool = False):
     check_backend(interpret)
-    return causal_flash_attention(q, k, v, interpret=interpret)
+    return causal_flash_attention(q, k, v, scale=scale, interpret=interpret)
 
 
 __all__ = ["flash_attention_op", "flash_attention", "attention_ref",

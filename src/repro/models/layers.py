@@ -160,9 +160,10 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
 # softmax attention.  ``attention`` picks the path by backend and shape:
 # short sequences materialise the scores (``full_attention``); long causal
 # self-attention from position 0 runs the causal Pallas flash kernel with
-# its own backward on a TPU (repro/kernels/flash_attention/train.py);
-# everything else (decode against a cache, ``kv_len``, ``q_offset``,
-# non-causal, unequal q/v head dims, other backends) runs the scan below.
+# its own backward on a TPU (repro/kernels/flash_attention/train.py), at
+# the head dims it supports (latent attention's q.k 192 with v 128 among
+# them); everything else (decode against a cache, ``kv_len``, ``q_offset``,
+# non-causal, other head dims, other backends) runs the scan below.
 # ---------------------------------------------------------------------------
 
 
@@ -286,15 +287,15 @@ def full_attention(q, k, v, *, causal: bool, q_offset: int = 0,
 def uses_flash_kernel(q, k, v, *, causal: bool, q_offset: int = 0,
                       kv_len=None, scale: Optional[float] = None) -> bool:
     """Whether ``attention`` runs the causal flash kernel on a TPU: causal
-    bf16 self-attention from position 0 over the whole sequence, scaled by
-    1/sqrt(hd), equal head dims the kernel supports, on at most one device
-    (a Mosaic kernel is not partitioned across a mesh)."""
+    bf16 self-attention from position 0 over the whole sequence, at q.k and
+    v head dims the kernel supports (any ``scale``: it is folded into q),
+    on at most one device (a Mosaic kernel is not partitioned across a
+    mesh)."""
     (_, hq, sq, hd), (_, hkv, sk, _) = q.shape, k.shape
     mesh = active_mesh()
     return (causal and isinstance(q_offset, int) and q_offset == 0
-            and kv_len is None and scale is None and sq == sk
-            and v.shape[-1] == hd
-            and hq % hkv == 0 and flash_train.supported(sq, hd)
+            and kv_len is None and sq == sk
+            and hq % hkv == 0 and flash_train.supported(sq, hd, v.shape[-1])
             and all(x.dtype == jnp.bfloat16 for x in (q, k, v))
             and (mesh is None or mesh.size == 1))
 
@@ -315,7 +316,8 @@ def attention(q, k, v, *, causal: bool, q_offset: int = 0, kv_len=None,
                          kv_len=kv_len, scale=scale):
         # the branch is chosen when the program is lowered for its platform
         return jax.lax.platform_dependent(
-            q, k, v, tpu=flash_train.causal_flash_attention, default=scan)
+            q, k, v, default=scan,
+            tpu=partial(flash_train.causal_flash_attention, scale=scale))
     return scan(q, k, v)
 
 

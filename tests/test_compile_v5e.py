@@ -179,7 +179,13 @@ def test_qwen_train_step_runs_the_flash_kernel_on_one_v5e(one_chip):
     compiled = jax.jit(steps_lib.make_train_step(cfg, opt_cfg, remat="dots"),
                        donate_argnums=(0, 1)).lower(
         params, opt_state, batch).compile()
-    text = compiled.as_text()
+    _assert_splash_attention(compiled.as_text())
+    assert 0 < _total_bytes(compiled) < 0.9 * V5E_HBM_BYTES
+
+
+def _assert_splash_attention(text):
+    """Attention in the compiled step runs the splash forward and fused
+    backward under ``attn_core``, and no scan loop is left there."""
     # a custom call's attributes span lines; its own metadata comes first
     calls = re.findall(r"%(splash_mha_\w+?)\.\d+ = .*?custom-call\(.*?"
                        r'metadata=\{op_name="([^"]*)"', text, re.S)
@@ -188,7 +194,6 @@ def test_qwen_train_step_runs_the_flash_kernel_on_one_v5e(one_chip):
     assert all("/attn_core/" in op for _, op in calls)
     loops = re.findall(r' while\(.*?op_name="([^"]*)"', text)
     assert loops and not any("attn_core" in op for op in loops)
-    assert 0 < _total_bytes(compiled) < 0.9 * V5E_HBM_BYTES
 
 
 def _deepseek_cell():
@@ -211,10 +216,12 @@ def test_deepseek_v2_lite_train_step_fits_one_v5e_and_groups_by_expert(
         one_chip):
     """The cell's train step (1 x 8192 tokens, f32 master weights, bf16
     compute, remat dots) fits 90% of HBM by the compiler's own peak, runs
-    the held experts' products in the megablox kernels (``gmm``, and
-    ``tgmm`` for the weights' gradient) under ``moe_experts``, and holds no
-    (group, seq, expert, capacity) one-hot dispatch: no array in the
-    program is near the S x E x S*K elements such a tensor has."""
+    latent attention (q.k 192, v 128) in the splash kernels under
+    ``attn_core`` with no scan loop there, runs the held experts' products
+    in the megablox kernels (``gmm``, and ``tgmm`` for the weights'
+    gradient) under ``moe_experts``, and holds no (group, seq, expert,
+    capacity) one-hot dispatch: no array in the program is near the
+    S x E x S*K elements such a tensor has."""
     cfg, mix = _deepseek_cell()
     assert cfg.moe.capacity_factor <= 0 and cfg.moe.held == 8
     opt_cfg = OptimizerConfig(**mix["optimizer"])
@@ -229,6 +236,7 @@ def test_deepseek_v2_lite_train_step_fits_one_v5e_and_groups_by_expert(
     assert 0 < compiled.memory_analysis().peak_memory_in_bytes \
         < 0.9 * V5E_HBM_BYTES
     text = compiled.as_text()
+    _assert_splash_attention(text)
     calls = re.findall(r"%(t?gmm)(?:\.\d+)? = \S+ custom-call\(.*?"
                        r'metadata=\{op_name="([^"]*)"', text, re.S)
     assert {name for name, _ in calls} == {"gmm", "tgmm"}

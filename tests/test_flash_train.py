@@ -2,30 +2,37 @@
 chooses it.
 
 The kernel runs in the Pallas interpreter against ``L.full_attention`` on
-the same bf16 inputs, forward and gradients.  The dispatch is checked by
-lowering ``L.attention`` for a TPU (no chip needed): the kernel's custom
-call appears only for causal self-attention from position 0 over the whole
-sequence; ``kv_len``, ``q_offset``, non-causal and other long calls keep
-the online-softmax scan, and decode and short calls full attention.
+the same bf16 inputs, forward and gradients, latent attention's unequal
+head dims and YaRN scale among them.  The dispatch is checked by lowering
+``L.attention`` for a TPU (no chip needed): the kernel's custom call
+appears only for causal self-attention from position 0 over the whole
+sequence, at any scale; ``kv_len``, ``q_offset``, non-causal and other
+long calls keep the online-softmax scan, and decode and short calls full
+attention.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.config import get_arch
 from repro.kernels.flash_attention.ops import causal_flash_attention_op
 from repro.models import layers as L
+from repro.models.attention import mla_softmax_scale
 from repro.sharding import activation_rules
 
 BF16 = jnp.bfloat16
+# DeepSeek-V2's: 192^-1/2 times YaRN's mscale squared
+MLA_SCALE = mla_softmax_scale(get_arch("deepseek-v2-lite").model.attention)
 
 
-def _qkv(hq, hkv, seq, hd, batch=1):
+def _qkv(hq, hkv, seq, hd, batch=1, vd=None):
+    vd = hd if vd is None else vd
     kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(kq, (batch, hq, seq, hd)).astype(BF16)
     k = jax.random.normal(kk, (batch, hkv, seq, hd)).astype(BF16)
-    v = jax.random.normal(kv, (batch, hkv, seq, hd)).astype(BF16)
-    w = jax.random.normal(kw, (batch, hq, seq, hd))
+    v = jax.random.normal(kv, (batch, hkv, seq, vd)).astype(BF16)
+    w = jax.random.normal(kw, (batch, hq, seq, vd))
     return q, k, v, w
 
 
@@ -37,32 +44,34 @@ def _close(got, want):
                                atol=2e-2 * np.abs(want).max())
 
 
-# (Hq, Hkv, S, hd, batch): at S 2048 the 2 x 2 block grid holds diagonal,
-# full and skipped blocks
-CASES = {"mha": (2, 2, 2048, 64, 1),
-         "gqa": (4, 2, 2048, 64, 1),
-         "batch2": (2, 1, 2048, 64, 2),
-         "hd128": (2, 2, 2048, 128, 1)}
+# (Hq, Hkv, S, hd, batch, vd, scale): at S 2048 the 2 x 2 block grid holds
+# diagonal, full and skipped blocks; ``mla`` is latent attention's q.k 192
+# (128 + 64 rope) with v 128 under its YaRN scale
+CASES = {"mha": (2, 2, 2048, 64, 1, 64, None),
+         "gqa": (4, 2, 2048, 64, 1, 64, None),
+         "batch2": (2, 1, 2048, 64, 2, 64, None),
+         "hd128": (2, 2, 2048, 128, 1, 128, None),
+         "mla": (2, 2, 2048, 192, 1, 128, MLA_SCALE)}
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_matches_full_attention(case):
     from repro.kernels.flash_attention.train import causal_flash_attention
 
-    hq, hkv, seq, hd, batch = CASES[case]
-    q, k, v, w = _qkv(hq, hkv, seq, hd, batch)
+    hq, hkv, seq, hd, batch, vd, scale = CASES[case]
+    q, k, v, w = _qkv(hq, hkv, seq, hd, batch, vd)
 
     def kernel(q, k, v):
-        return causal_flash_attention(q, k, v, interpret=True)
+        return causal_flash_attention(q, k, v, scale=scale, interpret=True)
 
     def ref(q, k, v):
-        return L.full_attention(q, k, v, causal=True)
+        return L.full_attention(q, k, v, causal=True, scale=scale)
 
     def loss(f):
         return lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) * w)
 
     out = jax.jit(kernel)(q, k, v)
-    assert out.shape == q.shape and out.dtype == BF16
+    assert out.shape == (batch, hq, seq, vd) and out.dtype == BF16
     _close(out, ref(q, k, v))
     grads = jax.jit(jax.grad(loss(kernel), (0, 1, 2)))(q, k, v)
     for got, want in zip(grads, jax.grad(loss(ref), (0, 1, 2))(q, k, v)):
@@ -91,7 +100,11 @@ DISPATCH = {
                    False),
     "short": ((16, 16, 1024, 1024, 64, 64), {}, BF16, False),
     "cross_len": ((16, 16, 2048, 4096, 64, 64), {}, BF16, False),
-    "mla_head_dims": ((16, 16, 2048, 2048, 192, 128), {}, BF16, False),
+    "scaled": ((16, 16, 2048, 2048, 64, 64), {"scale": 0.1}, BF16, True),
+    "mla_head_dims": ((16, 16, 2048, 2048, 192, 128), {"scale": MLA_SCALE},
+                      BF16, True),
+    "mla_ragged": ((16, 16, 2304, 2304, 192, 128), {"scale": MLA_SCALE},
+                   BF16, False),
     "ragged_seq": ((16, 16, 2304, 2304, 64, 64), {}, BF16, False),
     "float32": ((16, 16, 2048, 2048, 64, 64), {}, jnp.float32, False),
 }
