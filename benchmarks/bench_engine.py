@@ -2,9 +2,9 @@
 
 Measures the simulation core this PR optimized:
 
-  * fifo event throughput — a layered 10k-task DAG over 4 FIFO resources,
-    dict-based general engine vs the array-backed static fast path (cold
-    cache = first sweep point, warm cache = steady-state what-if loop);
+  * fifo event throughput — a layered 10k-task DAG over 4 FIFO resources
+    on the array-backed static engine (cold cache = first sweep point,
+    warm cache = steady-state what-if loop);
   * shared-channel scaling — n concurrent transfers with distinct
     durations on one width-2 processor-sharing channel.  Virtual-time GPS
     completes each in O(log n); the seed engine's per-event remaining-work
@@ -19,8 +19,8 @@ import time
 from typing import Dict, List, Tuple
 
 from repro.core.sim.engine import (DynamicSimulator, GraphTemplate,
-                                   ResourceSpec, Simulator, StaticCache,
-                                   Task, simulate_static)
+                                   ResourceSpec, StaticCache, Task,
+                                   simulate_static)
 
 SHARED_NS = (200, 800, 3200, 6400)
 
@@ -63,12 +63,10 @@ def _best_of(fn, reps: int = 3) -> float:
 def fifo_events_per_sec() -> Dict[str, float]:
     tasks = layered_dag()
     n = len(tasks)
-    t_dict = _best_of(lambda: Simulator(tasks).run())
     t_cold = _best_of(lambda: simulate_static(tasks))
     cache = StaticCache(tasks)
     t_warm = _best_of(lambda: simulate_static(tasks, cache=cache))
-    return {"dict": n / t_dict, "static_cold": n / t_cold,
-            "static_warm": n / t_warm}
+    return {"static_cold": n / t_cold, "static_warm": n / t_warm}
 
 
 def shared_tasks_per_sec() -> Dict[str, float]:
@@ -84,41 +82,9 @@ def dynamic_events_per_sec(n_phases: int = 3000,
     """Traffic-style dynamic injection: phases of ``chunks`` chained
     compute tasks plus zero-cost KV writes, each phase injected when the
     previous one completes — the serving simulator's task-graph pattern
-    without the scheduler, isolating engine injection overhead.  Compares
-    the dict engine (``Simulator.inject`` + global ``on_complete``)
-    against the array-backed ``DynamicSimulator.inject_template``."""
+    without the scheduler, isolating the injection overhead of
+    ``DynamicSimulator.inject_template``."""
     n_tasks = n_phases * 2 * chunks
-
-    def run_dict() -> None:
-        sim_box = []
-        tails = set()
-        done = [0]
-
-        def submit() -> None:
-            if done[0] >= n_phases:
-                return
-            done[0] += 1
-            sim = sim_box[0]
-            tid = sim.next_task_id()
-            prev = -1
-            for _ in range(chunks):
-                sim.inject(Task(tid, "c", "rep", "rep", 1e-6,
-                                deps=(prev,) if prev >= 0 else ()))
-                sim.inject(Task(tid + 1, "kv", "kv", "rep:kv", 0.0,
-                                deps=(tid,)))
-                prev = tid
-                tid += 2
-            tails.add(prev)
-
-        def on_complete(task: Task, now: float) -> None:
-            if task.tid in tails:
-                tails.discard(task.tid)
-                submit()
-
-        sim_box.append(Simulator(on_complete=on_complete))
-        sim_box[0].at(0.0, submit)
-        sim_box[0].run()
-
     tpl_tasks = []
     for i in range(chunks):
         tpl_tasks.append(Task(2 * i, "c", "rep", "rep", 0.0,
@@ -141,15 +107,13 @@ def dynamic_events_per_sec(n_phases: int = 3000,
         sim.at(0.0, submit)
         sim.run()
 
-    return {"dict": n_tasks / _best_of(run_dict),
-            "fast": n_tasks / _best_of(run_fast)}
+    return {"fast": n_tasks / _best_of(run_fast)}
 
 
 def run() -> List[Tuple[str, float, str]]:
     rows: List[Tuple[str, float, str]] = []
     fifo = fifo_events_per_sec()
-    rows.append(("engine_fifo_10k", 1e6 * 10_000 / fifo["dict"],
-                 f"dict={fifo['dict']:.0f}ev/s "
+    rows.append(("engine_fifo_10k", 1e6 * 10_000 / fifo["static_warm"],
                  f"static_cold={fifo['static_cold']:.0f}ev/s "
                  f"static_warm={fifo['static_warm']:.0f}ev/s"))
     shared = shared_tasks_per_sec()
@@ -164,6 +128,5 @@ def run() -> List[Tuple[str, float, str]]:
     rows.append((
         "engine_dynamic_injection",
         1e6 * 24_000 / dyn["fast"],
-        f"dict={dyn['dict']:.0f}ev/s fast={dyn['fast']:.0f}ev/s "
-        f"speedup={dyn['fast'] / dyn['dict']:.2f}x (accept: >=3x)"))
+        f"fast={dyn['fast']:.0f}ev/s"))
     return rows

@@ -4,14 +4,14 @@ Measures the virtual serving stack at the scale the ROADMAP asks about:
 
   * sim speed — wall seconds (and simulated requests per wall second) for
     10k requests through continuous batching (acceptance: < 10 s on CPU);
-  * dynamic fast path — the same 10k requests with *full task-graph
-    injection* (chunked phase graphs + KV writes) on the array-backed
-    dynamic engine vs the dict engine (acceptance: >= 3x);
+  * task-graph mode — the same 10k requests with *full task-graph
+    fidelity* (chunked phase graphs + KV writes, one ``TemplateLane``
+    per replica);
   * speculative leap — 10k requests under a scheduler that declares only
     the ``decode_stable`` contract, so every decode fusion takes the
     snapshot/rollback path;
   * graph-mode speculative leap — the same decode_stable-only scheduler
-    with full task-graph injection on the fast engine: each leap books
+    in full task-graph mode: each leap books
     one ``TemplateLane`` burst of per-step template instances and rolls
     back by truncating the burst at a snapshot boundary;
   * Monte-Carlo seed batch — 16 seeds x 10k requests in one
@@ -78,20 +78,18 @@ def run() -> List[Tuple[str, float, str]]:
                  f"{rep.n_requests / wall:.0f} req/wall-s "
                  f"(accept: wall<10s)"))
 
-    # full task-graph injection: fast dynamic engine vs dict engine
-    # (interleaved best-of-2, so machine-load drifts hit both engines)
-    walls = {"fast": float("inf"), "dict": float("inf")}
+    # full task-graph mode, best-of-2
+    wall_g = float("inf")
     for _ in range(2):
-        for engine in ("fast", "dict"):
-            t0 = time.perf_counter()
-            g = ServingSimulator(cost, ContinuousBatchingScheduler,
-                                 traffic(10_000), replicas=4, slots=8,
-                                 phase_tasks=4, engine=engine).run()
-            walls[engine] = min(walls[engine], time.perf_counter() - t0)
-    rows.append(("serve_sim_10k_taskgraph", walls["fast"] * 1e6,
-                 f"fast={walls['fast']:.2f}s dict={walls['dict']:.2f}s "
-                 f"speedup={walls['dict'] / walls['fast']:.2f}x "
-                 f"({g.n_requests} reqs, {4 * 2} tasks/phase, accept: >=3x)"))
+        t0 = time.perf_counter()
+        g = ServingSimulator(cost, ContinuousBatchingScheduler,
+                             traffic(10_000), replicas=4, slots=8,
+                             phase_tasks=4).run()
+        wall_g = min(wall_g, time.perf_counter() - t0)
+    rows.append(("serve_sim_10k_taskgraph", wall_g * 1e6,
+                 f"{g.n_requests} reqs, "
+                 f"{g.n_requests / wall_g:.0f} req/wall-s "
+                 f"({4 * 2} tasks/phase)"))
 
     # speculative decode leap: decode_stable-only scheduler, rollbacks on
     t0 = time.perf_counter()

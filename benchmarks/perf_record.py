@@ -17,9 +17,9 @@ overhead bound::
 
 ``BASELINE_PR9`` is the ``current`` section of the committed
 ``BENCH_pr9.json``; absolute numbers are machine-dependent, the *ratios*
-are the tracked signal.  Paired comparisons (MC vs scalar loop, fast vs
-dict engine, probe-on vs probe-off) are measured interleaved in this
-process, so load drifts hit both sides.  The ``--trials N`` median mode
+are the tracked signal.  Paired comparisons (MC vs scalar loop,
+probe-on vs probe-off) are measured interleaved in this process, so
+load drifts hit both sides.  The ``--trials N`` median mode
 exists so recordings are robust to a single bad window: each trial runs
 the full suite, and every leaf metric reports the across-trial median.
 """
@@ -34,12 +34,12 @@ from typing import Dict, List
 
 # The "current" section of BENCH_pr9.json, measured at db7ec02 (PR 9).
 BASELINE_PR9: Dict = {
-    "engine_fifo_events_per_sec": {"dict": 130041.6244, "static_cold": 395160.8601, "static_warm": 590498.0828},
+    "engine_fifo_events_per_sec": {"static_cold": 395160.8601, "static_warm": 590498.0828},
     "engine_shared_tasks_per_sec": {"200": 315746.7647, "800": 281400.3184, "3200": 261530.9186, "6400": 237899.9265},
-    "engine_dynamic_injection_events_per_sec": {"dict": 93394.3696, "fast": 753716.5291},
+    "engine_dynamic_injection_events_per_sec": {"fast": 753716.5291},
     "what_if_points_per_sec": {"roofline": 2145.7852, "analytic": 1558.9365, "des": 40.1664},
     "serve_sim_10k": {"wall_seconds": 0.3341, "requests_per_sec": 29928.7271},
-    "serve_sim_10k_taskgraph": {"fast_wall_seconds": 0.4534, "dict_wall_seconds": 2.769, "fast_requests_per_sec": 22055.8395, "speedup_fast_vs_dict": 5.8425},
+    "serve_sim_10k_taskgraph": {"fast_wall_seconds": 0.4534, "fast_requests_per_sec": 22055.8395},
     "serve_sim_10k_speculative": {"wall_seconds": 0.3215, "requests_per_sec": 31100.726},
     "serve_sim_10k_taskgraph_speculative": {"wall_seconds": 0.4163, "requests_per_sec": 24022.497},
     "serve_sim_10k_chaos": {"wall_seconds": 0.1035, "requests_per_sec": 94650.2414, "availability": 0.9128, "n_failures": 69, "n_retries": 338, "n_abandoned": 207},
@@ -109,29 +109,23 @@ def _serve_sim_10k() -> Dict[str, float]:
 
 
 def _serve_sim_10k_taskgraph(reps: int = 3) -> Dict[str, float]:
-    """10k requests with full task-graph injection (4 chunks + KV writes
-    per phase): array-backed dynamic engine vs the PR 3 dict path,
-    interleaved best-of-``reps``."""
+    """10k requests in full task-graph mode (4 chunks + KV writes per
+    phase), best-of-``reps``."""
     from repro.serve_sim import ContinuousBatchingScheduler, ServingSimulator
 
     import gc
 
     cost = _serve_cost()
-    walls = {"fast": float("inf"), "dict": float("inf")}
-    n = 0
+    wall = float("inf")
     for _ in range(reps):
-        for engine in ("fast", "dict"):
-            gc.collect()                     # drain prior suites' garbage
-            t0 = time.perf_counter()
-            rep = ServingSimulator(cost, ContinuousBatchingScheduler,
-                                   _traffic(), replicas=4, slots=8,
-                                   phase_tasks=4, engine=engine).run()
-            walls[engine] = min(walls[engine], time.perf_counter() - t0)
-            n = rep.n_requests
-    return {"fast_wall_seconds": walls["fast"],
-            "dict_wall_seconds": walls["dict"],
-            "fast_requests_per_sec": n / walls["fast"],
-            "speedup_fast_vs_dict": walls["dict"] / walls["fast"]}
+        gc.collect()                         # drain prior suites' garbage
+        t0 = time.perf_counter()
+        rep = ServingSimulator(cost, ContinuousBatchingScheduler,
+                               _traffic(), replicas=4, slots=8,
+                               phase_tasks=4).run()
+        wall = min(wall, time.perf_counter() - t0)
+    return {"fast_wall_seconds": wall,
+            "fast_requests_per_sec": rep.n_requests / wall}
 
 
 def _serve_sim_10k_speculative() -> Dict[str, float]:

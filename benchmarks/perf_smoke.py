@@ -9,7 +9,7 @@ observability layer's disabled-path contract (one dead branch per hot
 site, nothing else):
 
   * fifo static fast path (warm cache) >= 300k events/s
-    (seed dict engine: ~86k; measured: ~400-615k)
+    (measured: ~400-615k)
   * shared-channel burst, n=3200       >= 120k tasks/s
     (seed: ~2.3k — the quadratic collapse; measured: ~140-260k)
   * shared-channel flatness n=6400/200 >= 0.3
@@ -18,17 +18,15 @@ site, nothing else):
   * serve_sim 10k requests             >= 17k req/wall-s
     (seed: ~1.9k; measured: ~26k)
   * dynamic injection, fast engine     >= 420k events/s
-    (PR 4's array-backed ``DynamicSimulator`` + template instantiation;
-    the dict engine measures ~70k on the same scenario; measured ~700k)
+    (the array-backed ``DynamicSimulator`` + template instantiation;
+    measured ~700k)
   * serve_sim 10k, speculative leap    >= 15k req/wall-s
     (a ``decode_stable``-only scheduler: every decode fusion takes the
     snapshot/rollback path; measured ~23k)
   * serve_sim 10k, task-graph mode     >= 12k req/wall-s
-    (PR 8's ``TemplateLane`` graph serving on the fast engine, 4 chunks
-    + KV writes per phase; measured ~16-22k — the dict per-chunk engine
-    sustains ~3k and the pre-TemplateLane fast path ~11k on the same
-    scenario, so a lost burst/closed-form path fails loudly; the >= 2x
-    vs PR 4 headline itself is recorded in BENCH_pr8.json)
+    (``TemplateLane`` graph serving, 4 chunks + KV writes per phase;
+    measured ~16-22k — per-chunk event injection sustained ~3k-11k on
+    the same scenario, so a lost burst/closed-form path fails loudly)
   * serve_sim 10k, graph speculative   >= 11k req/wall-s
     (task-graph mode under the ``decode_stable``-only scheduler: every
     leap is one ``TemplateLane`` burst with snapshot rollback)
@@ -63,8 +61,8 @@ FLOORS = {
 
 
 def _taskgraph_requests_per_sec(speculative: bool) -> float:
-    """10k requests in full task-graph mode on the fast engine
-    (``TemplateLane`` serving), best-of-2.  ``speculative`` swaps in the
+    """10k requests in full task-graph mode (``TemplateLane``
+    serving), best-of-2.  ``speculative`` swaps in the
     ``decode_stable``-only scheduler so every leap takes the burst
     snapshot/rollback path."""
     from benchmarks.bench_serve_sim import SpeculativeContinuousScheduler

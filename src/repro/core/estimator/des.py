@@ -118,8 +118,8 @@ class DesBackend(EstimatorBackend):
         # no injection), so the dependency CSR is precomputed once per
         # structure (shared across re-annotated what-if variants) and the
         # event loop runs over flat duration arrays with records
-        # materialized lazily — several times faster than the general
-        # dict-based engine, with exact parity (tests/test_engine_parity).
+        # materialized lazily (parity with the seed engine:
+        # tests/test_engine_parity).
         result = simulate_static(graph.tasks, graph.resources,
                                  graph.durations, cache=graph.sim_cache())
 

@@ -22,25 +22,34 @@ models (repro.core.taskgraph.compiler); contention stretches them.
 Unknown resources default to a single-server FIFO, so plain task lists
 behave exactly as the original exclusive-resource engine.
 
-Beyond static graphs, the engine supports **dynamic event injection** — the
-foundation of the traffic-driven serving simulator (``repro.serve_sim``):
+The module holds two engines, one per job:
 
-  * :meth:`Simulator.at` schedules a timed callback (e.g. a request
-    arrival) that runs inside the event loop and may inject new work;
-  * :meth:`Simulator.inject` adds a task *while the simulation runs*; its
-    dependencies may already be satisfied or still in flight;
-  * ``on_complete`` observers fire as tasks finish, letting a scheduler
-    react causally (free a slot, admit the next request, issue the next
-    decode step);
-  * :meth:`Simulator.lane` opens a :class:`ServiceLane` — the express path
-    for the dominant serving pattern (one task at a time on a dedicated
-    single-server resource, submitted only when idle) that skips Task
-    construction and dependency bookkeeping entirely.
+  * :func:`simulate_static` runs a *static* task graph (no callbacks, no
+    injection) over precomputed dependency arrays (:class:`StaticCache`)
+    with deferred record materialization — the estimator's DES backend;
+  * :class:`DynamicSimulator` runs everything that injects work while the
+    simulation runs — the foundation of the traffic-driven serving
+    simulator (``repro.serve_sim``):
 
-Static task graphs are simply the special case with no callbacks — and
-for them :func:`simulate_static` runs the same causal semantics over
-precomputed dependency arrays (:class:`StaticCache`) with deferred record
-materialization, several times faster than the dict-based general loop.
+      - :meth:`DynamicSimulator.at` schedules a timed callback (e.g. a
+        request arrival) that runs inside the event loop and may inject
+        new work;
+      - :meth:`DynamicSimulator.inject` adds a task *while the simulation
+        runs*; its dependencies may already be satisfied or still in
+        flight;
+      - ``on_complete`` observers fire as tasks finish, letting a
+        scheduler react causally (free a slot, admit the next request,
+        issue the next decode step);
+      - :meth:`DynamicSimulator.lane` opens a :class:`ServiceLane` — the
+        express path for the dominant serving pattern (one task at a time
+        on a dedicated single-server resource, submitted only when idle)
+        that skips Task construction and dependency bookkeeping entirely —
+        and :meth:`DynamicSimulator.template_lane` its graph-structured
+        sibling, :class:`TemplateLane`.
+
+Both engines share the causal semantics and tie-breaking rules, and both
+are held to the frozen seed engine (``tests/reference_engine.py``) by the
+golden parity tests in ``tests/test_engine_parity.py``.
 
 Complexity: shared-link contention is O(log n) per event via virtual-time
 generalized processor sharing — each admitted task gets a fixed virtual
@@ -56,30 +65,6 @@ import math
 from bisect import bisect_left
 from dataclasses import dataclass
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
-
-
-def _schedule_every(sim, interval: float, fn: Callable[[], bool],
-                    start: Optional[float]) -> None:
-    """Shared implementation behind ``Simulator.every`` /
-    ``DynamicSimulator.every``.
-
-    Exactly one pending tick lives on the event heap at a time; the
-    chain self-extends only while ``fn()`` returns a truthy value, so a
-    draining run (the event loop stops when the heap empties) always
-    terminates: the caller's ``fn`` is responsible for returning False
-    once the condition it monitors (outstanding requests, open probes,
-    ...) is resolved.
-    """
-    if not (interval > 0.0) or not math.isfinite(interval):
-        raise ValueError(f"every(): interval must be finite and > 0, "
-                         f"got {interval!r}")
-    t0 = sim.now + interval if start is None else start
-
-    def _tick() -> None:
-        if fn():
-            sim.at(sim.now + interval, _tick)
-
-    sim.at(t0, _tick)
 
 
 @dataclass(frozen=True)
@@ -163,92 +148,13 @@ class SimResult:
                 f"resources={sorted(self.resource_busy)})")
 
 
-class _SharedChannel:
-    """Virtual-time generalized processor sharing for one ``shared`` resource.
-
-    All active tasks progress at the common rate ``min(1, servers / n)``,
-    so completion order equals admission-virtual-finish order: a task
-    admitted with ``work`` full-rate seconds at virtual time ``v`` finishes
-    at fixed virtual time ``v + work``.  The virtual clock advances at the
-    common rate and is converted to real time only at rate-change
-    boundaries (admit / complete), making each channel event O(log n) in
-    active tasks instead of the O(n) per-event remaining-work sweep of the
-    seed engine.  ``epoch`` invalidates stale completion events.
-    """
-
-    __slots__ = ("servers", "heap", "work", "start", "vnow", "last_t",
-                 "epoch", "n")
-
-    #: near-tie completion tolerance, *relative* to each task's own
-    #: full-rate duration.  (The seed engine used an absolute 1e-15 s
-    #: cutoff, which completed genuinely unfinished tasks early whenever
-    #: durations were themselves O(1e-15).)
-    REL_EPS = 1e-12
-
-    def __init__(self, servers: int):
-        self.servers = servers
-        self.heap: List[Tuple[float, int]] = []   # (virtual finish, tid)
-        self.work: Dict[int, float] = {}
-        self.start: Dict[int, float] = {}
-        self.vnow = 0.0
-        self.last_t = 0.0
-        self.epoch = 0
-        self.n = 0
-
-    @property
-    def rate(self) -> float:
-        n = self.n
-        return min(1.0, self.servers / n) if n else 1.0
-
-    def advance(self, now: float) -> None:
-        dt = now - self.last_t
-        if dt > 0.0:
-            if self.n:
-                self.vnow += dt * self.rate
-            self.last_t = now
-
-    def admit(self, tid: int, work: float, now: float) -> None:
-        self.advance(now)
-        self.n += 1
-        heapq.heappush(self.heap, (self.vnow + work, tid))
-        self.work[tid] = work
-        self.start[tid] = now
-
-    def next_completion(self, now: float) -> Optional[float]:
-        if not self.n:
-            return None
-        vf = self.heap[0][0]
-        return now + max(vf - self.vnow, 0.0) / self.rate
-
-    def pop_done(self, now: float) -> List[int]:
-        """Pop the head task plus any near-ties.
-
-        Called when the completion event scheduled for the current head
-        fires (``epoch`` guarantees no admission or completion intervened),
-        so the head is complete by construction — no absolute epsilon is
-        needed.  Near-ties complete together only when within
-        ``REL_EPS * work`` of the head's virtual finish.
-        """
-        self.advance(now)
-        if not self.n:
-            return []
-        vf0, tid0 = heapq.heappop(self.heap)
-        if vf0 > self.vnow:                # absorb scheduling round-off
-            self.vnow = vf0
-        self.n -= 1
-        del self.work[tid0]
-        done = [tid0]
-        heap = self.heap
-        while heap:
-            vf, tid = heap[0]
-            if vf - vf0 > self.REL_EPS * self.work[tid]:
-                break
-            heapq.heappop(heap)
-            self.n -= 1
-            del self.work[tid]
-            done.append(tid)
-        done.sort()
-        return done
+#: Shared-channel near-tie completion tolerance, *relative* to each task's
+#: own full-rate duration: when a channel's head task completes, tasks whose
+#: virtual finish lies within ``_REL_EPS * work`` of it complete with it.
+#: (The seed engine used an absolute 1e-15 s cutoff, which completed
+#: genuinely unfinished tasks early whenever durations were themselves
+#: O(1e-15).)
+_REL_EPS = 1e-12
 
 
 class ServiceLane:
@@ -257,7 +163,7 @@ class ServiceLane:
     The traffic-driven serving simulator issues one prefill/decode task at
     a time per replica, always from an idle state — so the general
     inject/enqueue/drain machinery (Task construction, dependency and
-    duration dicts, ready queues) is pure overhead.  A lane keeps plain
+    duration arrays, ready queues) is pure overhead.  A lane keeps plain
     start/end/kind arrays, schedules the completion event directly, and
     materializes ``TaskRecord``s lazily only when a trace is requested.
 
@@ -268,7 +174,7 @@ class ServiceLane:
     __slots__ = ("sim", "resource", "busy", "busy_time", "starts", "ends",
                  "kinds", "infos", "name_fn", "epoch", "_handler")
 
-    def __init__(self, sim: "Simulator", resource: str,
+    def __init__(self, sim: "DynamicSimulator", resource: str,
                  name_fn: Optional[Callable[[str, object], str]] = None):
         self.sim = sim
         self.resource = resource
@@ -415,15 +321,15 @@ class TemplateLane:
     topologically ordered by local id, every task's resources are
     dedicated to this lane, and the tail's dependency closure determines
     the phase end (the caller precomputes it with the same left-to-right
-    chunk accumulation the general engines' chained events produce, so
-    parity with the dict engine is bit-exact).
+    chunk accumulation that chained completion events produce, so the
+    replay equals an event-by-event run of the same tasks bit for bit).
     """
 
     __slots__ = ("sim", "resource", "busy", "epoch", "entries", "end",
                  "step_durs", "_handler", "_fin", "_sched", "_checked",
                  "_prev_end")
 
-    def __init__(self, sim, resource: str,
+    def __init__(self, sim: "DynamicSimulator", resource: str,
                  step_durs: Optional[Callable] = None):
         """``step_durs(tpl, dur) -> per-task durations`` splits one burst
         step's total duration at materialization time (bursts store only
@@ -533,9 +439,9 @@ class TemplateLane:
         and drops the rest; a plain phase entry is dropped whole before
         it materializes (template entries are step-granular at best, so
         graph mode records no partial-step work — the express
-        :class:`ServiceLane` keeps the truncated span instead; the
-        serving parity tests under faults therefore compare request
-        metrics, not task records).  The stale completion event is
+        :class:`ServiceLane` keeps the truncated span instead, so the
+        two serving modes agree on request metrics under faults, not on
+        task records).  The stale completion event is
         invalidated via ``epoch`` and the lane goes idle."""
         if not self.busy:
             raise RuntimeError(f"template lane {self.resource!r} has no "
@@ -644,8 +550,8 @@ class TemplateLane:
                 for i in range(0, len(dd), 2):
                     d = dd[i]
                     e += d
-                    comp_busy += d   # per-chunk, matching the general
-                    dk = dd[i + 1]   # engines' per-task accumulation
+                    comp_busy += d   # per-chunk, matching the event
+                    dk = dd[i + 1]   # loop's per-task accumulation
                     s = e if e > kvf else kvf
                     if kv_first is None:
                         kv_first = s
@@ -764,380 +670,6 @@ class TemplateLane:
         return out
 
 
-class Simulator:
-    """Event-driven scheduler over FIFO and bandwidth-shared resources.
-
-    The event loop is instance-level state, so timed callbacks
-    (:meth:`at`) and completion observers (``on_complete``) can inject
-    new tasks (:meth:`inject`) while the simulation is running — dynamic
-    arrivals preempting a static task graph.
-    """
-
-    def __init__(self, tasks: Iterable[Task] = (),
-                 resources: Optional[Dict[str, ResourceSpec]] = None,
-                 durations=None,
-                 on_complete: Optional[Callable[[Task, float], None]] = None,
-                 probe=None):
-        """``durations`` optionally overrides each task's annotated duration
-        (aligned with ``tasks``); the what-if fast path re-annotates a graph
-        by swapping this array, leaving the Task objects untouched.
-        ``probe`` (a :class:`repro.obs.probe.Probe`) enables event-loop
-        instrumentation: per-kind event counters plus active/share gauges
-        on bandwidth-shared channels.  Probes only read simulation state —
-        results are bit-identical with or without one."""
-        tasks = list(tasks)
-        self.tasks = {t.tid: t for t in tasks}
-        if len(self.tasks) != len(tasks):
-            raise ValueError("duplicate task ids")
-        if durations is None:
-            self.durations = {t.tid: t.duration for t in tasks}
-        else:
-            if len(durations) != len(tasks):
-                raise ValueError("durations must align with tasks")
-            self.durations = {t.tid: float(d)
-                              for t, d in zip(tasks, durations)}
-        self.resources = dict(resources or {})
-        self.on_complete = on_complete
-        self.probe = probe
-        self._chan_gauges: Dict[str, Tuple] = {}
-        self._validate(tasks)
-        self._next_tid = max(self.tasks, default=-1) + 1
-        # ---- event-loop state (live during run()) ----
-        self._now = 0.0
-        self._seq = 0
-        self._running = False
-        self._completed_ids: set = set()
-        self._n_deps: Dict[int, int] = {}
-        self._dependents: Dict[int, List[int]] = {}
-        # per-FIFO-resource ready queue: (ready_time, tid)
-        self._queues: Dict[str, List[Tuple[float, int]]] = {}
-        self._active: Dict[str, int] = {}     # fifo resource -> active count
-        self._channels: Dict[str, _SharedChannel] = {}
-        self._res_busy: Dict[str, float] = {}
-        self._records: List[TaskRecord] = []
-        self._lanes: List = []  # ServiceLane | TemplateLane
-        self._void: set = set()   # tids whose pending 'done' was cancelled
-        # event heap: (time, seq, kind, payload)
-        #   kind 'done'  — a fifo task finished (payload = tid)
-        #   kind 'chan'  — a shared channel may have completions
-        #                  (payload = (resource, epoch))
-        #   kind 'call'  — a timed callback (payload = zero-arg callable)
-        #   kind 'lane'  — a service-lane task finished
-        #                  (payload = (lane, handler, epoch))
-        self._events: List[Tuple[float, int, str, object]] = []
-
-    def _validate(self, tasks: List[Task]) -> None:
-        ids = set(self.tasks)
-        for t in tasks:
-            for d in t.deps:
-                if d not in ids:
-                    raise ValueError(f"task {t.tid} depends on unknown {d}")
-
-    def _spec(self, resource: str) -> ResourceSpec:
-        return self.resources.get(resource) or ResourceSpec(name=resource)
-
-    # ------------------------------------------------------------------
-    # Dynamic injection API
-    # ------------------------------------------------------------------
-
-    @property
-    def now(self) -> float:
-        """Current simulation time."""
-        return self._now
-
-    def at(self, t: float, fn: Callable[[], None]) -> None:
-        """Schedule ``fn`` to run inside the event loop at time ``t``.
-
-        Callbacks at equal times run in scheduling order.  ``fn`` may call
-        :meth:`inject` / :meth:`at` — this is how open-loop arrivals and
-        scheduler timeouts enter a running simulation.
-        """
-        if t < self._now - 1e-18:
-            raise ValueError(f"cannot schedule at {t} < now ({self._now})")
-        self._push_event(max(t, self._now), "call", fn)
-
-    def every(self, interval: float, fn: Callable[[], bool],
-              start: Optional[float] = None) -> None:
-        """Run ``fn`` periodically inside the event loop (health checks,
-        autoscaler ticks).  The first tick fires at ``start`` (default
-        ``now + interval``), then every ``interval`` for as long as
-        ``fn()`` returns truthy; a falsy return ends the chain so the
-        heap can drain and :meth:`run` can terminate."""
-        _schedule_every(self, interval, fn, start)
-
-    def inject(self, task: Task) -> Task:
-        """Add ``task`` to a (possibly running) simulation.
-
-        Dependencies may reference completed or in-flight tasks.  The task
-        becomes ready once its outstanding dependencies finish (immediately
-        if there are none).
-        """
-        if task.tid in self.tasks:
-            raise ValueError(f"duplicate task id {task.tid}")
-        for d in task.deps:
-            if d not in self.tasks:
-                raise ValueError(f"task {task.tid} depends on unknown {d}")
-        self.tasks[task.tid] = task
-        self.durations[task.tid] = task.duration
-        self._next_tid = max(self._next_tid, task.tid + 1)
-        if not self._running:
-            return task
-        outstanding = [d for d in task.deps if d not in self._completed_ids]
-        self._n_deps[task.tid] = len(outstanding)
-        self._dependents.setdefault(task.tid, [])
-        for d in outstanding:
-            self._dependents.setdefault(d, []).append(task.tid)
-        if not outstanding:
-            self._enqueue(task.tid, self._now)
-        return task
-
-    def lane(self, resource: str,
-             name_fn: Optional[Callable[[str, object], str]] = None
-             ) -> ServiceLane:
-        """Open a :class:`ServiceLane` on a dedicated single-server
-        resource (see the class docstring for the contract)."""
-        ln = ServiceLane(self, resource, name_fn)
-        self._lanes.append(ln)
-        return ln
-
-    def template_lane(self, resource: str,
-                      step_durs: Optional[Callable] = None) -> TemplateLane:
-        """Open a :class:`TemplateLane` — graph-structured phases with
-        one event per phase (see the class docstring for the contract)."""
-        ln = TemplateLane(self, resource, step_durs)
-        self._lanes.append(ln)
-        return ln
-
-    def next_task_id(self) -> int:
-        """A fresh task id (monotone counter above every existing id)."""
-        return self._next_tid
-
-    def cancel_tasks(self, tids: Iterable[int]) -> None:
-        """Cancel uncompleted tasks mid-run (a replica crash in the
-        serving simulator's dict-graph mode).
-
-        Queued and dependency-blocked tasks are dropped before they
-        start; an in-flight task's record is truncated at the current
-        time (work really done before the crash stays recorded), its
-        pending completion event is voided, and its server freed.
-        Cancelled tasks count as completed for the run's termination
-        check but never reach ``on_complete`` or release dependents.
-        FIFO resources only — a bandwidth-shared channel would need a
-        rate re-plan for the surviving tasks."""
-        if not self._running:
-            raise RuntimeError("cancel_tasks is only valid during run()")
-        now = self._now
-        started_res = []
-        for tid in tids:
-            if tid in self._completed_ids or tid not in self.tasks:
-                continue
-            res = self.tasks[tid].resource
-            if self._spec(res).mode == "shared":
-                raise NotImplementedError(
-                    "cancel_tasks on bandwidth-shared resources")
-            queued = False
-            q = self._queues.get(res)
-            if q:
-                for i, (_, qt) in enumerate(q):
-                    if qt == tid:
-                        q[i] = q[-1]
-                        q.pop()
-                        heapq.heapify(q)
-                        queued = True
-                        break
-            if not queued and self._n_deps.get(tid, 0) == 0:
-                # started: truncate its record, void the pending 'done'
-                for r in reversed(self._records):
-                    if r.task.tid == tid:
-                        if r.end > now:
-                            self._res_busy[res] -= r.end - now
-                            r.end = now
-                        break
-                self._active[res] -= 1
-                self._void.add(tid)
-                started_res.append(res)
-            self._n_deps[tid] = 0
-            self._completed_ids.add(tid)
-        for res in started_res:
-            self._drain(res)
-
-    # ------------------------------------------------------------------
-    # Event loop internals
-    # ------------------------------------------------------------------
-
-    def _push_event(self, t_ev: float, kind: str, payload) -> None:
-        self._seq += 1
-        heapq.heappush(self._events, (t_ev, self._seq, kind, payload))
-
-    def _chan_probe(self, res: str, ch: "_SharedChannel",
-                    t: float) -> None:
-        """Record a shared channel's active count and per-task bandwidth
-        share at a rate-change boundary (admit/complete) — called only
-        when a probe is installed."""
-        g = self._chan_gauges.get(res)
-        if g is None:
-            g = self._chan_gauges[res] = (
-                self.probe.gauge(f"engine/chan/{res}/active", unit="tasks"),
-                self.probe.gauge(f"engine/chan/{res}/share", unit="frac"))
-        g[0].set(t, ch.n)
-        g[1].set(t, ch.rate)
-
-    def _reschedule_channel(self, res: str) -> None:
-        ch = self._channels[res]
-        ch.epoch += 1
-        t_next = ch.next_completion(self._now)
-        if t_next is not None:
-            self._push_event(t_next, "chan", (res, ch.epoch))
-
-    def _enqueue(self, tid: int, t_ready: float) -> None:
-        t = self.tasks[tid]
-        spec = self._spec(t.resource)
-        if spec.mode == "shared":
-            ch = self._channels.get(t.resource)
-            if ch is None:
-                ch = self._channels[t.resource] = _SharedChannel(spec.servers)
-            ch.admit(tid, self.durations[tid], t_ready)
-            self._reschedule_channel(t.resource)
-            if self.probe is not None:
-                self._chan_probe(t.resource, ch, t_ready)
-        else:
-            q = self._queues.setdefault(t.resource, [])
-            heapq.heappush(q, (t_ready, tid))
-            self._drain(t.resource)
-
-    def _drain(self, resource: str) -> None:
-        spec = self._spec(resource)
-        q = self._queues.get(resource)
-        while q and self._active.get(resource, 0) < spec.servers:
-            t_ready, tid = heapq.heappop(q)
-            t = self.tasks[tid]
-            dur = self.durations[tid]
-            start = max(t_ready, self._now)
-            end = start + dur
-            self._active[resource] = self._active.get(resource, 0) + 1
-            self._res_busy[resource] = self._res_busy.get(resource, 0.0) + dur
-            self._records.append(TaskRecord(t, start, end))
-            self._push_event(end, "done", tid)
-
-    def _complete(self, tid: int) -> None:
-        self._completed_ids.add(tid)
-        for dep_tid in self._dependents.get(tid, ()):
-            self._n_deps[dep_tid] -= 1
-            if self._n_deps[dep_tid] == 0:
-                self._enqueue(dep_tid, self._now)
-        if self.on_complete is not None:
-            self.on_complete(self.tasks[tid], self._now)
-
-    def run(self) -> SimResult:
-        if self._running or self._completed_ids:
-            raise RuntimeError("Simulator.run() may only be called once")
-        self._running = True
-        self._n_deps = {tid: len(t.deps) for tid, t in self.tasks.items()}
-        self._dependents = {tid: [] for tid in self.tasks}
-        for t in self.tasks.values():
-            for d in t.deps:
-                self._dependents[d].append(t.tid)
-
-        for tid, n in list(self._n_deps.items()):
-            if n == 0:
-                self._enqueue(tid, 0.0)
-
-        # Observability: one local None-check per event when disabled
-        # (the default) — counters live only behind an installed probe.
-        prb = self.probe
-        if prb is not None:
-            p_done = prb.counter("engine/fifo_completions")
-            p_lane = prb.counter("engine/lane_completions")
-            p_call = prb.counter("engine/callbacks")
-            p_chan = prb.counter("engine/chan_completions")
-
-        events = self._events
-        void = self._void
-        while events:
-            self._now, _, kind, payload = heapq.heappop(events)
-            if kind == "done":
-                tid = payload
-                if void and tid in void:
-                    void.discard(tid)     # cancelled mid-flight
-                    continue
-                t = self.tasks[tid]
-                self._active[t.resource] -= 1
-                self._complete(tid)
-                self._drain(t.resource)
-                if prb is not None:
-                    p_done.add(self._now)
-            elif kind == "lane":
-                ln, handler, epoch = payload
-                if epoch != ln.epoch:
-                    continue                  # superseded by a truncation
-                ln.busy = False
-                handler(self._now)
-                if prb is not None:
-                    p_lane.add(self._now)
-            elif kind == "call":
-                payload()
-                if prb is not None:
-                    p_call.add(self._now)
-            else:  # 'chan'
-                res, epoch = payload
-                ch = self._channels[res]
-                if epoch != ch.epoch:
-                    continue                      # superseded by a re-plan
-                for tid in ch.pop_done(self._now):
-                    t = self.tasks[tid]
-                    self._res_busy[res] = (self._res_busy.get(res, 0.0)
-                                           + self.durations[tid])
-                    self._records.append(
-                        TaskRecord(t, ch.start.pop(tid), self._now))
-                    self._complete(tid)
-                    if prb is not None:
-                        p_chan.add(self._now)
-                self._reschedule_channel(res)
-                if prb is not None:
-                    self._chan_probe(res, ch, self._now)
-
-        if len(self._completed_ids) != len(self.tasks):
-            stuck = [tid for tid, n in self._n_deps.items() if n > 0]
-            raise RuntimeError(
-                f"deadlock/cycle: {len(stuck)} tasks never ran, e.g. "
-                f"{[self.tasks[t].name for t in stuck[:5]]}")
-        self._running = False
-
-        makespan = max((r.end for r in self._records), default=0.0)
-        layer_time: Dict[str, Tuple[float, float]] = {}
-        for r in self._records:
-            lay = r.task.layer
-            if lay in layer_time:
-                s, e = layer_time[lay]
-                layer_time[lay] = (min(s, r.start), max(e, r.end))
-            else:
-                layer_time[lay] = (r.start, r.end)
-
-        lanes = [ln for ln in self._lanes if ln._nonempty()]
-        for ln in lanes:
-            makespan = max(makespan, ln._merge(self._res_busy, layer_time))
-
-        if not lanes:
-            return SimResult(makespan=makespan, records=self._records,
-                             resource_busy=self._res_busy,
-                             layer_time=layer_time)
-
-        static_records = self._records
-        tid0 = self._next_tid
-
-        def materialize() -> List[TaskRecord]:
-            out = list(static_records)
-            base = tid0
-            for ln in lanes:
-                recs = ln._materialize(base)
-                out.extend(recs)
-                base += len(recs)
-            return out
-
-        return SimResult(makespan=makespan, records_thunk=materialize,
-                         resource_busy=self._res_busy, layer_time=layer_time)
-
-
 # ---------------------------------------------------------------------------
 # Array-backed fast path for static graphs
 # ---------------------------------------------------------------------------
@@ -1203,12 +735,13 @@ def simulate_static(tasks: Sequence[Task],
     """Run a *static* task graph (no callbacks, no injection) over
     precomputed dependency arrays.
 
-    Same causal semantics as :class:`Simulator` — multi-server FIFO
+    Same causal semantics as :class:`DynamicSimulator` — multi-server FIFO
     stations, virtual-time processor-sharing channels, identical
     tie-breaking — but the hot loop indexes flat lists instead of dicts
     and defers ``TaskRecord`` materialization until a trace is read, so
     ``reannotate``-then-simulate sweep points skip all per-task object
-    churn.  Exact-parity with the general engine is asserted by
+    churn.  Parity with the seed engine and bit-exact parity with
+    :class:`DynamicSimulator` are asserted by
     ``tests/test_engine_parity.py``.
 
     ``probe`` enables instrumentation with *zero* in-loop cost: the
@@ -1244,21 +777,22 @@ def simulate_static(tasks: Sequence[Task],
 
     res_of = cache.res_of
     tids = cache.tids            # equal-time ties break by tid, not index,
-    dependents = cache.dependents    # mirroring the general Simulator
+    dependents = cache.dependents    # mirroring DynamicSimulator
     indeg = list(cache.indeg)
     starts = [0.0] * n
     ends = [0.0] * n
     busy = [0.0] * n_res
     active = [0] * n_res
     queues: List[List[Tuple[float, int]]] = [[] for _ in range(n_res)]
-    # Shared channels live as flat per-resource state (virtual-time GPS
-    # with the object/property overhead of _SharedChannel inlined away):
+    # Shared channels live as flat per-resource state (virtual-time GPS:
+    # a task admitted with ``work`` at virtual time v finishes at the fixed
+    # virtual time v + work; the clock runs at min(1, servers / n)):
     ch_heap: List[Optional[List[Tuple[float, int]]]] = [None] * n_res
     ch_vnow = [0.0] * n_res      # virtual clock
     ch_last = [0.0] * n_res      # real time of the last advance
     ch_n = [0] * n_res           # active tasks
     ch_epoch = [0] * n_res       # invalidates superseded completion events
-    rel_eps = _SharedChannel.REL_EPS
+    rel_eps = _REL_EPS
     events: List[Tuple[float, int, int, object]] = []
     # event tuple: (time, seq, code, payload); code 0 = fifo done
     # (payload = task index), code 1 = channel completion
@@ -1618,20 +1152,20 @@ class DynamicCache:
 
 
 class DynamicSimulator:
-    """Array-backed engine for *dynamic* simulations.
+    """Event-driven engine for *dynamic* simulations over FIFO and
+    bandwidth-shared resources.
 
-    The fast-path counterpart of :class:`Simulator`: the same causal
-    semantics, the same dynamic API (:meth:`at`, :meth:`inject`,
-    ``on_complete`` observers, :meth:`lane`), and the same event ordering
-    — exact parity is asserted task-for-task in
-    ``tests/test_engine_parity.py`` — but the hot loop indexes the flat
-    :class:`DynamicCache` arrays instead of per-task dicts, resource specs
-    are resolved once per resource name instead of per enqueue, and
-    ``TaskRecord``/name construction is deferred until a trace is read.
-    :meth:`inject_template` additionally amortizes the structure of a
-    repeatedly injected subgraph (one CSR walk per :class:`GraphTemplate`,
-    list extends per instance) — the serving simulator's task-graph mode
-    runs ~3-4x faster than the dict engine on it.
+    The event loop is instance-level state, so timed callbacks
+    (:meth:`at`) and completion observers (``on_complete``) can inject
+    new tasks (:meth:`inject`) while the simulation is running — dynamic
+    arrivals preempting a static task graph.  The hot loop indexes the
+    flat :class:`DynamicCache` arrays, resource specs are resolved once
+    per resource name, and ``TaskRecord``/name construction is deferred
+    until a trace is read.  :meth:`inject_template` amortizes the
+    structure of a repeatedly injected subgraph (one CSR walk per
+    :class:`GraphTemplate`, list extends per instance).  Parity with the
+    frozen seed engine, static graphs and mid-flight injection alike, is
+    asserted task-for-task in ``tests/test_engine_parity.py``.
     """
 
     def __init__(self, tasks: Iterable[Task] = (),
@@ -1643,8 +1177,10 @@ class DynamicSimulator:
         """``durations`` optionally overrides annotated durations (aligned
         with ``tasks``); ``cache`` optionally seeds the dependency layout
         from a precomputed :class:`StaticCache` of the same task list.
-        ``probe`` enables event-loop instrumentation (same contract as on
-        :class:`Simulator`: read-only, bit-identical results)."""
+        ``probe`` (a :class:`repro.obs.probe.Probe`) enables event-loop
+        instrumentation: per-kind event counters plus active/share gauges
+        on bandwidth-shared channels.  Probes only read simulation state —
+        results are bit-identical with or without one."""
         tasks = tasks if isinstance(tasks, list) else list(tasks)
         self.resources = dict(resources or {})
         self.on_complete = on_complete
@@ -1684,8 +1220,8 @@ class DynamicSimulator:
         self._servers: List[int] = []
         self._active: List[int] = []
         self._busy: List[float] = []
-        self._used: List[bool] = []   # ever scheduled a task (the dict
-        #                               engine reports those, even all-zero)
+        self._used: List[bool] = []   # ever scheduled a task (reported in
+        #                               resource_busy, even when all-zero)
         self._queues: List[List[Tuple[float, int, int]]] = []
         self._ch_heap: List[Optional[List[Tuple[float, int, int]]]] = []
         self._ch_vnow: List[float] = []
@@ -1703,16 +1239,21 @@ class DynamicSimulator:
         self._grow_resources()
 
     # ------------------------------------------------------------------
-    # Dynamic injection API (mirrors Simulator)
+    # Dynamic injection API
     # ------------------------------------------------------------------
 
     @property
     def now(self) -> float:
+        """Current simulation time."""
         return self._now
 
     def at(self, t: float, fn: Callable[[], None]) -> None:
-        """Schedule ``fn`` inside the event loop at time ``t`` (see
-        :meth:`Simulator.at`)."""
+        """Schedule ``fn`` to run inside the event loop at time ``t``.
+
+        Callbacks at equal times run in scheduling order.  ``fn`` may call
+        :meth:`inject` / :meth:`at` — this is how open-loop arrivals and
+        scheduler timeouts enter a running simulation.
+        """
         if t < self._now - 1e-18:
             raise ValueError(f"cannot schedule at {t} < now ({self._now})")
         self._seq += 1
@@ -1721,35 +1262,53 @@ class DynamicSimulator:
 
     def every(self, interval: float, fn: Callable[[], bool],
               start: Optional[float] = None) -> None:
-        """Periodic conditional callback (see :meth:`Simulator.every`)."""
-        _schedule_every(self, interval, fn, start)
+        """Run ``fn`` periodically inside the event loop (health checks,
+        autoscaler ticks).  The first tick fires at ``start`` (default
+        ``now + interval``), then every ``interval`` for as long as
+        ``fn()`` returns truthy.  Exactly one pending tick lives on the
+        event heap at a time, so a falsy return ends the chain, the heap
+        can drain and :meth:`run` can terminate: ``fn`` must return False
+        once the condition it monitors is resolved."""
+        if not (interval > 0.0) or not math.isfinite(interval):
+            raise ValueError(f"every(): interval must be finite and > 0, "
+                             f"got {interval!r}")
+
+        def _tick() -> None:
+            if fn():
+                self.at(self._now + interval, _tick)
+
+        self.at(self._now + interval if start is None else start, _tick)
 
     def next_task_id(self) -> int:
+        """A fresh task id (monotone counter above every existing id)."""
         return self._next_tid
 
     def lane(self, resource: str,
              name_fn: Optional[Callable[[str, object], str]] = None
              ) -> ServiceLane:
-        """Open a :class:`ServiceLane` (express path, same contract as on
-        the dict engine — lanes only touch the shared event heap)."""
+        """Open a :class:`ServiceLane` on a dedicated single-server
+        resource (see the class docstring for the contract)."""
         ln = ServiceLane(self, resource, name_fn)
         self._lanes.append(ln)
         return ln
 
     def template_lane(self, resource: str,
                       step_durs: Optional[Callable] = None) -> TemplateLane:
-        """Open a :class:`TemplateLane` — full graph-structured phase
-        records at lane speed (same contract as on the dict engine)."""
+        """Open a :class:`TemplateLane` — graph-structured phases with
+        one event per phase (see the class docstring for the contract)."""
         ln = TemplateLane(self, resource, step_durs)
         self._lanes.append(ln)
         return ln
 
     def inject(self, task: Task,
                on_done: Optional[Callable[[float], None]] = None) -> Task:
-        """Add ``task`` to a (possibly running) simulation — the exact
-        :meth:`Simulator.inject` semantics over the flat arrays.
-        ``on_done(now)`` additionally fires when this task completes
-        (after dependents are released and the global ``on_complete``)."""
+        """Add ``task`` to a (possibly running) simulation.
+
+        Dependencies may reference completed or in-flight tasks.  The task
+        becomes ready once its outstanding dependencies finish (immediately
+        if there are none).  ``on_done(now)`` fires when this task
+        completes (after dependents are released and the global
+        ``on_complete``)."""
         c = self.cache
         for d in task.deps:
             if d not in c.index_of:
@@ -1974,7 +1533,7 @@ class DynamicSimulator:
         servers = self._servers
         on_done = self._on_done
         enqueue = self._enqueue
-        rel_eps = _SharedChannel.REL_EPS
+        rel_eps = _REL_EPS
         pop = heapq.heappop
         push = heapq.heappush
         n_res_known = len(servers)
@@ -2012,16 +1571,6 @@ class DynamicSimulator:
                             starts[j] = now
                             end = now + dur
                             ends[j] = end
-                            if (dur == 0.0 and not dependents[j]
-                                    and self.on_complete is None
-                                    and j not in on_done):
-                                # completes at `now` with no observable
-                                # effect between dispatch and completion
-                                # (no deps to release, no callbacks): skip
-                                # the event round-trip entirely
-                                done_flags[j] = True
-                                n_done += 1
-                                continue
                             active[rj] += 1
                             busy[rj] += dur
                             self._seq += 1

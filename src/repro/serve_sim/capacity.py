@@ -251,7 +251,7 @@ class ClusterCapacityPlanner:
     draw cannot declare a redundancy level sufficient).
 
     Remaining keyword arguments (``health=``, ``hedge=``, ``breaker=``,
-    ``autoscaler=``, ``engine=`` ...) are forwarded to every
+    ``autoscaler=``, ``phase_tasks=`` ...) are forwarded to every
     :class:`~repro.serve_sim.cluster.ClusterSimulator` probe.
     """
 

@@ -22,8 +22,9 @@ resilience machinery on top:
   ``retry_budget``;
 * **hedging** — a request still unfinished after a p99-derived delay is
   duplicated to a second pool; first completion wins, the loser is
-  cancelled at its next scheduler boundary (the same instants on every
-  engine, so dict-vs-fast golden parity survives cancellation);
+  cancelled at its next scheduler boundary (the same instants whether or
+  not decode steps are fused, so leap-vs-per-step parity survives
+  cancellation);
 * **circuit breakers** — per-pool error-rate trips with half-open
   probing (:class:`~repro.serve_sim.router.CircuitBreakerPolicy`);
 * **autoscaling** — a reactive
@@ -35,8 +36,8 @@ resilience machinery on top:
 Parity contract (``tests/test_cluster.py``): a 1-pool cluster with
 pass-through routing and no health checks reproduces the standalone
 :class:`~repro.serve_sim.simulator.ServingSimulator` report bit-exactly
-on every engine — the cluster hooks are bookkeeping-only on that path
-(no RNG draws, no extra heap events at decision points).
+in express and graph mode — the cluster hooks are bookkeeping-only on
+that path (no RNG draws, no extra heap events at decision points).
 
 :class:`MonteCarloClusterSimulator` runs the cluster across a
 seed-batched :class:`~repro.serve_sim.workload.RequestBatch` (per-seed
@@ -53,7 +54,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.sim.engine import DynamicSimulator, SimResult, Simulator, Task
+from repro.core.sim.engine import DynamicSimulator, SimResult
 from repro.serve_sim.cost import ServingCostModel
 from repro.serve_sim.faults import FailureModel, RetryPolicy
 from repro.serve_sim.router import (AutoscalerPolicy, CircuitBreaker,
@@ -254,7 +255,6 @@ class ClusterSimulator:
                  breaker: Optional[CircuitBreakerPolicy] = None,
                  autoscaler: Optional[AutoscalerPolicy] = None,
                  phase_tasks: int = 0,
-                 engine: str = "fast",
                  probe=None,
                  record_events: bool = False,
                  fault_seed=None):
@@ -276,14 +276,8 @@ class ClusterSimulator:
         self.probe = probe
         P = self._n_pools = len(pools)
 
-        # One engine for the whole cluster: pools share its heap, task
-        # ids and (dict-graph mode) the completion dispatcher below.
-        if phase_tasks and engine == "fast":
-            self._sim = DynamicSimulator()
-        elif phase_tasks:
-            self._sim = Simulator(on_complete=self._task_done)
-        else:
-            self._sim = Simulator()
+        # One engine for the whole cluster: pools share its event heap.
+        self._sim = DynamicSimulator()
 
         try:
             expected = int(workload.n_requests)
@@ -302,7 +296,7 @@ class ClusterSimulator:
                 spec.cost, spec.scheduler, workload,
                 replicas=n_built, slots=spec.slots,
                 record_events=record_events, phase_tasks=phase_tasks,
-                engine=engine, probe=probe, failures=spec.failures,
+                probe=probe, failures=spec.failures,
                 retry=spec.retry, fault_seed=fs, sim=self._sim,
                 res_prefix=f"{spec.name}/", obs_ns=f"cluster/{spec.name}")
             if P > 1 and expected > 16 * P:
@@ -403,17 +397,6 @@ class ClusterSimulator:
             self._p_hedges = probe.counter("cluster/router/hedges")
             self._p_lost = probe.counter("cluster/router/lost",
                                          unit="requests")
-
-    # ---- engine plumbing -------------------------------------------------
-
-    def _task_done(self, task: Task, now: float) -> None:
-        """Dict-graph mode: dispatch a phase-tail completion to the pool
-        that injected it (task ids are unique across the shared engine)."""
-        for rt in self._rts:
-            h = rt._tail_handlers.pop(task.tid, None)
-            if h is not None:
-                h(now)
-                return
 
     def _ensure(self, rid: int) -> None:
         n = len(self._live)

@@ -150,8 +150,8 @@ class BatchScheduler(abc.ABC):
     #: there per the policy's real decisions — exact parity with per-step
     #: simulation (tests/test_serve_sim.py).  The same contract powers
     #: both serving representations: the express ``ServiceLane`` truncates
-    #: its fused task, and task-graph mode (``phase_tasks=N`` on the fast
-    #: engine) books the leap as one ``TemplateLane`` burst of per-step
+    #: its fused task, and task-graph mode (``phase_tasks=N``) books the
+    #: leap as one ``TemplateLane`` burst of per-step
     #: template instances and truncates the burst at a snapshot boundary.
     #: Policies whose mid-batch decisions depend on ``now``, on step
     #: count, or on queue depth while no slot is free must leave this

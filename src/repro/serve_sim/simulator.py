@@ -6,10 +6,9 @@ this module answers the ROADMAP's serving question at the concept phase:
 traffic?"* — before any prototype exists.
 
 Mechanics: every request arrival is a timed callback
-(:meth:`~repro.core.sim.engine.Simulator.at`) on the DES engine; each
-scheduler decision (prefill batch, decode step) is injected as a
-:class:`~repro.core.sim.engine.Task` on the replica's FIFO resource, with
-durations from the :class:`~repro.serve_sim.cost.ServingCostModel` (itself
+(:meth:`~repro.core.sim.engine.DynamicSimulator.at`) on the DES engine;
+each scheduler decision (prefill batch, decode step) is submitted as one
+phase on the replica's service lane, with durations from the :class:`~repro.serve_sim.cost.ServingCostModel` (itself
 derived from a compiled task graph, so what-if re-annotation flows through
 to serving metrics).  Completion callbacks drive the scheduler causally:
 finish a request, free its slot, admit the next, issue the next step.
@@ -36,7 +35,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.sim.engine import (DynamicSimulator, GraphTemplate,
-                                   SimResult, Simulator, Task)
+                                   SimResult, Task)
 from repro.serve_sim.cost import ServingCostModel
 from repro.serve_sim.faults import RetryPolicy, compile_faults
 from repro.serve_sim.scheduler import (BatchScheduler, Decode, InFlight,
@@ -445,7 +444,6 @@ class ServingSimulator:
                  slots: int = 8,
                  record_events: bool = False,
                  phase_tasks: int = 0,
-                 engine: str = "fast",
                  probe=None,
                  probe_engine: bool = False,
                  failures=None,
@@ -457,19 +455,16 @@ class ServingSimulator:
         """``phase_tasks > 0`` switches from the ServiceLane express path
         to *full task-graph mode*: every prefill/decode phase carries a
         real task graph (chained compute chunks, each followed by a
-        KV-write DMA on a sibling resource).  Chunk durations either
+        KV-write DMA on a sibling resource), and each replica runs as a
+        :class:`~repro.core.sim.engine.TemplateLane`: one event per phase,
+        speculative decode leaps booked as burst entries that truncate on
+        rollback.  Chunk durations either
         exact-split the phase cost or, when the cost model carries
         compiled-graph :class:`~repro.serve_sim.cost.PhaseProfile`\\ s,
         follow the compiled prefill/decode graphs' real compute/DMA
         structure — either way the chunk chain's total is the exact phase
         cost, so serving metrics match the express path to float
-        round-off while traces show intra-phase overlap.  ``engine``
-        selects the implementation: ``"fast"`` runs each replica as a
-        :class:`TemplateLane` (one event per phase, speculative decode
-        leaps with burst truncation — lane-path speed with full graph
-        records) while ``"dict"`` injects per-chunk tasks through the
-        general :class:`Simulator` and never speculates (the golden
-        per-step parity baseline).  ``probe`` (a
+        round-off while traces show intra-phase overlap.  ``probe`` (a
         :class:`repro.obs.probe.Probe`) enables queue-depth/occupancy/
         leap instrumentation; probes only read state, so instrumented
         runs stay bit-identical.  ``probe_engine=True`` additionally
@@ -499,9 +494,6 @@ class ServingSimulator:
             raise ValueError("need replicas >= 1 and slots >= 1")
         if phase_tasks < 0:
             raise ValueError("phase_tasks must be >= 0")
-        if engine not in ("fast", "dict"):
-            raise ValueError(f"unknown engine {engine!r} "
-                             "(expected 'fast' or 'dict')")
         self.cost = cost
         self.workload = workload
         self.res_prefix = res_prefix
@@ -519,9 +511,7 @@ class ServingSimulator:
         except Exception:
             cap = 0
         self.lane_state = LaneStateArrays(capacity=cap)
-        self._lanes: List = []
-        self._templates: Optional[Dict[Tuple[int, str], GraphTemplate]] = None
-        self._tail_handlers: Dict[int, Callable[[float], None]] = {}
+        self._templates: Dict[Tuple[int, str], GraphTemplate] = {}
         # Probe handles are bound once here; every hot-path site guards on
         # a single ``is not None`` branch so disabled runs pay one branch.
         # Enabled sites only bump plain-int accumulators and a shared
@@ -578,30 +568,17 @@ class ServingSimulator:
             "prefill": len(pp.compute) if pp is not None else self.phase_tasks,
             "decode": len(dp.compute) if dp is not None else self.phase_tasks,
         }
-        eng_probe = probe if probe_engine else None
+        self._sim = sim if sim is not None else DynamicSimulator(
+            probe=probe if probe_engine else None)
         if self.phase_tasks:
-            if engine == "fast":
-                self._sim = sim if sim is not None \
-                    else DynamicSimulator(probe=eng_probe)
-                self._templates = {}
-                # Graph mode on the fast engine: each replica is a
-                # TemplateLane — full chunk/DMA records per phase, one
-                # heap event per phase (and per fused leap), and burst
-                # truncation for speculative rollback.  The dict engine
-                # stays per-chunk injection: the parity baseline.
-                self._lanes = [
-                    self._sim.template_lane(self._res(r),
-                                            step_durs=self._burst_step_durs)
-                    for r in range(replicas)]
-            else:
-                # A shared dict engine already carries the owner's
-                # ``on_complete`` dispatcher, which must forward phase
-                # tails to this pool's ``_task_done``.
-                self._sim = sim if sim is not None \
-                    else Simulator(on_complete=self._task_done,
-                                   probe=eng_probe)
+            # Graph mode: each replica is a TemplateLane — full chunk/DMA
+            # records per phase, one heap event per phase (and per fused
+            # leap), and burst truncation for speculative rollback.
+            self._lanes = [
+                self._sim.template_lane(self._res(r),
+                                        step_durs=self._burst_step_durs)
+                for r in range(replicas)]
         else:
-            self._sim = sim if sim is not None else Simulator(probe=eng_probe)
             # Express path: each replica is a ServiceLane (one phase at a
             # time on a dedicated single-server resource) — no Task
             # construction or dependency bookkeeping per decode step,
@@ -609,11 +586,6 @@ class ServingSimulator:
             self._lanes = [self._sim.lane(self._res(r),
                                           name_fn=self._name_fn(r))
                            for r in range(replicas)]
-        # Speculative leaps need a truncatable lane: the express
-        # ServiceLane or graph mode's TemplateLane.  Dict-engine graph
-        # mode (per-chunk injection) stays per-step — it is the golden
-        # baseline the leap path is verified against.
-        self._spec_ok = bool(self._lanes)
         # Completion handlers are bound once per replica, not per step.
         self._phase_done = [self._phase_handler(rep) for rep in self.replicas]
         self._decode_done = [self._decode_handler(rep)
@@ -641,10 +613,6 @@ class ServingSimulator:
         self._down = [False] * replicas        # crash windows (no admission)
         self._speed = [1.0] * replicas         # slow-degrade cost factor
         self._attempts: Dict[int, int] = {}    # rid -> crashes survived
-        # dict-graph mode: in-flight phase's (tid0, tid_end, tail_tid) so a
-        # crash can cancel the injected chunk tasks
-        self._phase_range: List[Optional[Tuple[int, int, int]]] = \
-            [None] * replicas
         # (step boundaries, n_dec) of an in-flight fused decode: a crash
         # mid-leap commits the tokens of the steps whose boundary precedes
         # it — exactly what the per-step baseline would have delivered
@@ -691,13 +659,6 @@ class ServingSimulator:
         return lambda now: self._finish_decode(replica, now)
 
     # ---- phase submission: ServiceLane express path or task-graph mode --
-
-    def _task_done(self, task: Task, now: float) -> None:
-        """Dict-engine ``on_complete`` observer: dispatch phase-tail
-        completions to the bound replica handler."""
-        h = self._tail_handlers.pop(task.tid, None)
-        if h is not None:
-            h(now)
 
     def _template(self, idx: int, kind: str) -> GraphTemplate:
         tpl = self._templates.get((idx, kind))
@@ -748,31 +709,13 @@ class ServingSimulator:
             self._lanes[idx].submit(dur, handler, kind=kind, info=info)
             return
         durs = self._phase_durs(kind, dur)
-        sim = self._sim
-        if self._templates is not None:     # fast engine: TemplateLane
-            # Accumulate the tail end left-to-right over the chunk chain
-            # — bit-identical to the dict engine's chained chunk events.
-            end = sim.now
-            for i in range(0, len(durs), 2):
-                end += durs[i]
-            self._lanes[idx].submit(self._template(idx, kind), durs, end,
-                                    handler)
-            return
-        res = self._res(idx)                # dict engine baseline
-        kv = res + ":kv"
-        tid = tid0 = sim.next_task_id()
-        prev = -1
+        # Accumulate the tail end left-to-right over the chunk chain —
+        # bit-identical to chained chunk completion events.
+        end = self._sim.now
         for i in range(0, len(durs), 2):
-            sim.inject(Task(tid, f"{kind}/r{idx}/c{i // 2}", res, res,
-                            durs[i], deps=(prev,) if prev >= 0 else (),
-                            kind=kind))
-            sim.inject(Task(tid + 1, f"{kind}/r{idx}/kv{i // 2}", kv, kv,
-                            durs[i + 1], deps=(tid,), kind="dma"))
-            prev = tid
-            tid += 2
-        self._tail_handlers[prev] = handler
-        if self._faults is not None:
-            self._phase_range[idx] = (tid0, tid, prev)
+            end += durs[i]
+        self._lanes[idx].submit(self._template(idx, kind), durs, end,
+                                handler)
 
     # ---- arrivals --------------------------------------------------------
 
@@ -870,19 +813,10 @@ class ServingSimulator:
                 if j:
                     self._total_out_tokens += j * n_dec
             # then cancel the in-flight phase via the epoch machinery:
-            # the express lane keeps the truncated span, the fast-graph
-            # lane keeps committed burst steps and drops the rest, and
-            # dict-graph mode voids the injected chunks
-            if self._lanes:
-                self._lanes[idx].cancel(now)
-            else:
-                rng_t = self._phase_range[idx]
-                if rng_t is not None:
-                    tid0, tid_end, tail = rng_t
-                    self._tail_handlers.pop(tail, None)
-                    self._sim.cancel_tasks(range(tid0, tid_end))
+            # the express lane keeps the truncated span, the graph lane
+            # keeps committed burst steps and drops the rest
+            self._lanes[idx].cancel(now)
             replica.busy = False
-        self._phase_range[idx] = None
         self._leap[idx] = None
         self._fault_bounds[idx] = None
         if self.record_events:
@@ -1082,7 +1016,7 @@ class ServingSimulator:
         # speculatively: the per-step boundaries are kept so an arrival
         # landing mid-leap rolls the fused task back (ServiceLane
         # truncation on the express path, TemplateLane burst truncation
-        # in fast-engine graph mode).
+        # in graph mode).
         k = 1
         speculate = False
         leap_ok = k_min > 1 and not self.record_events
@@ -1093,11 +1027,9 @@ class ServingSimulator:
             # guarantee identical decode steps, so the leap is exact with
             # no snapshot needed.
             k = k_min
-        elif leap_ok and sched.decode_stable and self._spec_ok:
+        elif leap_ok and sched.decode_stable:
             # Admission possible: leap speculatively and arm rollback (an
-            # arrival may change the next-step decision).  Requires a
-            # truncatable lane — the dict-engine graph baseline has none
-            # and runs these batches per-step.
+            # arrival may change the next-step decision).
             k = k_min
             speculate = True
         # Exact per-step cost accumulation.  For the stock affine
@@ -1220,8 +1152,8 @@ class ServingSimulator:
                 finished.append(fl)
             elif cr is not None and fl.req.rid in cr:
                 # hedge loser: leaves the batch at this step boundary —
-                # the same instant on every engine, so dict-vs-fast
-                # golden parity holds under cancellation
+                # the same instant in every mode, so leap-vs-per-step
+                # parity holds under cancellation
                 fl.done = True
                 finished.append(fl)
             else:
@@ -1267,8 +1199,8 @@ class ServingSimulator:
         race on another pool).  A queued copy leaves immediately; an
         admitted copy is marked and released at its replica's next
         scheduler boundary — a prefill end or a decode step boundary,
-        which fall at the same instants on every engine, so the
-        dict-vs-fast golden contract survives cancellation.  An armed
+        which fall at the same instants whether or not decode steps are
+        fused, so leap-vs-per-step parity survives cancellation.  An armed
         speculative decode leap is rolled back first so that boundary
         arrives at per-step fidelity instead of the leap's far end.
         Returns ``"queued"`` / ``"inflight"`` / ``"absent"``."""
@@ -1417,7 +1349,7 @@ def simulate_serving(cost: ServingCostModel,
                      scheduler_factory: Callable[[], BatchScheduler],
                      workload: Workload, replicas: int = 1, slots: int = 8,
                      record_events: bool = False,
-                     phase_tasks: int = 0, engine: str = "fast",
+                     phase_tasks: int = 0,
                      probe=None, failures=None,
                      retry: Optional[RetryPolicy] = None,
                      fault_seed=None) -> ServingReport:
@@ -1425,6 +1357,6 @@ def simulate_serving(cost: ServingCostModel,
     return ServingSimulator(cost, scheduler_factory, workload,
                             replicas=replicas, slots=slots,
                             record_events=record_events,
-                            phase_tasks=phase_tasks, engine=engine,
+                            phase_tasks=phase_tasks,
                             probe=probe, failures=failures, retry=retry,
                             fault_seed=fault_seed).run()

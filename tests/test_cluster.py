@@ -4,12 +4,14 @@ failover, hedging, circuit breakers and fault-aware autoscaling.
 The load-bearing contracts:
 
 * **golden parity** — a 1-pool cluster behind :class:`PassThroughRouter`
-  reproduces the standalone :class:`ServingSimulator` bit-exactly on
-  every engine (express, dict-graph, fast-graph), with and without
-  faults: the routing tier is pure bookkeeping on that path.
-* **cross-engine parity** — a multi-pool cluster with the full
-  resilience stack (health checks, breakers, hedging, failover) is
-  bit-identical between the dict and fast graph engines.
+  reproduces the standalone :class:`ServingSimulator` bit-exactly in
+  every mode (express lane, graph mode per step, graph mode with
+  speculative leaps), with and without faults: the routing tier is pure
+  bookkeeping on that path.
+* **leap parity** — a multi-pool graph-mode cluster with the full
+  resilience stack (health checks, breakers, hedging, failover) gives
+  the same counts, routes and tokens with speculative decode leaps as
+  run per step, and the same latencies to round-off.
 * **determinism** — seeded cluster scenarios (including Monte-Carlo
   sweeps) replay bit-identically across runs.
 """
@@ -56,6 +58,13 @@ def _report_fields(r):
     }
 
 
+class PerStepContinuous(ContinuousBatchingScheduler):
+    """Continuous batching without the speculative-leap contract: a batch
+    with a free slot decodes step by step."""
+
+    decode_stable = False
+
+
 def _cluster_fields(r):
     return dict(_report_fields(r), availability=r.availability,
                 n_failovers=r.n_failovers,
@@ -71,24 +80,26 @@ def _cluster_fields(r):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("engine", ["express", "dict", "fast"])
+@pytest.mark.parametrize("mode", ["express", "graph_per_step", "graph"])
 @pytest.mark.parametrize("faulty", [False, True])
-def test_one_pool_passthrough_matches_standalone(engine, faulty):
+def test_one_pool_passthrough_matches_standalone(mode, faulty):
     kw = dict(replicas=4, slots=4)
     if faulty:
         kw.update(failures=CHURN, retry=RetryPolicy())
-    phase_tasks = 0 if engine == "express" else 2
-    eng = "fast" if engine == "express" else engine
+    phase_tasks = 0 if mode == "express" else 2
+    sched = (PerStepContinuous if mode == "graph_per_step"
+             else ContinuousBatchingScheduler)
 
     def wl():
         return poisson_workload(40.0, 400, seed=7)
 
-    solo = ServingSimulator(FAST, ContinuousBatchingScheduler, wl(),
-                            phase_tasks=phase_tasks, engine=eng, **kw).run()
+    solo = ServingSimulator(FAST, sched, wl(),
+                            phase_tasks=phase_tasks, **kw).run()
     pool = ReplicaPool("only", FAST, kw["replicas"], slots=kw["slots"],
-                       failures=kw.get("failures"), retry=kw.get("retry"))
+                       scheduler=sched, failures=kw.get("failures"),
+                       retry=kw.get("retry"))
     clus = ClusterSimulator([pool], wl(), PassThroughRouter(),
-                            phase_tasks=phase_tasks, engine=eng).run()
+                            phase_tasks=phase_tasks).run()
     assert _report_fields(solo) == _report_fields(clus)
     # the pool's own sub-report agrees with the aggregate too
     assert _report_fields(solo) == _report_fields(clus.pools["only"])
@@ -116,61 +127,58 @@ def test_one_pool_parity_is_bit_exact_on_fused_metrics():
 
 
 # ---------------------------------------------------------------------------
-# cross-engine parity: full resilience stack, dict vs fast graph engines
+# leap parity: full resilience stack, speculative leaps vs per step
 # ---------------------------------------------------------------------------
 
 
-def _chaos_pools(n=3):
+def _chaos_pools(n=3, scheduler=ContinuousBatchingScheduler):
     return [
-        ReplicaPool("zone-a", FAST, n, slots=4,
+        ReplicaPool("zone-a", FAST, n, slots=4, scheduler=scheduler,
                     failures=FailureModel(mtbf=8.0, mttr=2.0, seed=11,
                                           horizon=40.0),
                     retry=RetryPolicy()),
-        ReplicaPool("zone-b", SLOW, n, slots=4,
+        ReplicaPool("zone-b", SLOW, n, slots=4, scheduler=scheduler,
                     failures=FailureModel(mtbf=10.0, mttr=2.5, seed=12,
                                           horizon=40.0),
                     retry=RetryPolicy()),
-        ReplicaPool("zone-c", FAST, n, slots=4,
+        ReplicaPool("zone-c", FAST, n, slots=4, scheduler=scheduler,
                     failures=FailureModel(mtbf=9.0, mttr=2.0, seed=13,
                                           horizon=40.0),
                     retry=RetryPolicy()),
     ]
 
 
-def _chaos_run(engine, phase_tasks=2):
+def _chaos_run(scheduler=ContinuousBatchingScheduler, phase_tasks=2):
     return ClusterSimulator(
-        _chaos_pools(), poisson_workload(60.0, 1200, seed=5),
-        RoundRobinRouter(retry_budget=4), engine=engine,
-        phase_tasks=phase_tasks,
+        _chaos_pools(scheduler=scheduler),
+        poisson_workload(60.0, 1200, seed=5),
+        RoundRobinRouter(retry_budget=4), phase_tasks=phase_tasks,
         health=HealthCheckPolicy(interval=0.5),
         hedge=HedgePolicy(delay=0.8, max_fraction=0.1),
         breaker=CircuitBreakerPolicy(error_threshold=4, window=5.0,
                                      cooldown=5.0)).run()
 
 
-def _assert_engines_agree(a, b):
-    """Dict vs fast graph engine: every count, route and token is
+def _assert_runs_agree(a, b):
+    """Speculative leaps vs per step: every count, route and token is
     bit-exact; float latencies agree to within accumulation-order ULPs
-    (the two engines sum task chains in different orders — a pre-existing
-    engine property, the schedules themselves are identical)."""
+    (a leap sums its steps in one pass, the per-step run step by step)."""
     fa, fb = _cluster_fields(a), _cluster_fields(b)
     for k in ("n_requests", "n_offered", "output_tokens", "n_failures",
               "n_retries", "n_abandoned", "n_failovers", "hedges_issued",
               "hedges_won", "hedge_waste_tokens", "n_lost", "n_routed",
               "breaker_trips"):
         assert fa[k] == fb[k], k
-    for k in ("duration", "availability", "fleet_availability"):
+    for k in ("duration", "availability", "fleet_availability",
+              "replica_util"):
         assert fa[k] == pytest.approx(fb[k], rel=1e-12), k
-    # busy-time integration under crash-cancelled work differs slightly
-    # between the engines (pre-existing, also true standalone)
-    assert fa["replica_util"] == pytest.approx(fb["replica_util"], rel=0.05)
     for k in ("ttft", "tpot", "e2e", "qd"):
         assert fa[k] == pytest.approx(fb[k], rel=1e-9), k
 
 
-def test_chaos_cluster_dict_vs_fast_graph_engines_agree():
-    a, b = _chaos_run("fast"), _chaos_run("dict")
-    _assert_engines_agree(a, b)
+def test_chaos_cluster_graph_leap_matches_per_step():
+    a, b = _chaos_run(), _chaos_run(PerStepContinuous)
+    _assert_runs_agree(a, b)
     for name in ("zone-a", "zone-b", "zone-c"):
         ra, rb = a.pools[name], b.pools[name]
         for k in ("n_requests", "n_offered", "output_tokens", "n_failures",
@@ -180,12 +188,12 @@ def test_chaos_cluster_dict_vs_fast_graph_engines_agree():
 
 
 def test_chaos_cluster_seeded_replay_is_bit_identical():
-    a, b = _chaos_run("fast"), _chaos_run("fast")
+    a, b = _chaos_run(), _chaos_run()
     assert _cluster_fields(a) == _cluster_fields(b)
 
 
 def test_chaos_cluster_exercises_the_resilience_machinery():
-    r = _chaos_run("fast")
+    r = _chaos_run()
     assert r.n_requests == r.n_offered == 1200     # nothing lost end-to-end
     assert r.n_failures > 0 and r.n_failovers > 0
     assert r.hedges_issued > 0 and r.hedges_won > 0
@@ -667,11 +675,11 @@ def test_cluster_probe_namespaces_per_pool_and_router_series():
 
 def test_probe_does_not_perturb_cluster_results():
     from repro.obs import Probe
-    base = _chaos_run("fast")
+    base = _chaos_run()
     p = Probe("parity")
     inst = ClusterSimulator(
         _chaos_pools(), poisson_workload(60.0, 1200, seed=5),
-        RoundRobinRouter(retry_budget=4), engine="fast", phase_tasks=2,
+        RoundRobinRouter(retry_budget=4), phase_tasks=2,
         health=HealthCheckPolicy(interval=0.5),
         hedge=HedgePolicy(delay=0.8, max_fraction=0.1),
         breaker=CircuitBreakerPolicy(error_threshold=4, window=5.0,
@@ -686,8 +694,8 @@ def test_probe_does_not_perturb_cluster_results():
 
 
 def test_engine_every_runs_until_fn_returns_false():
-    from repro.core.sim.engine import Simulator
-    sim = Simulator()
+    from repro.core.sim.engine import DynamicSimulator
+    sim = DynamicSimulator()
     ticks = []
     sim.at(0.0, lambda: None)
 
@@ -701,8 +709,8 @@ def test_engine_every_runs_until_fn_returns_false():
 
 
 def test_engine_every_rejects_bad_interval():
-    from repro.core.sim.engine import Simulator
-    sim = Simulator()
+    from repro.core.sim.engine import DynamicSimulator
+    sim = DynamicSimulator()
     for bad in (0.0, -1.0, math.nan, math.inf):
         with pytest.raises(ValueError):
             sim.every(bad, lambda: False)

@@ -12,6 +12,7 @@ import math
 
 import pytest
 from _hypothesis_compat import given, settings, st
+from _seed_replay import assert_matches_seed_replay
 
 from repro.serve_sim import (SLO, CapacityPlanner,
                              ContinuousBatchingScheduler, FailureModel,
@@ -231,7 +232,7 @@ def test_per_request_slo_attainment_counts_dropped_as_misses():
 
 
 # ---------------------------------------------------------------------------
-# deterministic tie-breaks: dict engine, lane engine and fused path agree
+# deterministic tie-breaks: graph mode, express lane and fused path agree
 # ---------------------------------------------------------------------------
 
 
@@ -240,26 +241,42 @@ def _metric_rows(rep):
             for m in rep.requests]
 
 
-def test_tiebreak_fault_at_arrival_timestamp_graph_engines_agree():
+def _assert_rows_close(ref, other):
+    """Same requests on the same replicas and slots; times to round-off
+    (graph mode sums a phase's chunks, the express lane books it whole)."""
+    assert len(ref.requests) == len(other.requests)
+    for ra, rb in zip(_metric_rows(ref), _metric_rows(other)):
+        assert ra[:3] == rb[:3]
+        for va, vb in zip(ra[3:], rb[3:]):
+            assert vb == pytest.approx(va, rel=1e-9, abs=1e-12)
+
+
+def test_tiebreak_fault_at_arrival_timestamp_graph_and_express_lane_agree():
     """A failure event landing exactly on an arrival (and a repair on a
-    later arrival) must order identically in the per-chunk dict engine
-    and the TemplateLane fast engine."""
+    later arrival) must order identically in TemplateLane graph mode and
+    on the express ServiceLane; the graph lane's schedule replays exactly
+    on the seed engine."""
     rows = [(0.05 * i, 64, 8) for i in range(40)]
     faults = [ReplicaFault(0, 0.25, 0.50),    # t_fail == arrival of rid 5
               ReplicaFault(1, 0.50, 0.75)]    # fail at repair timestamp
 
-    def run(engine):
+    def sim(phase_tasks):
         return ServingSimulator(TOY, ContinuousBatchingScheduler,
                                 trace_workload(rows), replicas=2, slots=4,
-                                phase_tasks=3, engine=engine,
+                                phase_tasks=phase_tasks,
                                 record_events=True, failures=faults,
-                                retry=CHURN_RETRY).run()
+                                retry=CHURN_RETRY)
 
-    fast, dict_ = run("fast"), run("dict")
-    assert fast.n_failures == dict_.n_failures == 2
-    assert fast.duration == dict_.duration
-    assert _metric_rows(fast) == _metric_rows(dict_)
-    assert _report_fields(fast) == _report_fields(dict_)
+    graph_sim = sim(3)
+    graph, lane = graph_sim.run(), sim(0).run()
+    assert graph.n_failures == lane.n_failures == 2
+    assert graph.events == lane.events        # same order of every tie
+    for k in ("n_requests", "output_tokens", "n_offered", "n_retries",
+              "n_abandoned", "n_shed"):
+        assert _report_fields(graph)[k] == _report_fields(lane)[k], k
+    assert graph.duration == pytest.approx(lane.duration, rel=1e-12)
+    _assert_rows_close(lane, graph)
+    assert_matches_seed_replay(graph_sim, graph)
 
 
 def test_tiebreak_fault_at_decode_completion_scalar_vs_fused():
@@ -331,17 +348,26 @@ def test_crash_mid_burst_lane_mode_matches_per_step_golden():
             assert vb == pytest.approx(va, rel=1e-9, abs=1e-12)
 
 
-def test_crash_mid_burst_graph_mode_dict_vs_fast_exact():
-    def run(engine):
+def test_crash_mid_burst_graph_mode_matches_per_step():
+    """The graph-mode mirror of the lane-mode test above: a crash inside
+    a fused decode (one TemplateLane phase) must commit the steps before
+    it and match the per-step golden run to round-off, with exact fault
+    counters; both lanes' surviving phases replay on the seed engine."""
+    def sim(record_events):
         return ServingSimulator(TOY, ContinuousBatchingScheduler,
                                 _burst_workload(), replicas=1, slots=4,
-                                phase_tasks=3, engine=engine,
-                                record_events=True, failures=_MID_BURST,
-                                retry=CHURN_RETRY).run()
-    fast, dict_ = run("fast"), run("dict")
-    assert fast.n_failures == dict_.n_failures == 1
-    assert fast.duration == dict_.duration
-    assert _metric_rows(fast) == _metric_rows(dict_)
+                                phase_tasks=3, record_events=record_events,
+                                failures=_MID_BURST, retry=CHURN_RETRY)
+    leap_sim, golden_sim = sim(False), sim(True)
+    leap, golden = leap_sim.run(), golden_sim.run()
+    assert leap.n_failures == golden.n_failures == 1
+    assert leap.n_retries == golden.n_retries > 0
+    assert leap.n_requests == golden.n_requests == 4
+    assert leap.output_tokens == golden.output_tokens
+    assert leap.duration == pytest.approx(golden.duration, rel=1e-12)
+    _assert_rows_close(golden, leap)
+    assert_matches_seed_replay(golden_sim, golden)
+    assert_matches_seed_replay(leap_sim, leap)
 
 
 # ---------------------------------------------------------------------------

@@ -6,8 +6,8 @@ import json
 import numpy as np
 import pytest
 
-from repro.core.sim.engine import (DynamicSimulator, ResourceSpec, Simulator,
-                                   Task, simulate_static)
+from repro.core.sim.engine import (DynamicSimulator, ResourceSpec, Task,
+                                   simulate_static)
 from repro.core.sim.trace import (ascii_gantt, chrome_trace,
                                   serving_chrome_trace, serving_trace_builder,
                                   trace_builder)
@@ -166,7 +166,7 @@ def _static_tasks():
 
 
 def test_chrome_trace_validates():
-    doc = chrome_trace(Simulator(_static_tasks()).run())
+    doc = chrome_trace(DynamicSimulator(_static_tasks()).run())
     assert validate_trace(doc) == []
     events = json.loads(doc)["traceEvents"]
     assert any(e["ph"] == "X" for e in events)
@@ -232,9 +232,9 @@ def _shared_tasks():
 
 def test_simulator_parity_with_probe():
     tasks, shared = _shared_tasks()
-    base = Simulator(tasks, resources=dict(shared)).run()
+    base = DynamicSimulator(tasks, resources=dict(shared)).run()
     p = Probe("on")
-    inst = Simulator(tasks, resources=dict(shared), probe=p).run()
+    inst = DynamicSimulator(tasks, resources=dict(shared), probe=p).run()
     assert inst.makespan == base.makespan
     assert [(r.task.tid, r.start, r.end) for r in inst.records] == \
            [(r.task.tid, r.start, r.end) for r in base.records]
@@ -335,7 +335,7 @@ def test_worker_pool_reports_into_global_probe():
 
 
 def test_ascii_gantt_narrow_width_does_not_raise():
-    res = Simulator(_static_tasks()).run()
+    res = DynamicSimulator(_static_tasks()).run()
     for w in (1, 5, 11, 12):
         out = ascii_gantt(res, width=w)
         assert "compute" in out or "#" in out

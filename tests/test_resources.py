@@ -4,7 +4,7 @@ and throughput conservation."""
 import pytest
 
 from repro.core.hw import tpu_v5e_pod
-from repro.core.sim.engine import ResourceSpec, Simulator, Task
+from repro.core.sim.engine import DynamicSimulator, ResourceSpec, Task
 
 
 def _spans(res):
@@ -20,7 +20,7 @@ def test_multi_server_fifo_parallelism():
     """k servers run k tasks concurrently; n tasks take ceil(n/k) waves."""
     tasks = [Task(i, f"t{i}", "L", "dma", 1.0) for i in range(6)]
     specs = {"dma": ResourceSpec("dma", servers=3, mode="fifo")}
-    res = Simulator(tasks, resources=specs).run()
+    res = DynamicSimulator(tasks, resources=specs).run()
     assert res.makespan == pytest.approx(2.0)
     assert res.resource_busy["dma"] == pytest.approx(6.0)
 
@@ -28,14 +28,14 @@ def test_multi_server_fifo_parallelism():
 def test_single_server_fifo_matches_legacy_exclusive():
     """Default spec (unknown resource) = 1-server FIFO = old behaviour."""
     tasks = [Task(0, "a", "L", "r", 1.0), Task(1, "b", "L", "r", 1.0)]
-    res = Simulator(tasks).run()
+    res = DynamicSimulator(tasks).run()
     assert res.makespan == pytest.approx(2.0)
 
 
 def test_fifo_more_servers_than_tasks():
     tasks = [Task(i, f"t{i}", "L", "r", 2.0) for i in range(3)]
     specs = {"r": ResourceSpec("r", servers=8)}
-    res = Simulator(tasks, resources=specs).run()
+    res = DynamicSimulator(tasks, resources=specs).run()
     assert res.makespan == pytest.approx(2.0)
 
 
@@ -49,7 +49,7 @@ def test_shared_channel_splits_bandwidth():
     together — not strictly serialized (old behaviour: 1.0 then 2.0)."""
     tasks = [Task(0, "a", "L", "link", 1.0), Task(1, "b", "L", "link", 1.0)]
     specs = {"link": ResourceSpec("link", servers=1, mode="shared")}
-    res = Simulator(tasks, resources=specs).run()
+    res = DynamicSimulator(tasks, resources=specs).run()
     spans = _spans(res)
     assert spans[0] == pytest.approx((0.0, 2.0))
     assert spans[1] == pytest.approx((0.0, 2.0))
@@ -63,12 +63,12 @@ def test_shared_channel_total_throughput_conserved():
     for k in (1, 2, 3):
         tasks = [Task(i, f"t{i}", "L", "link", d) for i, d in enumerate(durs)]
         specs = {"link": ResourceSpec("link", servers=k, mode="shared")}
-        res = Simulator(tasks, resources=specs).run()
+        res = DynamicSimulator(tasks, resources=specs).run()
         assert res.makespan >= sum(durs) / k - 1e-9
         assert res.resource_busy["link"] == pytest.approx(sum(durs))
     # width 1, all admitted at t=0: channel saturated until the end
     tasks = [Task(i, f"t{i}", "L", "link", d) for i, d in enumerate(durs)]
-    res = Simulator(tasks, resources={
+    res = DynamicSimulator(tasks, resources={
         "link": ResourceSpec("link", servers=1, mode="shared")}).run()
     assert res.makespan == pytest.approx(sum(durs))
 
@@ -76,7 +76,7 @@ def test_shared_channel_total_throughput_conserved():
 def test_shared_channel_under_capacity_runs_full_rate():
     tasks = [Task(0, "a", "L", "link", 2.0), Task(1, "b", "L", "link", 3.0)]
     specs = {"link": ResourceSpec("link", servers=2, mode="shared")}
-    res = Simulator(tasks, resources=specs).run()
+    res = DynamicSimulator(tasks, resources=specs).run()
     spans = _spans(res)
     assert spans[0] == pytest.approx((0.0, 2.0))
     assert spans[1] == pytest.approx((0.0, 3.0))
@@ -91,7 +91,7 @@ def test_shared_channel_late_arrival_processor_sharing():
         Task(2, "b", "L", "link", 1.0, deps=(1,)),
     ]
     specs = {"link": ResourceSpec("link", servers=1, mode="shared")}
-    res = Simulator(tasks, resources=specs).run()
+    res = DynamicSimulator(tasks, resources=specs).run()
     spans = _spans(res)
     assert spans[0] == pytest.approx((0.0, 3.0))
     assert spans[2] == pytest.approx((1.0, 3.0))
@@ -106,7 +106,7 @@ def test_shared_channel_dependency_causality():
         Task(2, "c", "L", "nce", 0.5, deps=(0,)),
     ]
     specs = {"link": ResourceSpec("link", servers=1, mode="shared")}
-    res = Simulator(tasks, resources=specs).run()
+    res = DynamicSimulator(tasks, resources=specs).run()
     spans = _spans(res)
     assert spans[2][0] >= spans[0][1] - 1e-9
 
@@ -114,7 +114,7 @@ def test_shared_channel_dependency_causality():
 def test_zero_duration_task_on_shared_channel():
     tasks = [Task(0, "z", "L", "link", 0.0), Task(1, "a", "L", "link", 1.0)]
     specs = {"link": ResourceSpec("link", servers=1, mode="shared")}
-    res = Simulator(tasks, resources=specs).run()
+    res = DynamicSimulator(tasks, resources=specs).run()
     assert res.makespan == pytest.approx(1.0)
     assert len(res.records) == 2
 
@@ -144,7 +144,7 @@ def _mixed_workload():
 
 def test_des_deterministic_under_multi_server_resources():
     tasks, specs = _mixed_workload()
-    runs = [Simulator(tasks, resources=specs).run() for _ in range(3)]
+    runs = [DynamicSimulator(tasks, resources=specs).run() for _ in range(3)]
     base = runs[0]
     for other in runs[1:]:
         assert other.makespan == base.makespan
@@ -154,7 +154,7 @@ def test_des_deterministic_under_multi_server_resources():
 
 def test_mixed_workload_invariants():
     tasks, specs = _mixed_workload()
-    res = Simulator(tasks, resources=specs).run()
+    res = DynamicSimulator(tasks, resources=specs).run()
     spans = _spans(res)
     assert len(spans) == len(tasks)
     for t in tasks:
@@ -174,11 +174,11 @@ def test_duration_override_array():
     """The what-if fast path swaps durations without touching Tasks."""
     tasks = [Task(0, "a", "L", "r", 1.0), Task(1, "b", "L", "r", 1.0,
                                                deps=(0,))]
-    res = Simulator(tasks, durations=[0.5, 0.25]).run()
+    res = DynamicSimulator(tasks, durations=[0.5, 0.25]).run()
     assert res.makespan == pytest.approx(0.75)
     assert tasks[0].duration == 1.0          # untouched
     with pytest.raises(ValueError):
-        Simulator(tasks, durations=[0.5])
+        DynamicSimulator(tasks, durations=[0.5])
 
 
 # ---------------------------------------------------------------------------

@@ -6,8 +6,9 @@ import json
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
+from _seed_replay import assert_matches_seed_replay
 
-from repro.core.sim.engine import ResourceSpec, Simulator, Task
+from repro.core.sim.engine import DynamicSimulator, ResourceSpec, Task
 from repro.core.sim.trace import serving_chrome_trace
 from repro.serve_sim.scheduler import Decode, Prefill
 from repro.serve_sim import (SLO, BucketedPrefillScheduler, CapacityPlanner,
@@ -34,7 +35,7 @@ def toy_poisson(n=200, rate=20.0, seed=0):
 
 
 def test_engine_timed_callback_injects_tasks():
-    sim = Simulator(resources={"r": ResourceSpec("r")})
+    sim = DynamicSimulator(resources={"r": ResourceSpec("r")})
     sim.at(1.0, lambda: sim.inject(Task(0, "late", "L", "r", 2.0)))
     res = sim.run()
     rec = res.records[0]
@@ -44,7 +45,7 @@ def test_engine_timed_callback_injects_tasks():
 
 
 def test_engine_injected_task_waits_for_inflight_dep():
-    sim = Simulator([Task(0, "a", "L", "r", 2.0)])
+    sim = DynamicSimulator([Task(0, "a", "L", "r", 2.0)])
     sim.at(0.5, lambda: sim.inject(Task(1, "b", "L", "r", 1.0, deps=(0,))))
     res = sim.run()
     recs = {r.task.tid: r for r in res.records}
@@ -59,21 +60,21 @@ def test_engine_on_complete_chains_tasks():
         if task.tid < 3:
             sim.inject(Task(task.tid + 1, f"t{task.tid + 1}", "L", "r", 1.0))
 
-    sim = Simulator([Task(0, "t0", "L", "r", 1.0)], on_complete=hook)
+    sim = DynamicSimulator([Task(0, "t0", "L", "r", 1.0)], on_complete=hook)
     res = sim.run()
     assert [d[0] for d in done] == [0, 1, 2, 3]
     assert res.makespan == pytest.approx(4.0)
 
 
 def test_engine_next_task_id_monotone():
-    sim = Simulator([Task(5, "a", "L", "r", 1.0)])
+    sim = DynamicSimulator([Task(5, "a", "L", "r", 1.0)])
     assert sim.next_task_id() == 6
     sim.inject(Task(6, "b", "L", "r", 1.0))
     assert sim.next_task_id() == 7
 
 
 def test_engine_rejects_past_callback():
-    sim = Simulator()
+    sim = DynamicSimulator()
     with pytest.raises(ValueError):
         sim.at(-1.0, lambda: None)
 
@@ -270,7 +271,8 @@ def test_profile_from_graph_groups_real_tasks():
 def test_profiled_graph_mode_matches_affine_metrics():
     """Compiled-chunk durations re-shape *intra-phase* structure only:
     phase totals are unchanged, so serving metrics match the equal-split
-    graph mode to round-off, while both engines stay bit-identical."""
+    graph mode to round-off, while the lane's schedule stays
+    bit-identical to the seed engine running the same phases."""
     cost = _profiled_cost(phase_chunks=3)
     plain = ServingCostModel(
         name="plain", prefill_fixed=cost.prefill_fixed,
@@ -278,24 +280,19 @@ def test_profiled_graph_mode_matches_affine_metrics():
         decode_fixed=cost.decode_fixed,
         decode_per_token=cost.decode_per_token,
         decode_per_ctx_token=cost.decode_per_ctx_token)
-    prof = ServingSimulator(cost, ContinuousBatchingScheduler, toy_poisson(150),
-                            replicas=2, slots=4, phase_tasks=3,
-                            engine="fast", record_events=True).run()
+    prof_sim = ServingSimulator(cost, ContinuousBatchingScheduler,
+                                toy_poisson(150), replicas=2, slots=4,
+                                phase_tasks=3, record_events=True)
+    prof = prof_sim.run()
     affine = ServingSimulator(plain, ContinuousBatchingScheduler,
                               toy_poisson(150), replicas=2, slots=4,
-                              phase_tasks=3, engine="fast",
-                              record_events=True).run()
+                              phase_tasks=3, record_events=True).run()
     for ra, rb in zip(_metric_rows(affine), _metric_rows(prof)):
         assert ra[0] == rb[0]
         for va, vb in zip(ra[1:], rb[1:]):
             assert vb == pytest.approx(va, rel=1e-9, abs=1e-12)
-    # profile-carrying runs keep exact fast-vs-dict engine parity
-    dict_ = ServingSimulator(cost, ContinuousBatchingScheduler,
-                             toy_poisson(150), replicas=2, slots=4,
-                             phase_tasks=3, engine="dict",
-                             record_events=True).run()
-    assert prof.duration == dict_.duration
-    assert _metric_rows(prof) == _metric_rows(dict_)
+    # profile-carrying phases replay exactly on the seed engine
+    assert_matches_seed_replay(prof_sim, prof)
     # and the compiled structure shows up: KV DMAs have real durations
     kv = [r for r in prof.sim_result.records if r.task.kind == "dma"]
     assert kv and any(r.end > r.start for r in kv)
@@ -381,7 +378,7 @@ def test_closed_loop_serving_completes():
 
 
 # ---------------------------------------------------------------------------
-# task-graph injection mode: fast array engine vs dict engine (PR 4)
+# task-graph mode: the seed engine, the express lane, and per-step runs
 # ---------------------------------------------------------------------------
 
 
@@ -389,99 +386,89 @@ def _metric_rows(rep):
     return [(m.rid, m.t_admit, m.t_first, m.t_done) for m in rep.requests]
 
 
-def _assert_graph_runs_identical(fast, dict_):
-    """Bit-exact equality between a TemplateLane run and the dict-engine
-    per-chunk injection baseline: metrics, per-task spans, and run-level
-    aggregates.  Task ids differ by construction (lanes materialize
-    per-lane, the dict engine interleaves injection across replicas), so
-    spans compare on (name, start, end)."""
-    assert fast.duration == dict_.duration
-    assert fast.output_tokens == dict_.output_tokens
-    assert _metric_rows(fast) == _metric_rows(dict_)
-    for stat in ("ttft", "tpot", "e2e", "queue_delay"):
-        assert getattr(fast, stat) == getattr(dict_, stat)
-    assert fast.replica_util == dict_.replica_util
-    fast_spans = sorted((r.task.name, r.start, r.end)
-                        for r in fast.sim_result.records)
-    dict_spans = sorted((r.task.name, r.start, r.end)
-                        for r in dict_.sim_result.records)
-    assert fast_spans == dict_spans
-    assert fast.sim_result.resource_busy == dict_.sim_result.resource_busy
-    assert fast.sim_result.layer_time == dict_.sim_result.layer_time
+def _assert_metric_rows_close(ref, other, rel):
+    assert other.n_requests == ref.n_requests
+    assert other.output_tokens == ref.output_tokens
+    for ra, rb in zip(_metric_rows(ref), _metric_rows(other)):
+        assert ra[0] == rb[0]
+        for va, vb in zip(ra[1:], rb[1:]):
+            assert vb == pytest.approx(va, rel=rel, abs=1e-12)
+
+
+class PerStepContinuous(ContinuousBatchingScheduler):
+    """Continuous batching without the speculative-leap contract: decode
+    steps fuse only while admission is blocked, so a batch with a free
+    slot runs step by step — the baseline the speculative leap is held
+    to."""
+
+    decode_stable = False
 
 
 @pytest.mark.parametrize("chunks", [1, 3])
-def test_graph_mode_fast_matches_dict_engine_exactly(chunks):
-    """Per-step task-graph mode (record_events disables leaping on both
-    engines): the TemplateLane fast path must reproduce the dict engine
-    task-for-task and metric-for-metric (bit-identical — same
-    arithmetic, same event order)."""
-    fast = ServingSimulator(TOY, ContinuousBatchingScheduler, toy_poisson(250),
-                            replicas=2, slots=4, phase_tasks=chunks,
-                            engine="fast", record_events=True).run()
-    dict_ = ServingSimulator(TOY, ContinuousBatchingScheduler, toy_poisson(250),
-                             replicas=2, slots=4, phase_tasks=chunks,
-                             engine="dict", record_events=True).run()
-    assert fast.events == dict_.events
-    _assert_graph_runs_identical(fast, dict_)
+def test_graph_mode_matches_seed_engine_replay_exactly(chunks):
+    """Per-step task-graph mode (record_events disables leaping): the
+    TemplateLane's deferred schedule must equal the seed engine running
+    the same phases task by task (bit-identical — same arithmetic, same
+    event order), and the express lane at the same phase totals must make
+    the same decisions, with metrics equal to round-off."""
+    sim = ServingSimulator(TOY, ContinuousBatchingScheduler, toy_poisson(250),
+                           replicas=2, slots=4, phase_tasks=chunks,
+                           record_events=True)
+    graph = sim.run()
+    assert_matches_seed_replay(sim, graph)
+    lane = ServingSimulator(TOY, ContinuousBatchingScheduler, toy_poisson(250),
+                            replicas=2, slots=4, record_events=True).run()
+    assert graph.events == lane.events
+    _assert_metric_rows_close(lane, graph, rel=1e-9)
 
 
-def test_graph_mode_blocked_fusion_matches_dict_engine_exactly():
-    """Blocked (non-speculative) decode leaps fuse identically on both
-    engines — hold_finished static batching never takes the speculative
-    path, so leaping runs stay bit-identical to the dict baseline."""
-    fast = ServingSimulator(TOY, StaticBatchScheduler, toy_poisson(250),
-                            replicas=2, slots=4, phase_tasks=3,
-                            engine="fast").run()
-    dict_ = ServingSimulator(TOY, StaticBatchScheduler, toy_poisson(250),
-                             replicas=2, slots=4, phase_tasks=3,
-                             engine="dict").run()
-    _assert_graph_runs_identical(fast, dict_)
+def test_graph_mode_blocked_fusion_matches_seed_engine_exactly():
+    """Blocked (non-speculative) decode leaps: hold_finished static
+    batching never takes the speculative path, so every fused step is a
+    plain TemplateLane phase that the seed engine replays bit for bit;
+    the express lane fuses the same steps."""
+    sim = ServingSimulator(TOY, StaticBatchScheduler, toy_poisson(250),
+                           replicas=2, slots=4, phase_tasks=3)
+    graph = sim.run()
+    assert_matches_seed_replay(sim, graph)
+    lane = ServingSimulator(TOY, StaticBatchScheduler, toy_poisson(250),
+                            replicas=2, slots=4).run()
+    _assert_metric_rows_close(lane, graph, rel=1e-9)
 
 
 @pytest.mark.parametrize("chunks", [1, 4])
-def test_graph_mode_speculative_leap_matches_dict_per_step(chunks):
+def test_graph_mode_speculative_leap_matches_per_step(chunks):
     """Graph-mode speculative leaps (TemplateLane bursts + rollback)
-    against the dict engine running the same batches per step: metrics
+    against the same graph mode running those batches per step: metrics
     must agree to float round-off — the fused per-step boundaries use
-    the same arithmetic, accumulated in one pass."""
-    fast = ServingSimulator(TOY, ContinuousBatchingScheduler, toy_poisson(250),
-                            replicas=2, slots=4, phase_tasks=chunks,
-                            engine="fast").run()
-    dict_ = ServingSimulator(TOY, ContinuousBatchingScheduler, toy_poisson(250),
-                             replicas=2, slots=4, phase_tasks=chunks,
-                             engine="dict").run()
-    assert fast.n_requests == dict_.n_requests
-    assert fast.output_tokens == dict_.output_tokens
-    for ra, rb in zip(_metric_rows(dict_), _metric_rows(fast)):
-        assert ra[0] == rb[0]
-        for va, vb in zip(ra[1:], rb[1:]):
-            assert vb == pytest.approx(va, rel=1e-12, abs=1e-12)
+    the same arithmetic, accumulated in one pass.  The burst steps
+    replay on the seed engine to the same round-off."""
+    sim = ServingSimulator(TOY, ContinuousBatchingScheduler, toy_poisson(250),
+                           replicas=2, slots=4, phase_tasks=chunks)
+    leap = sim.run()
+    per_step = ServingSimulator(TOY, PerStepContinuous, toy_poisson(250),
+                                replicas=2, slots=4,
+                                phase_tasks=chunks).run()
+    _assert_metric_rows_close(per_step, leap, rel=1e-12)
+    assert_matches_seed_replay(sim, leap, exact=False)
 
 
-def test_graph_mode_scripted_rollback_matches_dict_per_step():
+def test_graph_mode_scripted_rollback_matches_per_step():
     """Scripted mid-leap interventions in graph mode: arrivals land
     while a TemplateLane burst is in flight, forcing truncation back to
-    a step boundary and per-step replay; the dict engine per-step run is
-    the ground truth."""
+    a step boundary and per-step replay; the same policy without the
+    speculative contract, run per step, is the ground truth."""
     fast = simulate_serving(TOY, lambda: ScriptedInterveningScheduler(32),
-                            _light_traffic(), slots=8, phase_tasks=4,
-                            engine="fast")
-    dict_ = simulate_serving(TOY, lambda: ScriptedInterveningScheduler(32),
-                             _light_traffic(), slots=8, phase_tasks=4,
-                             engine="dict")
-    assert fast.n_requests == dict_.n_requests
-    assert fast.output_tokens == dict_.output_tokens
-    for ra, rb in zip(_metric_rows(dict_), _metric_rows(fast)):
-        assert ra[0] == rb[0]
-        for va, vb in zip(ra[1:], rb[1:]):
-            assert vb == pytest.approx(va, rel=1e-12, abs=1e-12)
+                            _light_traffic(), slots=8, phase_tasks=4)
+    per_step = simulate_serving(TOY, lambda: PerStepScripted(32),
+                                _light_traffic(), slots=8, phase_tasks=4)
+    _assert_metric_rows_close(per_step, fast, rel=1e-12)
     # fusion must actually engage: far fewer materialized decode chunks
     fast_decode = sum(1 for r in fast.sim_result.records
                       if r.task.kind == "decode")
-    dict_decode = sum(1 for r in dict_.sim_result.records
+    step_decode = sum(1 for r in per_step.sim_result.records
                       if r.task.kind == "decode")
-    assert fast_decode == dict_decode     # every truncated step replays
+    assert fast_decode == step_decode     # every truncated step replays
 
 
 def test_graph_mode_burst_truncation_white_box():
@@ -546,9 +533,9 @@ def test_graph_mode_rejects_bad_args():
     with pytest.raises(ValueError):
         ServingSimulator(TOY, ContinuousBatchingScheduler, toy_poisson(5),
                          phase_tasks=-1)
-    with pytest.raises(ValueError):
+    with pytest.raises(TypeError):          # one engine: no engine= option
         ServingSimulator(TOY, ContinuousBatchingScheduler, toy_poisson(5),
-                         engine="verilog")
+                         engine="dict")
 
 
 # ---------------------------------------------------------------------------
@@ -565,6 +552,13 @@ class ScriptedInterveningScheduler(BucketedPrefillScheduler):
     name = "scripted"
     steady_decode = False
     decode_stable = True
+
+
+class PerStepScripted(ScriptedInterveningScheduler):
+    """The scripted policy without the speculative contract: every decode
+    step is its own phase."""
+
+    decode_stable = False
 
 
 def _light_traffic(n=300, seed=4):

@@ -6,35 +6,35 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core.sim.engine import Simulator, Task
+from repro.core.sim.engine import DynamicSimulator, Task
 from repro.core.sim.trace import ascii_gantt, chrome_trace
 
 
 def test_serial_chain():
     tasks = [Task(i, f"t{i}", "L", "r", 1.0, deps=(i - 1,) if i else ())
              for i in range(5)]
-    res = Simulator(tasks).run()
+    res = DynamicSimulator(tasks).run()
     assert res.makespan == pytest.approx(5.0)
     assert res.utilization("r") == pytest.approx(1.0)
 
 
 def test_parallel_resources():
     tasks = [Task(0, "a", "L", "r1", 2.0), Task(1, "b", "L", "r2", 3.0)]
-    res = Simulator(tasks).run()
+    res = DynamicSimulator(tasks).run()
     assert res.makespan == pytest.approx(3.0)
 
 
 def test_dependency_blocks_across_resources():
     tasks = [Task(0, "dma", "L", "dma0", 2.0),
              Task(1, "compute", "L", "nce", 1.0, deps=(0,))]
-    res = Simulator(tasks).run()
+    res = DynamicSimulator(tasks).run()
     recs = {r.task.name: r for r in res.records}
     assert recs["compute"].start == pytest.approx(2.0)
 
 
 def test_fifo_contention():
     tasks = [Task(0, "a", "L", "r", 1.0), Task(1, "b", "L", "r", 1.0)]
-    res = Simulator(tasks).run()
+    res = DynamicSimulator(tasks).run()
     assert res.makespan == pytest.approx(2.0)
     spans = sorted((r.start, r.end) for r in res.records)
     assert spans[0][1] <= spans[1][0] + 1e-12     # no overlap on a resource
@@ -44,12 +44,12 @@ def test_cycle_detection():
     tasks = [Task(0, "a", "L", "r", 1.0, deps=(1,)),
              Task(1, "b", "L", "r", 1.0, deps=(0,))]
     with pytest.raises(RuntimeError, match="deadlock|cycle"):
-        Simulator(tasks).run()
+        DynamicSimulator(tasks).run()
 
 
 def test_unknown_dep_rejected():
     with pytest.raises(ValueError):
-        Simulator([Task(0, "a", "L", "r", 1.0, deps=(7,))])
+        DynamicSimulator([Task(0, "a", "L", "r", 1.0, deps=(7,))])
 
 
 @settings(max_examples=30, deadline=None)
@@ -64,7 +64,7 @@ def test_random_dag_invariants(data):
         dur = data.draw(st.floats(0.01, 2.0))
         tasks.append(Task(i, f"t{i}", f"L{i % 5}", f"r{i % n_res}", dur,
                           deps=deps))
-    res = Simulator(tasks).run()
+    res = DynamicSimulator(tasks).run()
     recs = {r.task.tid: r for r in res.records}
     assert len(recs) == n
     # causality: every task starts after all deps end
@@ -91,7 +91,7 @@ def test_random_dag_invariants(data):
 def test_chrome_trace_valid_json(tmp_path):
     tasks = [Task(0, "a", "L", "nce", 1.0),
              Task(1, "b", "L", "dma0", 0.5, deps=(0,), kind="dma")]
-    res = Simulator(tasks).run()
+    res = DynamicSimulator(tasks).run()
     p = tmp_path / "trace.json"
     chrome_trace(res, str(p))
     data = json.loads(p.read_text())
