@@ -597,12 +597,3 @@ def init_embedding(key, vocab: int, d_model: int, dtype) -> Params:
 def embed(p: Params, tokens: jnp.ndarray, compute_dtype=jnp.bfloat16) -> jnp.ndarray:
     return jnp.take(p["table"], tokens, axis=0).astype(compute_dtype)
 
-
-def logits_from_embedding(p: Params, x: jnp.ndarray, softcap: float = 0.0,
-                          compute_dtype=jnp.bfloat16) -> jnp.ndarray:
-    y = jnp.einsum("...d,vd->...v", x.astype(compute_dtype),
-                   p["table"].astype(compute_dtype),
-                   preferred_element_type=jnp.float32)
-    if softcap:
-        y = jnp.tanh(y / softcap) * softcap
-    return y

@@ -5,6 +5,7 @@ Public surface (used by repro.models.api):
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -14,7 +15,7 @@ from repro.core.config import ModelConfig
 from repro.models import blocks as B
 from repro.models import layers as L
 from repro.models import scopes
-from repro.sharding import constrain
+from repro.sharding import active_mesh, constrain
 
 Params = Dict[str, Any]
 
@@ -32,17 +33,24 @@ def init_params(key, cfg: ModelConfig) -> Params:
     return p
 
 
+def _head_weight(p: Params, cfg: ModelConfig) -> jnp.ndarray:
+    """The head's weights as stored: the tied (V, D) table or (D, V)."""
+    return p["embed"]["table"] if cfg.tie_embeddings else p["lm_head"]["w"]
+
+
+def _logits(x, w, tied: bool, softcap: float) -> jnp.ndarray:
+    """f32 logits of rows ``x`` against head weights ``w`` ((V, D) when
+    tied, else (D, V)), both in the compute dtype; softcapped where set."""
+    z = jnp.einsum("...d,vd->...v" if tied else "...d,dv->...v", x, w,
+                   preferred_element_type=jnp.float32)
+    return jnp.tanh(z / softcap) * softcap if softcap else z
+
+
 @jax.named_scope(scopes.HEAD)
 def _head(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     cd = L.dtype_of(cfg.compute_dtype)
-    if cfg.tie_embeddings:
-        logits = L.logits_from_embedding(p["embed"], x, cfg.logit_softcap, cd)
-    else:
-        logits = jnp.einsum("...d,dv->...v", x.astype(cd),
-                            p["lm_head"]["w"].astype(cd),
-                            preferred_element_type=jnp.float32)
-        if cfg.logit_softcap:
-            logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
+    logits = _logits(x.astype(cd), _head_weight(p, cfg).astype(cd),
+                     cfg.tie_embeddings, cfg.logit_softcap)
     return constrain(logits, ("batch", "seq", "vocab"))
 
 
@@ -76,13 +84,128 @@ def hidden_states(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
     return L.apply_norm(p["final_norm"], x, cfg.norm_eps), stats
 
 
+XENT_ROWS = 512   # rows of a forward chunk of the head-plus-loss
+LANES = 128       # a backward vocabulary chunk is a multiple of this
+
+
+def xent_vocab_chunk(rows: int, vocab: int) -> int:
+    """Columns of one backward vocabulary chunk of ``head_xent``: a
+    multiple of ``LANES`` (or the whole vocabulary) for n to 2n chunks,
+    where n is the forward's count of row chunks, so an f32 (rows, chunk)
+    block is at most about the (XENT_ROWS, vocab) block of a forward chunk;
+    of those widths, the one whose chunks cover the vocabulary with the
+    fewest columns to spare, the widest of equals."""
+    n = max(1, -(-rows // XENT_ROWS))
+    widths = {min(vocab, -(-vocab // (k * LANES)) * LANES)
+              for k in range(n, 2 * n + 1)}
+    return min(widths, key=lambda w: (-(-vocab // w) * w, -w))
+
+
+@jax.named_scope(scopes.HEAD)
+def _head_xent_fwd(spec, w, h, targets, mask):
+    tied, softcap, cd = spec
+    wc, hc = w.astype(cd), h.astype(cd)
+    n = h.shape[0]
+    rows = min(XENT_ROWS, n)
+    nc = -(-n // rows)
+    hb = jnp.pad(hc, ((0, nc * rows - n), (0, 0))).reshape(nc, rows, -1)
+
+    def step(_, hr):
+        z = _logits(hr, wc, tied, softcap)
+        m = jnp.max(z, axis=-1)
+        return None, m + jnp.log(jnp.sum(jnp.exp(z - m[:, None]), axis=-1))
+
+    lse = jax.lax.scan(step, None, hb)[1].reshape(-1)[:n]
+    wt = jnp.take(wc, targets, axis=0) if tied else \
+        jnp.take(wc, targets, axis=1).T
+    zt = jnp.einsum("nd,nd->n", hc, wt, preferred_element_type=jnp.float32)
+    if softcap:
+        zt = jnp.tanh(zt / softcap) * softcap
+    count = jnp.maximum(jnp.sum(mask), 1.0)
+    loss = jnp.sum(mask * (lse - zt)) / count
+    return loss, (w, h, targets, mask, lse, count)
+
+
+@jax.named_scope(scopes.HEAD)
+def _head_xent_bwd(spec, res, g):
+    tied, softcap, cd = spec
+    w, h, targets, mask, lse, count = res
+    wc, hc = w.astype(cd), h.astype(cd)
+    vocab = w.shape[0] if tied else w.shape[1]
+    vc = xent_vocab_chunk(h.shape[0], vocab)
+    scale = (g * mask / count)[:, None]
+
+    # Chunk k covers columns [k*vc, (k+1)*vc); the last starts at vocab - vc
+    # instead, and zeroes the columns an earlier chunk owns.  Chunks run
+    # last first, so that earlier chunk then rewrites those rows of dw.
+    def step(carry, k):
+        dh, dw = carry
+        start = jnp.minimum(k * vc, vocab - vc)
+        at = (start, 0) if tied else (0, start)
+        wk = jax.lax.dynamic_slice(wc, at, (vc, w.shape[1]) if tied
+                                   else (w.shape[0], vc))
+        z = _logits(hc, wk, tied, softcap)
+        cols = start + jnp.arange(vc)
+        d = (jnp.exp(z - lse[:, None])
+             - (cols == targets[:, None]).astype(jnp.float32)) * scale
+        if softcap:
+            d = d * (1.0 - jnp.square(z / softcap))
+        d = jnp.where(cols >= k * vc, d, 0.0).astype(cd)
+        dh = dh + jnp.einsum("nv,vd->nd" if tied else "nv,dv->nd", d, wk,
+                             preferred_element_type=jnp.float32)
+        dwk = jnp.einsum("nv,nd->vd" if tied else "nv,nd->dv", d, hc,
+                         preferred_element_type=jnp.float32)
+        return (dh, jax.lax.dynamic_update_slice(dw, dwk.astype(w.dtype),
+                                                 at)), None
+
+    nc = -(-vocab // vc)
+    (dh, dw), _ = jax.lax.scan(
+        step, (jnp.zeros(h.shape, jnp.float32), jnp.zeros_like(w)),
+        jnp.arange(nc), reverse=True)
+    return dw, dh.astype(h.dtype), None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _head_xent(spec, w, h, targets, mask):
+    return _head_xent_fwd(spec, w, h, targets, mask)[0]
+
+
+_head_xent.defvjp(_head_xent_fwd, _head_xent_bwd)
+
+
+def head_xent(p: Params, cfg: ModelConfig, h: jnp.ndarray,
+              targets: jnp.ndarray, mask: Optional[jnp.ndarray] = None,
+              ) -> jnp.ndarray:
+    """Mean next-token cross-entropy of the vocabulary head over the rows
+    of ``h`` (B, S, D) whose ``mask`` is set, never holding (rows, V)
+    logits.  The forward scans ``XENT_ROWS``-row chunks for each row's f32
+    log-sum-exp and gathers the target's logit; the backward recomputes
+    the logits by vocabulary chunks (``xent_vocab_chunk``), so the input's
+    gradient sums in an f32 carry and each row of the head's weight
+    gradient is written once.  Products take compute-dtype operands with
+    f32 accumulation.  On a mesh of more than one device, where the
+    vocabulary is sharded, ``chunked_xent`` runs instead."""
+    mesh = active_mesh()
+    if mesh is not None and mesh.size > 1:
+        return chunked_xent(p, cfg, h, targets, mask)
+    D = h.shape[-1]
+    mf = jnp.ones(targets.shape, jnp.float32) if mask is None else \
+        mask.astype(jnp.float32)
+    spec = (cfg.tie_embeddings, float(cfg.logit_softcap or 0.0),
+            L.dtype_of(cfg.compute_dtype))
+    return _head_xent(spec, _head_weight(p, cfg), h.reshape(-1, D),
+                      targets.reshape(-1), mf.reshape(-1))
+
+
 def chunked_xent(p: Params, cfg: ModelConfig, h: jnp.ndarray,
                  targets: jnp.ndarray, mask: Optional[jnp.ndarray] = None,
-                 chunk: int = 512) -> jnp.ndarray:
+                 ) -> jnp.ndarray:
     """Cross-entropy without materialising full (B,S,V) logits: scan over
-    sequence chunks, computing head projection + log-softmax per chunk."""
+    sequence chunks, computing head projection + log-softmax per chunk.
+    Autodiff carries the head's weight gradient through the scan, so it
+    serves only a sharded vocabulary (``head_xent``)."""
     Bz, S, D = h.shape
-    chunk = min(chunk, S)
+    chunk = min(XENT_ROWS, S)
     nc = -(-S // chunk)
     pad = nc * chunk - S
     hf = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
@@ -111,7 +234,7 @@ def chunked_xent(p: Params, cfg: ModelConfig, h: jnp.ndarray,
 
 def loss_fn(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray], *,
             remat: str = "dots") -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Next-token cross-entropy (+ MoE aux); chunked head+xent keeps the
+    """Next-token cross-entropy (+ MoE aux); ``head_xent`` keeps the
     (B,S,V) logits tensor out of memory.  A MoE model's metrics also count
     the assignments routed to the experts held (``moe_held_assignments``,
     summed over the expert layers) and the worst layer's largest held
@@ -123,8 +246,8 @@ def loss_fn(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray], *,
         h = h[:, n_prefix:]
     targets = batch["tokens"][:, 1:]
     mask = batch.get("loss_mask")
-    loss = chunked_xent(p, cfg, h[:, :-1], targets,
-                        None if mask is None else mask[:, 1:])
+    loss = head_xent(p, cfg, h[:, :-1], targets,
+                     None if mask is None else mask[:, 1:])
     aux_coef = cfg.moe.aux_loss_coef if cfg.moe else 0.0
     total = loss + aux_coef * aux
     metrics = {"loss": loss, "aux": aux, "total": total}

@@ -168,7 +168,8 @@ def test_qwen_decode_step_fits_one_v5e(one_chip):
 def test_qwen_train_step_runs_the_flash_kernel_on_one_v5e(one_chip):
     """The benchmark's train step (1 x 4096 tokens, f32 master weights, bf16
     compute, remat dots): attention runs the splash kernels under the
-    ``attn_core`` scope, no scan loop is left there, and the step fits."""
+    ``attn_core`` scope, no scan loop is left there, the head's loops write
+    each row of its weight gradient once, and the step fits."""
     cfg = common.run_config(get_arch("qwen1.5-0.5b"), smoke=False)
     cfg = dataclasses.replace(cfg, param_dtype="float32",
                               compute_dtype="bfloat16")
@@ -179,8 +180,40 @@ def test_qwen_train_step_runs_the_flash_kernel_on_one_v5e(one_chip):
     compiled = jax.jit(steps_lib.make_train_step(cfg, opt_cfg, remat="dots"),
                        donate_argnums=(0, 1)).lower(
         params, opt_state, batch).compile()
-    _assert_splash_attention(compiled.as_text())
+    text = compiled.as_text()
+    _assert_splash_attention(text)
+    _assert_head_writes_weight_rows_once(text, f"{cfg.vocab_size},"
+                                         f"{cfg.d_model}")
     assert 0 < _total_bytes(compiled) < 0.9 * V5E_HBM_BYTES
+
+
+def _assert_head_writes_weight_rows_once(text, dims):
+    """The head-plus-loss runs as loops under ``head``, every op in them
+    that has a name names ``head`` (a constant's name is any of its
+    users'), and the f32 weight gradient (``dims``,
+    as the weights are stored) that the backward loop carries is only ever
+    written a chunk at a time by dynamic-update-slice: no loop adds into it
+    (what autodiff of a token-chunk scan did, once a chunk)."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%(\S+) [^\n]*\{\n(.*?)\n\}", text,
+                            re.S | re.M))
+    head = re.compile(r'op_name="[^"]*\bhead\b')
+    loops = [body for body in re.findall(r" while\(.*?body=%(\S+?),", text)
+             if head.search(comps[body])]
+    assert len(loops) == 2, "a forward and a backward loop under head"
+    grad = f"f32[{dims}]"
+    writes = []
+    for body in loops:
+        for line in comps[body].splitlines():
+            op = re.search(r'op_name="([^"]*)"', line)
+            assert op is None or " constant(" in line \
+                or re.search(r"\bhead\b", op.group(1)), line
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (\S+?)\{[^}]*\} ([\w-]+)\(",
+                         line)
+            if m and m.group(1) == grad:
+                assert m.group(2) in ("get-tuple-element",
+                                      "dynamic-update-slice"), line
+                writes.append(m.group(2))
+    assert writes.count("dynamic-update-slice") == 1
 
 
 def _assert_splash_attention(text):
@@ -237,6 +270,8 @@ def test_deepseek_v2_lite_train_step_fits_one_v5e_and_groups_by_expert(
         < 0.9 * V5E_HBM_BYTES
     text = compiled.as_text()
     _assert_splash_attention(text)
+    _assert_head_writes_weight_rows_once(text, f"{cfg.d_model},"
+                                         f"{cfg.vocab_size}")
     calls = re.findall(r"%(t?gmm)(?:\.\d+)? = \S+ custom-call\(.*?"
                        r'metadata=\{op_name="([^"]*)"', text, re.S)
     assert {name for name, _ in calls} == {"gmm", "tgmm"}
@@ -251,7 +286,9 @@ def test_dropless_moe_on_a_four_chip_mesh_keeps_xlas_ragged_dot(topo):
     """GSPMD does not partition a Mosaic call, so on a mesh of four
     described v5e chips (experts sharded on ``model``) the dropless train
     step keeps ``lax.ragged_dot``, which XLA partitions: it compiles, runs
-    no megablox kernel, and gathers no expert's weights onto one chip."""
+    no megablox kernel, and gathers no expert's weights onto one chip.  Nor
+    does it gather the head's weights, sharded by vocabulary: there the loss
+    scans token chunks (``lm.chunked_xent``), not vocabulary chunks."""
     import numpy as np
     from jax.sharding import Mesh
 
@@ -279,6 +316,7 @@ def test_dropless_moe_on_a_four_chip_mesh_keeps_xlas_ragged_dot(topo):
     gathered = re.findall(r"= \S+\[([0-9,]+)\]\S* all-gather", text)
     assert gathered and not any(dims.endswith(w) for dims in gathered
                                 for w in whole)
+    assert f"{cfg.d_model},{cfg.vocab_size}" not in gathered
 
 
 OPS = {"causal_flash_attention": (causal_flash_attention_op, 3),
