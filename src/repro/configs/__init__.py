@@ -6,6 +6,7 @@ from repro.configs import (  # noqa: F401
     dilated_vgg,
     granite_moe_1b_a400m,
     internvl2_2b,
+    joyai_llm_flash,
     jamba_1_5_large_398b,
     minitron_8b,
     mistral_large_123b,

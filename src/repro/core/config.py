@@ -88,6 +88,20 @@ class MoEConfig:
     # experts [0, experts_held) live here (expert parallelism); 0 = all.
     # The router keeps all num_experts outputs.  Dropless only.
     experts_held: int = 0
+    # "softmax": top-k of the softmax over the router's outputs.
+    # "sigmoid": DeepSeek-V3's noaux_tc routing: sigmoid affinities, top-k
+    # of affinity + a per-expert correction bias that no gradient reaches,
+    # gates from the affinities.  Dropless only.
+    scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0   # on the routed gates
+    # sigmoid scoring: the step moves each bias by this rate towards
+    # balance, b += rate * sign(mean load - load) (DeepSeek-V3's gamma)
+    bias_update_rate: float = 0.0
+    # dropless: the grouped matmuls visit at least this multiple of the
+    # rows a layer's held experts expect, S*K*held/E (zero rows past those
+    # routed, which add nothing), so that a step's work does not follow
+    # its routing below that bound; 0 = the rows routed alone
+    grouped_rows_floor: float = 0.0
 
     @property
     def held(self) -> int:
@@ -185,6 +199,10 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
+    # multi-token prediction (DeepSeek-V3): 1 = one depth after the last
+    # block predicting the token after next, its loss weighted by the coef
+    mtp_depth: int = 0
+    mtp_loss_coef: float = 0.0
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
     max_seq_len: int = 4096

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from repro.core.config import ModelConfig, OptimizerConfig, ShapeConfig
 from repro.launch import mesh as mesh_lib
 from repro.models import api
+from repro.models import layers as L
+from repro.models import scopes
 from repro.optim import adamw
 
 
@@ -27,9 +29,18 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
             return loss, metrics
 
         (loss, metrics), grads = jax.value_and_grad(loss_of, has_aux=True)(params)
+        metrics = dict(metrics)
+        loads = metrics.pop("router_loads", None)
         params, opt_state, opt_metrics = adamw.adamw_update(
             params, grads, opt_state, opt_cfg)
-        metrics = dict(metrics)
+        if loads:
+            # sigmoid routers: the correction bias moves towards balance
+            # by this step's loads (no gradient reaches it, and AdamW
+            # decays no bias, so AdamW has left it as it was)
+            with jax.named_scope(scopes.OPTIMIZER):
+                params, metrics["moe_bias_absmax"] = \
+                    L.rebalance_router_bias(params, loads,
+                                            cfg.moe.bias_update_rate)
         metrics.update(opt_metrics)
         return params, opt_state, metrics
 

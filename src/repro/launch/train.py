@@ -118,8 +118,15 @@ def main(argv=None):
                            f"  max load "
                            f"{float(metrics['moe_max_load']):5.2f}  "
                            if "moe_held_assignments" in metrics else "")
+                    extra = "".join(
+                        f"{label} {float(metrics[k]):.4g}  " for k, label in
+                        (("mtp_loss", "mtp loss"),
+                         ("moe_bias_absmax", "bias absmax"),
+                         ("moe_max_load_all", "max load all"))
+                        if k in metrics)
                     print(f"step {step_i + 1:5d}  loss {loss:8.4f}  "
                           f"gnorm {float(metrics['grad_norm']):7.3f}  {moe}"
+                          f"{extra}"
                           f"lr {float(metrics['lr']):.2e}  {dt * 1e3:7.1f} ms")
                 if (step_i + 1) % args.ckpt_every == 0:
                     ckpt.save(step_i + 1,

@@ -142,6 +142,8 @@ def apply_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
             y = L.apply_ffn(p["ffn"], h, cfg.act, cd)
         elif ffn == "moe":
             y, stats = L.apply_moe(p["ffn_moe"], h, cfg, compute_dtype=cd)
+            if "loads" in stats:
+                stats["loads"] = {"ffn_moe": stats["loads"]}
         else:  # rwkv channel mix
             y, c = R6.apply_channel_mix(
                 p["rwkv_cm"], h, cfg, mode=mode,
@@ -208,10 +210,13 @@ def apply_stack(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     """Run prefix blocks then the scanned periods.
 
     Returns (x, new_cache (same structure as cache, or None), the layers'
-    ``L.moe_stats`` merged).
+    ``L.moe_stats`` merged).  Where expert layers count their loads over
+    all experts (sigmoid routers), the stats also hold ``loads``, at the
+    places of their biases in ``params`` (stacked over the periods).
     """
     prefix, period, n_periods = block_pattern(cfg)
     total = L.moe_stats()
+    loads: Params = {}
     new_cache: Params = {}
 
     if prefix:
@@ -227,6 +232,8 @@ def apply_stack(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
                 remat if mode == "train" else "none")
             x, c, stats = run(blk, x, c_in)
             total = L.merge_moe_stats(total, stats)
+            if "loads" in stats:
+                loads.setdefault("prefix", {})[f"blk{i}"] = stats["loads"]
             if c is not None:
                 pc[f"blk{i}"] = c
         if pc:
@@ -236,21 +243,29 @@ def apply_stack(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         p_params, p_cache = scanned
         caches_out = {}
         period_stats = L.moe_stats()
+        period_loads = {}
         for j, (m, f) in enumerate(period):
             c_in = None if p_cache is None else p_cache[f"sub{j}"]
             x, c, stats = apply_block(p_params[f"sub{j}"], x, cfg, m, f,
                                       mode=mode, cache=c_in, pos=pos,
                                       causal=causal)
             period_stats = L.merge_moe_stats(period_stats, stats)
+            if "loads" in stats:
+                period_loads[f"sub{j}"] = stats["loads"]
             if c is not None:
                 caches_out[f"sub{j}"] = c
-        return x, (caches_out if caches_out else None, period_stats)
+        return x, (caches_out if caches_out else None, period_stats,
+                   period_loads)
 
     body = _remat_wrap(period_fn, remat if mode == "train" else "none")
     xs = (params["periods"], cache["periods"] if cache is not None else None)
-    x, (period_caches, per) = jax.lax.scan(body, x, xs)
+    x, (period_caches, per, per_loads) = jax.lax.scan(body, x, xs)
     total = L.merge_moe_stats(total, L.moe_stats(
         jnp.sum(per["aux"]), jnp.sum(per["held"]), jnp.max(per["max_load"])))
+    if per_loads:
+        loads["periods"] = per_loads
+    if loads:
+        total["loads"] = loads
     if period_caches is not None:
         new_cache["periods"] = period_caches
     return x, (new_cache if new_cache else None), total

@@ -379,6 +379,11 @@ def init_moe(key, cfg: ModelConfig, dtype) -> Params:
     if m.num_shared_experts:
         f_sh = m.d_ff_shared or f * m.num_shared_experts
         p["shared"] = init_ffn(keys[4], d, f_sh, "swiglu", dtype)
+    if m.scoring == "sigmoid":
+        # the correction bias: set by the step (``rebalance_router_bias``),
+        # kept in float32 whatever the parameters' dtype; AdamW leaves it
+        # as it is, as it gets no gradient and no decay (``/bias``)
+        p["router"]["bias"] = jnp.zeros((m.num_experts,), jnp.float32)
     return p
 
 
@@ -404,7 +409,8 @@ def moe_stats(aux=0.0, held=0.0, max_load=0.0) -> Dict[str, jnp.ndarray]:
 
 
 def merge_moe_stats(a, b):
-    """Over layers: losses and assignments add, the worst load is kept."""
+    """Over layers: losses and assignments add, the worst load is kept (a
+    layer's ``loads`` over all experts, where it counts them, stay out)."""
     return {"aux": a["aux"] + b["aux"], "held": a["held"] + b["held"],
             "max_load": jnp.maximum(a["max_load"], b["max_load"])}
 
@@ -433,8 +439,10 @@ def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig,
 
 def _capacity_moe(p: Params, x: jnp.ndarray, m: MoEConfig, cf: float,
                   compute_dtype):
-    if m.held != m.num_experts or not m.norm_topk_prob:
-        raise ValueError("experts_held and norm_topk_prob=False need the "
+    if m.held != m.num_experts or not m.norm_topk_prob \
+            or m.scoring != "softmax" or m.routed_scaling_factor != 1.0:
+        raise ValueError("experts_held, norm_topk_prob=False, sigmoid "
+                         "scoring and a routed scaling factor need the "
                          "dropless MoE (capacity_factor <= 0)")
     G0, S0, D = x.shape
     # re-group long sequences into fixed-size routing groups
@@ -544,27 +552,46 @@ def dropless_moe(p: Params, x: jnp.ndarray, m: MoEConfig, cd):
     """Top-k MoE that drops nothing, over the experts held (no shared
     expert).
 
-    The router keeps all ``num_experts`` outputs: softmax, greedy top-k,
-    gates renormalised where ``norm_topk_prob``.  The (token, choice)
+    The router keeps all ``num_experts`` outputs.  Softmax scoring: greedy
+    top-k of the softmax.  Sigmoid scoring (DeepSeek-V3's noaux_tc): f32
+    sigmoid affinities, top-k of affinity + the router's correction bias
+    (no gradient reaches it), gates from the affinities.  Gates are then
+    renormalised where ``norm_topk_prob`` and scaled by
+    ``routed_scaling_factor``.  The (token, choice)
     assignments are sorted by expert; those of the experts held,
     ``[0, experts_held)``, come first, and grouped matmuls run over them
-    alone.  The weighted results
+    alone, or, where ``grouped_rows_floor`` asks for more rows, on over
+    the zero rows after them (which change no number).  The weighted results
     go back to their tokens (the other experts' part is left out, as on
     the chip that holds only these).  The balance loss is DeepSeek's
     sequence-wise one, E/(S K) * sum_e count_e * mean_s p_e, averaged over
-    the sequences of x (B, S, D)."""
+    the sequences of x (B, S, D), where p is the softmax or, under sigmoid
+    scoring, the affinities normalised over all experts; count_e counts
+    the assignments as routed.  Under sigmoid scoring the stats also hold
+    ``loads``, the step's assignments to every one of the E experts, at
+    the bias's place in ``p`` (``{"router": {"bias": loads}}``)."""
     B, S, D = x.shape
     E, K, H = m.num_experts, m.num_experts_per_tok, m.held
     n = B * S * K
+    sigmoid = m.scoring == "sigmoid"
     with jax.named_scope(scopes.MOE_ROUTE):
         rd = dtype_of(m.router_dtype)
         logits = jnp.einsum("bsd,de->bse", x.astype(rd),
                             p["router"]["w"].astype(rd),
                             preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)                   # (B,S,E)
-        gate, idx = jax.lax.top_k(probs, K)                       # (B,S,K)
+        if sigmoid:
+            scores = jax.nn.sigmoid(logits)                       # (B,S,E)
+            bias = jax.lax.stop_gradient(p["router"]["bias"])
+            _, idx = jax.lax.top_k(scores + bias, K)
+            gate = jnp.take_along_axis(scores, idx, axis=-1)      # (B,S,K)
+            probs = scores / jnp.sum(scores, -1, keepdims=True)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)               # (B,S,E)
+            gate, idx = jax.lax.top_k(probs, K)                   # (B,S,K)
         if m.norm_topk_prob:
             gate = gate / jnp.maximum(jnp.sum(gate, -1, keepdims=True), 1e-9)
+        if m.routed_scaling_factor != 1.0:
+            gate = gate * m.routed_scaling_factor
         seq_counts = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32),
                              axis=(1, 2))                          # (B,E)
         aux = jnp.mean(jnp.sum(seq_counts * jnp.mean(probs, axis=1), -1)) \
@@ -577,12 +604,40 @@ def dropless_moe(p: Params, x: jnp.ndarray, m: MoEConfig, cd):
         held = (jnp.arange(n) < jnp.sum(counts))[:, None]
         rows = jnp.where(held, rows, 0)
     with jax.named_scope(scopes.MOE_EXPERTS):
-        out = _grouped_swiglu(p, rows, counts.astype(jnp.int32), cd)
+        sizes = counts.astype(jnp.int32)
+        if m.grouped_rows_floor > 0 and H < E:
+            # the last group runs on over zero rows up to the floor
+            floor = min(n, math.ceil(m.grouped_rows_floor * n * H / E))
+            sizes = sizes.at[-1].add(jnp.maximum(floor - jnp.sum(sizes), 0))
+        out = _grouped_swiglu(p, rows, sizes, cd)
     with jax.named_scope(scopes.MOE_ROUTE):
         w = jnp.take(gate.reshape(n), order)[:, None]
         out = (jnp.where(held, out, 0) * w).astype(cd)
         y = combine_rows(out, order, inverse, K).reshape(B, S, D).astype(cd)
-    return y, _load_stats(aux, counts)
+    stats = _load_stats(aux, counts)
+    if sigmoid:
+        stats["loads"] = {"router": {"bias": jnp.sum(seq_counts, axis=0)}}
+    return y, stats
+
+
+def rebalance_router_bias(params: Params, loads: Params,
+                          rate: float) -> Tuple[Params, jnp.ndarray]:
+    """DeepSeek-V3's balancing without an auxiliary loss, once a step:
+    each sigmoid router's correction bias moves towards balance,
+    b <- b + rate * sign(mean load - load), from the step's loads.
+    ``loads`` follows ``params`` down to each bias and holds there its
+    (..., E) assignments per expert; the mean is over the E experts.
+    Returns (params, the largest |b| after the move)."""
+    moved = []
+
+    def move(p, load):
+        if isinstance(load, dict):
+            return {**p, **{k: move(p[k], v) for k, v in load.items()}}
+        b = p + rate * jnp.sign(jnp.mean(load, -1, keepdims=True) - load)
+        moved.append(jnp.max(jnp.abs(b)))
+        return b
+
+    return move(params, loads), jnp.max(jnp.stack(moved))
 
 
 # ---------------------------------------------------------------------------

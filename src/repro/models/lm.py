@@ -30,7 +30,25 @@ def init_params(key, cfg: ModelConfig) -> Params:
     }
     if not cfg.tie_embeddings:
         p["lm_head"] = L.init_linear(k3, cfg.d_model, cfg.vocab_size, dt)
+    if cfg.mtp_depth:
+        p["mtp"] = init_mtp(jax.random.fold_in(key, 3), cfg)
     return p
+
+
+def init_mtp(key, cfg: ModelConfig) -> Params:
+    """The multi-token-prediction depth (DeepSeek-V3, one depth): norms of
+    the embedding and of the hidden state, their projection, and one
+    decoder block with an expert FFN; the embedding, final norm and head
+    are the model's own."""
+    if cfg.mtp_depth != 1:
+        raise ValueError(f"mtp_depth {cfg.mtp_depth}: one depth or none")
+    dt = L.dtype_of(cfg.param_dtype)
+    k1, k2 = jax.random.split(key)
+    d = cfg.d_model
+    return {"enorm": L.init_norm(d, cfg.norm, dt),
+            "hnorm": L.init_norm(d, cfg.norm, dt),
+            "eh_proj": L.init_linear(k1, 2 * d, d, dt),
+            "block": B.init_block(k2, cfg, "attn", "moe")}
 
 
 def _head_weight(p: Params, cfg: ModelConfig) -> jnp.ndarray:
@@ -75,13 +93,13 @@ def forward(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray], *,
     return _head(p, x, cfg), stats["aux"]
 
 
-def hidden_states(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
-                  *, remat: str = "dots") -> Tuple[jnp.ndarray, Dict]:
-    """Final-norm hidden states (pre-head). Returns (h, ``L.moe_stats``
-    merged over the layers)."""
+def trunk(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
+          *, remat: str = "dots") -> Tuple[jnp.ndarray, Dict]:
+    """The last block's output, before the final norm. Returns (x,
+    ``L.moe_stats`` merged over the layers)."""
     x = _embed_inputs(p, cfg, batch)
     x, _, stats = B.apply_stack(p["stack"], x, cfg, mode="train", remat=remat)
-    return L.apply_norm(p["final_norm"], x, cfg.norm_eps), stats
+    return x, stats
 
 
 XENT_ROWS = 512   # rows of a forward chunk of the head-plus-loss
@@ -232,28 +250,78 @@ def chunked_xent(p: Params, cfg: ModelConfig, h: jnp.ndarray,
     return tot / jnp.maximum(cnt, 1.0)
 
 
+def mtp_loss(p: Params, cfg: ModelConfig, x: jnp.ndarray,
+             tokens: jnp.ndarray, mask: Optional[jnp.ndarray] = None, *,
+             remat: str = "dots") -> Tuple[jnp.ndarray, Dict]:
+    """The multi-token-prediction depth's loss (DeepSeek-V3 section 2.2,
+    one depth) from the last block's output ``x`` (B, S, D), before the
+    final norm: position i takes W_eh [rmsnorm(Emb(t_{i+1}));
+    rmsnorm(x_i)] through one decoder block, then the model's final norm
+    and head, and predicts t_{i+2}.  The depth runs over all S positions,
+    the last one pairing x with token 0 (it has no next token); causal
+    attention keeps it out of every other position, and no loss reads it.
+    Returns (mean cross-entropy over the S - 2 targets, the block's
+    ``L.moe_stats``)."""
+    m = p["mtp"]
+    cd, eps = L.dtype_of(cfg.compute_dtype), cfg.norm_eps
+    with jax.named_scope(scopes.MTP):
+        nxt = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
+        e = L.apply_norm(m["enorm"], L.embed(p["embed"], nxt, cd), eps)
+        h = L.linear(m["eh_proj"], jnp.concatenate(
+            [e, L.apply_norm(m["hnorm"], x, eps)], axis=-1), cd)
+        block = B._remat_wrap(lambda blk, h: B.apply_block(
+            blk, h, cfg, "attn", "moe", mode="train"), remat)
+        h, _, stats = block(m["block"], h)
+        h = L.apply_norm(p["final_norm"], h, eps)
+        loss = head_xent(p, cfg, h[:, :-2], tokens[:, 2:],
+                         None if mask is None else mask[:, 2:])
+    return loss, stats
+
+
 def loss_fn(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray], *,
             remat: str = "dots") -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Next-token cross-entropy (+ MoE aux); ``head_xent`` keeps the
-    (B,S,V) logits tensor out of memory.  A MoE model's metrics also count
-    the assignments routed to the experts held (``moe_held_assignments``,
-    summed over the expert layers) and the worst layer's largest held
-    expert's load over its mean (``moe_max_load``)."""
-    h, stats = hidden_states(p, cfg, batch, remat=remat)
-    aux = stats["aux"]
-    n_prefix = h.shape[1] - batch["tokens"].shape[1]
+    """Next-token cross-entropy (+ MoE aux, + the multi-token-prediction
+    depth's loss where the model has one, ``mtp_loss``); ``head_xent``
+    keeps the (B,S,V) logits tensor out of memory.  A MoE model's metrics
+    also count the assignments routed to the experts held
+    (``moe_held_assignments``, summed over the expert layers) and the worst
+    layer's largest held expert's load over its mean (``moe_max_load``).
+    Sigmoid routers add the worst layer's largest load over the mean of
+    all E experts (``moe_max_load_all``) and ``router_loads``, the loads
+    ``L.rebalance_router_bias`` takes, at the places of the biases in
+    ``p``."""
+    x, stats = trunk(p, cfg, batch, remat=remat)
+    n_prefix = x.shape[1] - batch["tokens"].shape[1]
     if n_prefix > 0:
-        h = h[:, n_prefix:]
+        x = x[:, n_prefix:]
+    h = L.apply_norm(p["final_norm"], x, cfg.norm_eps)
     targets = batch["tokens"][:, 1:]
     mask = batch.get("loss_mask")
     loss = head_xent(p, cfg, h[:, :-1], targets,
                      None if mask is None else mask[:, 1:])
+    metrics = {"loss": loss}
+    loads = {"stack": stats["loads"]} if "loads" in stats else {}
+    total = loss
+    if cfg.mtp_depth:
+        extra, mtp_stats = mtp_loss(p, cfg, x, batch["tokens"], mask,
+                                    remat=remat)
+        total = total + cfg.mtp_loss_coef * extra
+        stats = L.merge_moe_stats(stats, mtp_stats)
+        if "loads" in mtp_stats:
+            loads["mtp"] = {"block": mtp_stats["loads"]}
+        metrics["mtp_loss"] = extra
+    aux = stats["aux"]
     aux_coef = cfg.moe.aux_loss_coef if cfg.moe else 0.0
-    total = loss + aux_coef * aux
-    metrics = {"loss": loss, "aux": aux, "total": total}
+    total = total + aux_coef * aux
+    metrics.update(aux=aux, total=total)
     if cfg.moe:
         metrics.update(moe_held_assignments=stats["held"],
                        moe_max_load=stats["max_load"])
+    if loads:
+        metrics["moe_max_load_all"] = jnp.max(jnp.stack([
+            jnp.max(jnp.max(v, -1) / jnp.mean(v, -1))
+            for v in jax.tree.leaves(loads)]))
+        metrics["router_loads"] = loads
     return total, metrics
 
 

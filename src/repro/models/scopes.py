@@ -22,9 +22,13 @@ RWKV = "rwkv"               # RWKV-6 time mix
 
 ALL = (ATTN_PROJ, ATTN_CORE, KV_WRITE, FFN, HEAD, OPTIMIZER, SSM, RWKV)
 
-# Inside FFN, the dropless MoE's two parts; an op under one of these is
-# under FFN too, so FFN's time keeps its meaning.
+# Scopes that nest with those above and take no op from them.  Inside FFN,
+# the dropless MoE's two parts; an op under one of these is under FFN too,
+# so FFN's time keeps its meaning.  Around the multi-token-prediction depth
+# (its projection, its block and its head pass), MTP: the depth's ops keep
+# their inner scopes (attn_core, ffn, head, ...) as well.
 MOE_ROUTE = "moe_route"     # router, top-k, balance loss, sort, permute, combine
 MOE_EXPERTS = "moe_experts"  # grouped matmuls and SwiGLU of the experts held
+MTP = "mtp"                 # the multi-token-prediction depth, whole
 
-NESTED = (MOE_ROUTE, MOE_EXPERTS)
+NESTED = (MOE_ROUTE, MOE_EXPERTS, MTP)

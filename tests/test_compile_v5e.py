@@ -165,21 +165,32 @@ def test_qwen_decode_step_fits_one_v5e(one_chip):
     assert 0 < _total_bytes(compiled) < V5E_HBM_BYTES
 
 
-def test_qwen_train_step_runs_the_flash_kernel_on_one_v5e(one_chip):
+def _train_step(one_chip, cfg, opt_cfg, batch_shape, remat):
+    """The jitted train step traced for one described v5e."""
+    params, opt_state = (_place(one_chip, t) for t in
+                         steps_lib.train_state_shapes(cfg, opt_cfg))
+    batch = {"tokens": _on(one_chip, batch_shape, jnp.int32)}
+    return jax.jit(steps_lib.make_train_step(cfg, opt_cfg, remat=remat),
+                   donate_argnums=(0, 1)).trace(params, opt_state, batch)
+
+
+@pytest.fixture(scope="module")
+def qwen_train(one_chip):
+    """(config, compiled) of the benchmark's qwen train step: 1 x 4096
+    tokens, f32 master weights, bf16 compute, remat dots."""
+    cfg = common.run_config(get_arch("qwen1.5-0.5b"), smoke=False)
+    cfg = dataclasses.replace(cfg, param_dtype="float32",
+                              compute_dtype="bfloat16")
+    return cfg, _train_step(one_chip, cfg, OptimizerConfig(), (1, 4096),
+                            "dots").lower().compile()
+
+
+def test_qwen_train_step_runs_the_flash_kernel_on_one_v5e(qwen_train):
     """The benchmark's train step (1 x 4096 tokens, f32 master weights, bf16
     compute, remat dots): attention runs the splash kernels under the
     ``attn_core`` scope, no scan loop is left there, the head's loops write
     each row of its weight gradient once, and the step fits."""
-    cfg = common.run_config(get_arch("qwen1.5-0.5b"), smoke=False)
-    cfg = dataclasses.replace(cfg, param_dtype="float32",
-                              compute_dtype="bfloat16")
-    opt_cfg = OptimizerConfig()
-    params, opt_state = (_place(one_chip, t) for t in
-                         steps_lib.train_state_shapes(cfg, opt_cfg))
-    batch = {"tokens": _on(one_chip, (1, 4096), jnp.int32)}
-    compiled = jax.jit(steps_lib.make_train_step(cfg, opt_cfg, remat="dots"),
-                       donate_argnums=(0, 1)).lower(
-        params, opt_state, batch).compile()
+    cfg, compiled = qwen_train
     text = compiled.as_text()
     _assert_splash_attention(text)
     _assert_head_writes_weight_rows_once(text, f"{cfg.vocab_size},"
@@ -229,9 +240,9 @@ def _assert_splash_attention(text):
     assert loops and not any("attn_core" in op for op in loops)
 
 
-def _deepseek_cell():
-    """The ModelConfig and mix of ``deepseek-v2-lite.train_8k``, through the
-    benchmark's own cut (``benchmarks/chip/harness.py``)."""
+def _cell(config, mix):
+    """The ModelConfig and mix of a benchmark cell, through the benchmark's
+    own cut (``benchmarks/chip/harness.py``)."""
     import json
     import sys
     from pathlib import Path
@@ -240,13 +251,27 @@ def _deepseek_cell():
     sys.path.insert(0, str(chip))
     import harness
 
-    conf = json.loads((chip / "configs" / "deepseek-v2-lite.json").read_text())
-    mix = json.loads((chip / "traffic" / "train_8k.json").read_text())
+    conf = json.loads((chip / "configs" / f"{config}.json").read_text())
+    mix = json.loads((chip / "traffic" / f"{mix}.json").read_text())
     return harness.program_config(conf, mix), mix
 
 
+def _cell_step(one_chip, config, mix):
+    """(config, mix, traced) of a cell's train step at its own sizes."""
+    cfg, mix = _cell(config, mix)
+    traced = _train_step(one_chip, cfg, OptimizerConfig(**mix["optimizer"]),
+                         (mix["batch"], mix["seq_len"]), mix["remat"])
+    return cfg, mix, traced
+
+
+@pytest.fixture(scope="module")
+def deepseek_train(one_chip):
+    cfg, mix, traced = _cell_step(one_chip, "deepseek-v2-lite", "train_8k")
+    return cfg, mix, traced.lower().compile()
+
+
 def test_deepseek_v2_lite_train_step_fits_one_v5e_and_groups_by_expert(
-        one_chip):
+        deepseek_train):
     """The cell's train step (1 x 8192 tokens, f32 master weights, bf16
     compute, remat dots) fits 90% of HBM by the compiler's own peak, runs
     latent attention (q.k 192, v 128) in the splash kernels under
@@ -255,31 +280,150 @@ def test_deepseek_v2_lite_train_step_fits_one_v5e_and_groups_by_expert(
     gradient) under ``moe_experts``, and holds no (group, seq, expert,
     capacity) one-hot dispatch: no array in the program is near the
     S x E x S*K elements such a tensor has."""
-    cfg, mix = _deepseek_cell()
+    cfg, mix, compiled = deepseek_train
     assert cfg.moe.capacity_factor <= 0 and cfg.moe.held == 8
-    opt_cfg = OptimizerConfig(**mix["optimizer"])
-    params, opt_state = (_place(one_chip, t) for t in
-                         steps_lib.train_state_shapes(cfg, opt_cfg))
     S = mix["seq_len"]
-    batch = {"tokens": _on(one_chip, (mix["batch"], S), jnp.int32)}
-    compiled = jax.jit(steps_lib.make_train_step(cfg, opt_cfg,
-                                                 remat=mix["remat"]),
-                       donate_argnums=(0, 1)).lower(
-        params, opt_state, batch).compile()
     assert 0 < compiled.memory_analysis().peak_memory_in_bytes \
         < 0.9 * V5E_HBM_BYTES
     text = compiled.as_text()
     _assert_splash_attention(text)
     _assert_head_writes_weight_rows_once(text, f"{cfg.d_model},"
                                          f"{cfg.vocab_size}")
-    calls = re.findall(r"%(t?gmm)(?:\.\d+)? = \S+ custom-call\(.*?"
-                       r'metadata=\{op_name="([^"]*)"', text, re.S)
-    assert {name for name, _ in calls} == {"gmm", "tgmm"}
-    assert all("/moe_experts/" in op for _, op in calls)
+    _assert_grouped_matmuls(text)
     sizes = [math.prod(int(d) for d in dims.split(","))
              for dims in re.findall(r"[a-z0-9]+\[([0-9,]+)\]", text)]
     one_hot = S * cfg.moe.num_experts * S * cfg.moe.num_experts_per_tok
     assert max(sizes) < one_hot / 100
+
+
+def _assert_grouped_matmuls(text):
+    """The held experts' products run the megablox kernels (``gmm``, and
+    ``tgmm`` for the weights' gradient) under ``moe_experts``; returns
+    their op names."""
+    calls = re.findall(r"%(t?gmm)(?:\.\d+)? = \S+ custom-call\(.*?"
+                       r'metadata=\{op_name="([^"]*)"', text, re.S)
+    assert {name for name, _ in calls} == {"gmm", "tgmm"}
+    assert all("/moe_experts/" in op for _, op in calls)
+    return [op for _, op in calls]
+
+
+def _named(op_name: str, scope: str) -> bool:
+    return scope in re.findall(r"\w+", op_name)
+
+
+def _eqns(jaxpr, outer=""):
+    """(full name stack, function names of the user frames) of every
+    equation of a jaxpr and of the jaxprs inside it, but those inside a
+    ``jit`` equation: a jitted helper is traced once for every call site
+    of the same shapes, so its inner equations carry the frames of
+    whichever call traced it first (the ``jit`` equation itself carries
+    its own call's)."""
+    from jax._src import source_info_util
+
+    for eqn in jaxpr.eqns:
+        stack = outer + "/" + str(eqn.source_info.name_stack)
+        frames = {f.function_name for f in source_info_util.user_frames(
+            eqn.source_info.traceback)}
+        yield stack, frames
+        if eqn.primitive.name != "jit":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _eqns(sub, stack)
+
+
+def test_joyai_llm_flash_train_step_fits_one_v5e_and_names_its_depth(
+        one_chip):
+    """The cell's train step (1 x 8192 tokens, f32 master weights, bf16
+    compute, remat dots) fits 90% of HBM by the compiler's own peak (11.53
+    GiB, 12,379,285,504 bytes, when this was written), runs the splash
+    kernels under ``attn_core`` and the grouped matmuls under
+    ``moe_experts``, and the multi-token-prediction depth is named: every
+    equation that ``lm.mtp_loss`` traced, forward, rematerialised and
+    backward, carries ``mtp``, and the depth's attention kernels, expert
+    kernels and head loops carry it in the compiled step, inside their own
+    scopes."""
+    cfg, mix, traced = _cell_step(one_chip, "joyai-llm-flash", "train_8k")
+    assert cfg.mtp_depth == 1 and cfg.moe.scoring == "sigmoid"
+    inside = [stack for stack, frames in _eqns(traced.jaxpr.jaxpr)
+              if "mtp_loss" in frames]
+    assert len(inside) > 100
+    assert all(_named(stack, "mtp") for stack in inside)
+    assert any("transpose(" in stack for stack in inside)
+    compiled = traced.lower().compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 0 < peak < 0.9 * V5E_HBM_BYTES
+    text = compiled.as_text()
+    _assert_splash_attention(text)
+    gmm = _assert_grouped_matmuls(text)
+    assert any(_named(op, "mtp") for op in gmm)
+    assert not all(_named(op, "mtp") for op in gmm)
+    splash = re.findall(r"%splash_mha_\w+?\.\d+ = .*?custom-call\(.*?"
+                        r'metadata=\{op_name="([^"]*)"', text, re.S)
+    assert any(_named(op, "mtp") for op in splash)
+    loops = re.findall(r' while\(.*?op_name="([^"]*)"', text)
+    assert len([op for op in loops if _named(op, "mtp")
+                and _named(op, "head")]) == 2   # its head, both ways
+
+
+def _without_metadata(text: str) -> str:
+    """A compiled module's text without what only describes where its ops
+    came from: each op's ``metadata``, the tables of source files,
+    functions and frames that it points into, and the source locations
+    inside each Mosaic kernel's body (the body is parsed and printed
+    without them, then hashed)."""
+    import base64
+    import hashlib
+
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = ir.Context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+
+    def body(m):
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(m.group(1))).operation \
+                .get_asm(enable_debug_info=False)
+        return '"body":"' + hashlib.sha256(asm.encode()).hexdigest() + '"'
+    text = re.sub(r'"body":"([^"]+)"', body, text)
+    text = re.sub(r', metadata=\{(?:[^{}"]|"[^"]*")*\}', "", text)
+    out, skip = [], False
+    for line in text.split("\n"):
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            skip = True
+        elif skip and not line:
+            skip = False
+        elif not skip:
+            out.append(line)
+    return "\n".join(out)
+
+
+# sha256 of ``_without_metadata`` of the two train steps above, as their
+# fixtures compile them (JAX 0.9.0), recorded from a program without
+# sigmoid routing, the router's correction bias or the
+# multi-token-prediction depth: those must leave softmax-routed and dense
+# models' steps as they are.  The digests pin the toolchain too: a change
+# that alters either step on purpose, or a new JAX or XLA, re-records
+# them as a matter of course (run the test, copy the digest it reads) and
+# says why.
+UNCHANGED_HLO = {
+    "qwen_train":
+        "17c3ecdc3373963a8dd34e2467553f5e3639e19f6729dfd60409ce40b2570f0d",
+    "deepseek_train":
+        "cef6c26bcd4012ac4d379cdeff1397342a6bb56e53f4bbb4f5965a7dcb73cbdd",
+}
+
+
+@pytest.mark.parametrize("fixture", sorted(UNCHANGED_HLO))
+def test_train_step_compiles_as_before_sigmoid_routing_and_the_depth(
+        fixture, request):
+    import hashlib
+
+    compiled = request.getfixturevalue(fixture)[-1]
+    digest = hashlib.sha256(
+        _without_metadata(compiled.as_text()).encode()).hexdigest()
+    assert digest == UNCHANGED_HLO[fixture]
 
 
 def test_dropless_moe_on_a_four_chip_mesh_keeps_xlas_ragged_dot(topo):

@@ -207,9 +207,62 @@ def test_moe_scopes_lie_inside_ffn():
     names = _op_names(step.lower(ps, os_, batch).compile())
     inner = [n for n in names if moe_scopes.nested_scope_of(n)]
     assert {moe_scopes.nested_scope_of(n) for n in inner} == \
-        set(scopes.NESTED) == set(moe_scopes.NESTED)
+        set(scopes.NESTED) - {scopes.MTP} == set(moe_scopes.NESTED)
     assert all(bench_scopes.scope_of(n) == "ffn" for n in inner)
     assert moe_scopes.nested_times(str(SCOPED)) is None
+
+
+def _mtp_reader():
+    import harness
+
+    return harness._load(CHIP / "metrics" / "train_mtp_device_ms.py",
+                         "metric")
+
+
+@pytest.mark.parametrize("tf_op,inside", [
+    ("jit(train_step)/jvp(mtp)/attn_proj/attn_core/dot_general", True),
+    ("jit(train_step)/transpose(jvp(mtp))/jvp(mtp)/checkpoint/ffn/"
+     "moe_experts/cond/branch_0_fun/jit(tgmm)/pallas_call", True),
+    ("jit(train_step)/jvp(mtp)/head/while/body/exp", True),
+    ("jit(train_step)/jvp()/while/body/closed_call/ffn/dot_general", False),
+    ("jit(train_step)/jvp(head)/while", False),
+    ("jit(train_step)/mtp_loss_scale/mul", False),
+])
+def test_ops_of_the_prediction_depth(tf_op, inside):
+    assert _mtp_reader().in_depth(tf_op) == inside
+
+
+def test_prediction_depth_scope_wraps_the_layer_scopes():
+    """The depth's ops carry ``mtp`` around the scopes the readers match:
+    ``scopes.py`` still puts them under ``attn_core``, ``ffn``, ``head`` and
+    the rest, ``moe_scopes.py`` under ``moe_route`` and ``moe_experts``; a
+    trace of a program without the depth reads nothing."""
+    import jax
+    import jax.numpy as jnp
+    import moe_scopes
+
+    from repro.core.config import OptimizerConfig, get_arch
+    from repro.launch import steps as steps_lib
+    from repro.models import scopes
+
+    cfg = dataclasses.replace(get_arch("joyai-llm-flash").smoke,
+                              param_dtype="float32", compute_dtype="float32")
+    opt = OptimizerConfig()
+    ps, os_ = steps_lib.train_state_shapes(cfg, opt)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 64), jnp.int32)}
+    step = jax.jit(steps_lib.make_train_step(cfg, opt))
+    names = _op_names(step.lower(ps, os_, batch).compile())
+    reader = _mtp_reader()
+    depth = [n for n in names if reader.in_depth(n)]
+    assert scopes.MTP in scopes.NESTED and scopes.MTP not in scopes.ALL
+    assert {bench_scopes.scope_of(n) for n in depth} >= \
+        {"attn_proj", "attn_core", "ffn", "head", bench_scopes.REST}
+    assert {moe_scopes.nested_scope_of(n) for n in depth} >= \
+        set(moe_scopes.NESTED)
+    assert any("transpose(" in n for n in depth)
+    assert len(depth) < len(names)
+    assert reader._seconds(str(SCOPED), bench_scopes._stamp(str(SCOPED))) \
+        is None
 
 
 def test_program_without_scopes_reads_nothing():
