@@ -31,7 +31,7 @@ FULL = ModelConfig(
                   first_k_dense=1, d_ff_dense=7168, capacity_factor=0.0,
                   norm_topk_prob=True, scoring="sigmoid",
                   routed_scaling_factor=2.5, bias_update_rate=0.001,
-                  aux_loss_coef=1e-4, grouped_rows_floor=4.0),
+                  aux_loss_coef=1e-4, grouped_rows_floor=8.0),
     mtp_depth=1,
     mtp_loss_coef=0.3,
     act="swiglu",

@@ -100,7 +100,9 @@ class MoEConfig:
     # dropless: the grouped matmuls visit at least this multiple of the
     # rows a layer's held experts expect, S*K*held/E (zero rows past those
     # routed, which add nothing), so that a step's work does not follow
-    # its routing below that bound; 0 = the rows routed alone
+    # its routing below that bound; 0 = the rows routed alone.  Where
+    # these rows lie below half a layer's S*K, that half also bounds its
+    # row-wide work (``layers.held_row_bound``)
     grouped_rows_floor: float = 0.0
 
     @property

@@ -120,7 +120,8 @@ def main(argv=None):
                            if "moe_held_assignments" in metrics else "")
                     extra = "".join(
                         f"{label} {float(metrics[k]):.4g}  " for k, label in
-                        (("mtp_loss", "mtp loss"),
+                        (("moe_rows_fallback", "rows fallback"),
+                         ("mtp_loss", "mtp loss"),
                          ("moe_bias_absmax", "bias absmax"),
                          ("moe_max_load_all", "max load all"))
                         if k in metrics)

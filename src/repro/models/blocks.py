@@ -261,7 +261,8 @@ def apply_stack(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     xs = (params["periods"], cache["periods"] if cache is not None else None)
     x, (period_caches, per, per_loads) = jax.lax.scan(body, x, xs)
     total = L.merge_moe_stats(total, L.moe_stats(
-        jnp.sum(per["aux"]), jnp.sum(per["held"]), jnp.max(per["max_load"])))
+        jnp.sum(per["aux"]), jnp.sum(per["held"]), jnp.max(per["max_load"]),
+        jnp.sum(per["fallback"]) if "fallback" in per else None))
     if per_loads:
         loads["periods"] = per_loads
     if loads:

@@ -399,27 +399,38 @@ MOE_GROUP_SIZE = 4096   # routing-group tokens; capacity scales with the
 #                         made dispatch 10-50x the expert FLOPs)
 
 
-def moe_stats(aux=0.0, held=0.0, max_load=0.0) -> Dict[str, jnp.ndarray]:
+def moe_stats(aux=0.0, held=0.0, max_load=0.0,
+              fallback=None) -> Dict[str, jnp.ndarray]:
     """What an FFN reports besides its output: the balance loss, the
     assignments routed to the experts held, and the largest held expert's
-    assignments over the mean held expert's (0 for a dense FFN)."""
+    assignments over the mean held expert's (0 for a dense FFN).  A
+    dropless layer with a static row bound also counts ``fallback``: 1
+    where its held rows passed the bound and it ran over all S*K rows."""
     f32 = jnp.float32
-    return {"aux": jnp.asarray(aux, f32), "held": jnp.asarray(held, f32),
-            "max_load": jnp.asarray(max_load, f32)}
+    stats = {"aux": jnp.asarray(aux, f32), "held": jnp.asarray(held, f32),
+             "max_load": jnp.asarray(max_load, f32)}
+    if fallback is not None:
+        stats["fallback"] = jnp.asarray(fallback, f32)
+    return stats
 
 
 def merge_moe_stats(a, b):
-    """Over layers: losses and assignments add, the worst load is kept (a
-    layer's ``loads`` over all experts, where it counts them, stay out)."""
-    return {"aux": a["aux"] + b["aux"], "held": a["held"] + b["held"],
-            "max_load": jnp.maximum(a["max_load"], b["max_load"])}
+    """Over layers: losses, assignments and fallbacks add, the worst load
+    is kept (a layer's ``loads`` over all experts, where it counts them,
+    stay out)."""
+    out = {"aux": a["aux"] + b["aux"], "held": a["held"] + b["held"],
+           "max_load": jnp.maximum(a["max_load"], b["max_load"])}
+    if "fallback" in a or "fallback" in b:
+        out["fallback"] = a.get("fallback", 0.0) + b.get("fallback", 0.0)
+    return out
 
 
-def _load_stats(aux, counts):
+def _load_stats(aux, counts, fallback=None):
     """``moe_stats`` from the assignments of each expert held."""
     counts = counts.astype(jnp.float32)
     return moe_stats(aux, jnp.sum(counts),
-                     jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9))
+                     jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9),
+                     fallback)
 
 
 def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig,
@@ -509,7 +520,10 @@ def _capacity_moe(p: Params, x: jnp.ndarray, m: MoEConfig, cf: float,
 # The assignments of T tokens to K experts each, as n = T * K rows sorted by
 # expert: row r holds token order[r] // K, and assignment a sits at row
 # inverse[a].  Dispatch and combine are each other's transpose; both are
-# gathers, so neither pass scatters.
+# gathers, so neither pass scatters.  Over the first R rows alone (the held
+# rows, where they fit), ``first`` = order[:R], and choice j of token t
+# sits at row slot[t, j], or at R where it is not among them: the combine
+# reads zero there.
 
 
 def _dispatch_rows(x, order, inverse, k: int):
@@ -524,6 +538,15 @@ def _combine_rows(y, order, inverse, k: int):
                    .astype(jnp.float32), axis=1)
 
 
+def _combine_first(y, first, slot, k: int):
+    """(R, D) rows -> (T, D): each token's K slots summed in float32, a
+    slot past the rows adding zero; the same adds as ``_combine_rows``,
+    one (T, D) gather a choice, which XLA fuses into the sum, so that no
+    (T, K, D) array is written."""
+    return sum(jnp.take(y, slot[:, j], axis=0, fill_value=0)
+               .astype(jnp.float32) for j in range(k))
+
+
 def _rows_vjp(fwd, bwd):
     f = jax.custom_vjp(fwd, nondiff_argnums=(3,))
     # the residual's empty array carries x's dtype for the cotangent
@@ -535,6 +558,8 @@ def _rows_vjp(fwd, bwd):
 
 dispatch_rows = _rows_vjp(_dispatch_rows, _combine_rows)
 combine_rows = _rows_vjp(_combine_rows, _dispatch_rows)
+dispatch_first = _rows_vjp(_dispatch_rows, _combine_first)
+combine_first = _rows_vjp(_combine_first, _dispatch_rows)
 
 
 def _grouped_swiglu(p: Params, rows, group_sizes, cd):
@@ -561,7 +586,11 @@ def dropless_moe(p: Params, x: jnp.ndarray, m: MoEConfig, cd):
     assignments are sorted by expert; those of the experts held,
     ``[0, experts_held)``, come first, and grouped matmuls run over them
     alone, or, where ``grouped_rows_floor`` asks for more rows, on over
-    the zero rows after them (which change no number).  The weighted results
+    the zero rows after them (which change no number).  Where that floor
+    lies below half the S*K rows (``held_row_bound``), every row-wide step
+    runs over that half alone while the held rows fit it, and over all
+    rows, with the same numbers, where they do not (the stats'
+    ``fallback`` counts it).  The weighted results
     go back to their tokens (the other experts' part is left out, as on
     the chip that holds only these).  The balance loss is DeepSeek's
     sequence-wise one, E/(S K) * sum_e count_e * mean_s p_e, averaged over
@@ -598,26 +627,136 @@ def dropless_moe(p: Params, x: jnp.ndarray, m: MoEConfig, cd):
             * E / (S * K)
         counts = jnp.sum(seq_counts, axis=0)[:H]
         order = jnp.argsort(idx.reshape(n), stable=True)
+    experts = {k: p[k] for k in ("w_gate", "w_up", "w_down")}
+    floor, bound = floor_rows(m, n), held_row_bound(m, n)
+    if bound is None:
+        y = _all_rows(experts, x, gate, order, counts, K, floor, cd)
+        fallback = None
+    else:
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            # the weights go in cast, so that their gradients leave the
+            # branch in the compute dtype, as the kernels give them
+            experts = {k: w.astype(cd) for k, w in experts.items()}
+        with jax.named_scope(scopes.MOE_ROUTE):
+            y = _bounded_rows(experts, x, gate, order, counts, K, bound,
+                              floor, cd)
+        fallback = (jnp.sum(counts) > bound).astype(jnp.float32)
+    stats = _load_stats(aux, counts, fallback)
+    if sigmoid:
+        stats["loads"] = {"router": {"bias": jnp.sum(seq_counts, axis=0)}}
+    return y, stats
+
+
+def floor_rows(m: MoEConfig, n: int) -> int:
+    """The rows the grouped matmuls visit at least, of a layer's n = S*K:
+    ``grouped_rows_floor`` times the rows its held experts expect, where
+    they are a share of the experts; else 0."""
+    if m.grouped_rows_floor > 0 and m.held < m.num_experts:
+        return min(n, math.ceil(m.grouped_rows_floor * n * m.held
+                                / m.num_experts))
+    return 0
+
+
+def held_row_bound(m: MoEConfig, n: int) -> Optional[int]:
+    """The static bound on a layer's held rows, half its n = S*K rows,
+    where the grouped matmuls' floor (``floor_rows``) lies below it; else
+    ``None``.  The held rows grow and shrink with the step's routing, and
+    a layer whose held rows pass the bound costs what it costs without
+    one, at most twice what the bound's rows cost."""
+    floor = floor_rows(m, n)
+    return n // 2 if 0 < floor < n // 2 else None
+
+
+def _branches(k: int, bound: int, floor: int, cd):
+    return (partial(_first_rows, k=k, bound=bound, floor=floor, cd=cd),
+            partial(_all_rows, k=k, floor=floor, cd=cd))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _bounded_rows(p, x, gate, order, counts, k: int, bound: int, floor: int,
+                  cd):
+    """The rows of the experts held come first: where they fit ``bound``,
+    ``_first_rows``, all of whose row-wide work stops there; else
+    ``_all_rows``.  Both ways the backward reruns the branch the forward
+    took (which the layer's remat runs anyway), so that neither branch
+    keeps the other's residuals."""
+    return jax.lax.cond(jnp.sum(counts) <= bound,
+                        *_branches(k, bound, floor, cd),
+                        p, x, gate, order, counts)
+
+
+def _bounded_rows_fwd(p, x, gate, order, counts, k, bound, floor, cd):
+    return (_bounded_rows(p, x, gate, order, counts, k, bound, floor, cd),
+            (p, x, gate, order, counts))
+
+
+def _bounded_rows_bwd(k, bound, floor, cd, res, g):
+    p, x, gate, order, counts = res
+
+    def back(fn):
+        return lambda p, x, gate: jax.vjp(
+            lambda p, x, gate: fn(p, x, gate, order, counts), p, x, gate)[1](g)
+    # the barrier keeps XLA from moving the gradients' consumers (their
+    # float32 casts into the stacked gradients) into the branches
+    return jax.lax.optimization_barrier(jax.lax.cond(
+        jnp.sum(counts) <= bound,
+        *map(back, _branches(k, bound, floor, cd)),
+        p, x, gate)) + (None, None)
+
+
+_bounded_rows.defvjp(_bounded_rows_fwd, _bounded_rows_bwd)
+
+
+def _all_rows(p, x, gate, order, counts, k: int, floor: int, cd):
+    """The held experts over all n rows in expert order, the rows past the
+    held ones zero; the grouped matmuls run on to ``floor`` rows."""
+    B, S, D = x.shape
+    n = order.shape[0]
+    with jax.named_scope(scopes.MOE_ROUTE):
         inverse = jnp.argsort(order)
         rows = dispatch_rows(x.astype(cd).reshape(B * S, D), order, inverse,
-                             K)
+                             k)
         held = (jnp.arange(n) < jnp.sum(counts))[:, None]
         rows = jnp.where(held, rows, 0)
     with jax.named_scope(scopes.MOE_EXPERTS):
         sizes = counts.astype(jnp.int32)
-        if m.grouped_rows_floor > 0 and H < E:
+        if floor:
             # the last group runs on over zero rows up to the floor
-            floor = min(n, math.ceil(m.grouped_rows_floor * n * H / E))
             sizes = sizes.at[-1].add(jnp.maximum(floor - jnp.sum(sizes), 0))
         out = _grouped_swiglu(p, rows, sizes, cd)
     with jax.named_scope(scopes.MOE_ROUTE):
         w = jnp.take(gate.reshape(n), order)[:, None]
         out = (jnp.where(held, out, 0) * w).astype(cd)
-        y = combine_rows(out, order, inverse, K).reshape(B, S, D).astype(cd)
-    stats = _load_stats(aux, counts)
-    if sigmoid:
-        stats["loads"] = {"router": {"bias": jnp.sum(seq_counts, axis=0)}}
-    return y, stats
+        y = combine_rows(out, order, inverse, k).reshape(B, S, D).astype(cd)
+    return y
+
+
+def _first_rows(p, x, gate, order, counts, k: int, bound: int, floor: int,
+                cd):
+    """``_all_rows`` over the first ``bound`` rows alone, which hold every
+    held row: the same numbers, with no row-wide work past the bound.  The
+    grouped matmuls, as there, stop at the held rows or the ``floor``,
+    whichever is more."""
+    B, S, D = x.shape
+    n = order.shape[0]
+    with jax.named_scope(scopes.MOE_ROUTE):
+        first = order[:bound]
+        held = jnp.arange(bound) < jnp.sum(counts)
+        slot = jnp.full((B * S, k), bound, jnp.int32).at[
+            first // k, first % k].set(
+            jnp.where(held, jnp.arange(bound, dtype=jnp.int32), bound),
+            unique_indices=True)
+        rows = dispatch_first(x.astype(cd).reshape(B * S, D), first, slot, k)
+        rows = jnp.where(held[:, None], rows, 0)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        sizes = counts.astype(jnp.int32)
+        sizes = sizes.at[-1].add(jnp.maximum(floor - jnp.sum(sizes), 0))
+        out = _grouped_swiglu(p, rows, sizes, cd)
+    with jax.named_scope(scopes.MOE_ROUTE):
+        w = jnp.take(gate.reshape(n), first)[:, None]
+        out = (jnp.where(held[:, None], out, 0) * w).astype(cd)
+        y = combine_first(out, first, slot, k).reshape(B, S, D).astype(cd)
+    return y
 
 
 def rebalance_router_bias(params: Params, loads: Params,

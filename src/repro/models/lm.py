@@ -286,6 +286,9 @@ def loss_fn(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray], *,
     also count the assignments routed to the experts held
     (``moe_held_assignments``, summed over the expert layers) and the worst
     layer's largest held expert's load over its mean (``moe_max_load``).
+    Where the expert layers bound their held rows (``grouped_rows_floor``),
+    ``moe_rows_fallback`` counts those whose held rows passed the bound
+    and ran over all S*K rows.
     Sigmoid routers add the worst layer's largest load over the mean of
     all E experts (``moe_max_load_all``) and ``router_loads``, the loads
     ``L.rebalance_router_bias`` takes, at the places of the biases in
@@ -317,6 +320,8 @@ def loss_fn(p: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray], *,
     if cfg.moe:
         metrics.update(moe_held_assignments=stats["held"],
                        moe_max_load=stats["max_load"])
+    if "fallback" in stats:
+        metrics["moe_rows_fallback"] = stats["fallback"]
     if loads:
         metrics["moe_max_load_all"] = jnp.max(jnp.stack([
             jnp.max(jnp.max(v, -1) / jnp.mean(v, -1))

@@ -303,7 +303,7 @@ def _assert_grouped_matmuls(text):
     calls = re.findall(r"%(t?gmm)(?:\.\d+)? = \S+ custom-call\(.*?"
                        r'metadata=\{op_name="([^"]*)"', text, re.S)
     assert {name for name, _ in calls} == {"gmm", "tgmm"}
-    assert all("/moe_experts/" in op for _, op in calls)
+    assert all(_named(op, "moe_experts") for _, op in calls)
     return [op for _, op in calls]
 
 
@@ -330,17 +330,71 @@ def _eqns(jaxpr, outer=""):
                 yield from _eqns(sub, stack)
 
 
+# What XLA schedules to move data, or what only names a value: it takes
+# the name of wherever XLA puts it, not of the program's scope.
+_PLUMBING = ("parameter", "constant", "get-tuple-element", "tuple",
+             "bitcast", "copy", "copy-start", "copy-done", "slice-start",
+             "slice-done")
+
+
+def _computations(text):
+    return dict(re.findall(r"^(?:ENTRY )?%(\S+) [^\n]*\{\n(.*?)\n\}", text,
+                           re.S | re.M))
+
+
+def _holds_rows(dims: str, n: int) -> bool:
+    """Whether an array of these dims holds n rows of more than one value
+    (leading dims that multiply to n, then more than one value)."""
+    d = [int(x) for x in dims.split(",") if x]
+    return any(math.prod(d[:i]) == n and math.prod(d[i:]) > 1
+               for i in range(1, len(d)))
+
+
+def _assert_held_rows_bounded(text, n: int):
+    """The expert layers' conditionals (the all-rows branch first, then
+    the branch over the held-row bound; a forward and a backward one for
+    the scanned layers and for the depth's): every op of both that the
+    program names names ``moe_route`` or ``moe_experts``, and no array of
+    the bounded branch holds n = S*K rows."""
+    comps = _computations(text)
+    conds = [[b.strip().lstrip("%") for b in branches.split(",")]
+             for branches in re.findall(
+                 r" conditional\(.*?branch_computations=\{([^}]*)\}", text)]
+    moe = [bs for bs in conds if any(
+        re.search(r"%t?gmm(?:\.\d+)? = \S+ custom-call\(", comps[b])
+        for b in bs)]
+    assert len(moe) == 4
+    for all_rows, bounded in moe:
+        for b in (all_rows, bounded):
+            for line in comps[b].splitlines():
+                m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(", line)
+                op = re.search(r'op_name="(jit\([^"]*)"', line)
+                if m and op and m.group(2) not in _PLUMBING:
+                    assert _named(op.group(1), "moe_route") \
+                        or _named(op.group(1), "moe_experts"), line
+        assert any(_holds_rows(d, n) for d in re.findall(
+            r"\w+\[([0-9,]+)\]", comps[all_rows]))
+        for line in comps[bounded].splitlines():
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) [\w-]+\(", line)
+            assert not (m and any(_holds_rows(d, n) for d in re.findall(
+                r"\w+\[([0-9,]+)\]", m.group(1)))), line
+
+
 def test_joyai_llm_flash_train_step_fits_one_v5e_and_names_its_depth(
         one_chip):
     """The cell's train step (1 x 8192 tokens, f32 master weights, bf16
     compute, remat dots) fits 90% of HBM by the compiler's own peak (11.53
-    GiB, 12,379,285,504 bytes, when this was written), runs the splash
+    GiB, 12,379,285,504 bytes, when this was written; 12,212,600,832 once
+    its expert layers bounded their held rows at half their rows, with
+    the grouped matmuls' floor at 8), runs the splash
     kernels under ``attn_core`` and the grouped matmuls under
     ``moe_experts``, and the multi-token-prediction depth is named: every
     equation that ``lm.mtp_loss`` traced, forward, rematerialised and
     backward, carries ``mtp``, and the depth's attention kernels, expert
     kernels and head loops carry it in the compiled step, inside their own
-    scopes."""
+    scopes.  Its expert layers branch on the held-row bound (32,768 of the
+    65,536 rows): the bounded branch holds no array of 65,536 rows, and
+    every op of both branches is named for the benchmark's MoE scopes."""
     cfg, mix, traced = _cell_step(one_chip, "joyai-llm-flash", "train_8k")
     assert cfg.mtp_depth == 1 and cfg.moe.scoring == "sigmoid"
     inside = [stack for stack, frames in _eqns(traced.jaxpr.jaxpr)
@@ -362,6 +416,9 @@ def test_joyai_llm_flash_train_step_fits_one_v5e_and_names_its_depth(
     loops = re.findall(r' while\(.*?op_name="([^"]*)"', text)
     assert len([op for op in loops if _named(op, "mtp")
                 and _named(op, "head")]) == 2   # its head, both ways
+    n = mix["batch"] * mix["seq_len"] * cfg.moe.num_experts_per_tok
+    assert L.held_row_bound(cfg.moe, n) == n // 2 == 32768
+    _assert_held_rows_bounded(text, n)
 
 
 def _without_metadata(text: str) -> str:

@@ -318,28 +318,123 @@ def test_32_expert_shares_add_up_to_the_uncut_reference_layer():
         48 * m.num_experts_per_tok
 
 
+def _eight_held(bias=None):
+    """The smoke layer with 8 of its 32 experts held, its router's bias
+    random (or ``bias``), and 64 tokens."""
+    cfg = _smoke()
+    m = dataclasses.replace(cfg.moe, experts_held=8)
+    p = L.init_moe(jax.random.key(1), dataclasses.replace(cfg, moe=m), F32)
+    p["router"]["bias"] = 0.1 * jax.random.normal(jax.random.key(3), (32,)) \
+        if bias is None else jnp.asarray(bias, F32)
+    x = jax.random.normal(jax.random.key(2), (1, 64, cfg.d_model))
+    return m, p, x
+
+
+def _layer_value_and_grad(m, p, x):
+    def f(q):
+        y, s = L.dropless_moe(q, x, m, jnp.bfloat16)
+        return jnp.sum(y.astype(F32) ** 2), s
+    (v, s), g = jax.jit(jax.value_and_grad(f, has_aux=True))(p)
+    return v, s, g
+
+
+def _assert_same_numbers(m0, m1, p, x):
+    """The layer's output, its stats (but the fallback count) and every
+    gradient under ``m1`` are those under ``m0``, bit for bit; returns
+    ``m1``'s stats."""
+    v0, s0, g0 = _layer_value_and_grad(m0, p, x)
+    v1, s1, g1 = _layer_value_and_grad(m1, p, x)
+    assert float(v1) == float(v0)
+    s1 = dict(s1)
+    fallback = s1.pop("fallback", None)
+    assert jax.tree.structure(s0) == jax.tree.structure(s1)
+    for a, b in zip(jax.tree.leaves((s0, g0)), jax.tree.leaves((s1, g1))):
+        np.testing.assert_array_equal(a, b)
+    return s0, fallback
+
+
 @pytest.mark.parametrize("floor", [1.0, 4.0, 1e3])
 def test_grouped_rows_floor_changes_no_number(floor):
     """The floor only lets the grouped matmuls run on over zero rows: with
     8 of 32 experts held, the layer's output, its stats and every
     gradient are those without it, bit for bit, below, near and above
-    the rows routed (86 here, against 64 expected)."""
+    the rows routed (86 here, against 64 expected).  At floor 1 the
+    floor's 64 rows lie below half the 256 rows, so that half bounds the
+    layer's rows; its 86 held rows fit, and it counts no fallback.  At 4
+    and above the floor is all 256 rows: no bound and no count."""
+    m, p, x = _eight_held()
+    mf = dataclasses.replace(m, grouped_rows_floor=floor)
+    s0, fallback = _assert_same_numbers(m, mf, p, x)
+    assert float(s0["held"]) == 86
+    assert L.floor_rows(mf, 256) == (64 if floor == 1.0 else 256)
+    assert L.held_row_bound(mf, 256) == (128 if floor == 1.0 else None)
+    if floor == 1.0:
+        assert float(fallback) == 0.0
+    else:
+        assert fallback is None
+
+
+@pytest.mark.parametrize("floor", [0.5, 1.0, 1.5])
+def test_held_rows_within_the_bound_run_over_the_bound_alone(floor):
+    """Where the held rows (86) fit the bound (128 of the 256 rows), the
+    layer runs over the bound's rows alone and counts no fallback, with
+    the grouped matmuls over the held rows or the floor's (32, 64 and 96
+    rows), whichever are more; its output, stats and every gradient are
+    those of the all-rows path, bit for bit: the combine adds each
+    token's K slots in float32 in the same order, a slot past the bound
+    adding zero."""
+    m, p, x = _eight_held()
+    mf = dataclasses.replace(m, grouped_rows_floor=floor)
+    assert L.floor_rows(mf, 256) == 64 * floor
+    assert L.held_row_bound(mf, 256) == 128
+    _, fallback = _assert_same_numbers(m, mf, p, x)
+    assert float(fallback) == 0.0
+
+
+def test_held_rows_past_the_bound_fall_back_to_all_rows_and_are_counted():
+    """A router bias that sends every token's 4 choices to the 8 experts
+    held puts all 256 rows past the bound of 128: the layer runs over all
+    rows, with the numbers of the path without a floor, bit for bit, and
+    counts the fallback."""
+    m, p, x = _eight_held(np.r_[np.full(8, 10.0), np.zeros(24)])
+    s0, fallback = _assert_same_numbers(
+        m, dataclasses.replace(m, grouped_rows_floor=1.0), p, x)
+    assert float(s0["held"]) == 256
+    assert float(fallback) == 1.0
+
+
+@pytest.mark.parametrize("favour_held", [False, True])
+def test_train_metrics_count_the_expert_layers_that_fell_back(favour_held):
+    """``moe_rows_fallback`` in the step's metrics counts the expert layers
+    (the scanned ones and the depth's) whose held rows passed the bound:
+    none with the biases at zero, all three where every bias sends every
+    token to the experts held.  The loss and every gradient are those of
+    the model without a floor, bit for bit, and that model reports no
+    count."""
     cfg = _smoke()
     m = dataclasses.replace(cfg.moe, experts_held=8)
-    p = L.init_moe(jax.random.key(1), dataclasses.replace(cfg, moe=m), F32)
-    p["router"]["bias"] = 0.1 * jax.random.normal(jax.random.key(3), (32,))
-    x = jax.random.normal(jax.random.key(2), (1, 64, cfg.d_model))
+    cfg0 = dataclasses.replace(cfg, moe=m)
+    cfg1 = dataclasses.replace(
+        cfg, moe=dataclasses.replace(m, grouped_rows_floor=1.0))
+    W = weights.make_params(api.param_shapes(cfg0), 5)
+    if favour_held:
+        favour = jnp.asarray(np.r_[np.full(8, 10.0), np.zeros(24)], F32)
+        for path in BIAS:
+            *up, last = path.split("/")
+            leaf = _leaf(W, "/".join(up))
+            leaf[last] = jnp.broadcast_to(favour, leaf[last].shape)
+    toks = _tokens(cfg, 2, 40)
 
-    def run(m):
-        def f(q):
-            y, s = L.dropless_moe(q, x, m, jnp.bfloat16)
-            return jnp.sum(y.astype(F32) ** 2), s
-        return jax.jit(jax.value_and_grad(f, has_aux=True))(p)
-    (v0, s0), g0 = run(m)
-    (v1, s1), g1 = run(dataclasses.replace(m, grouped_rows_floor=floor))
-    assert float(s0["held"]) == 86
-    assert float(v1) == float(v0)
-    for a, b in zip(jax.tree.leaves((s0, g0)), jax.tree.leaves((s1, g1))):
+    def run(cfg):
+        return jax.jit(jax.value_and_grad(
+            lambda p: api.loss_fn(p, cfg, {"tokens": toks}),
+            has_aux=True))(W)
+    (l0, met0), g0 = run(cfg0)
+    (l1, met1), g1 = run(cfg1)
+    assert "moe_rows_fallback" not in met0
+    assert float(met1["moe_rows_fallback"]) == (3.0 if favour_held else 0.0)
+    assert float(l1) == float(l0)
+    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
         np.testing.assert_array_equal(a, b)
 
 
